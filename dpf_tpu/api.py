@@ -193,14 +193,14 @@ class DPF(object):
         self._keygen_knobs_cache = {}
         self.table = None             # original table (numpy int32)
         self.table_device = None      # permuted table on device (jnp)
+        self._kernel_tables = {}      # Pallas digit planes per layout
         self.table_num_entries = None
         self.table_effective_entry_size = None
         self._torch_io = False
         self.buffers = None           # reference-API compat handle
         # optional time.monotonic() soft deadline for
         # kernel_impl="dispatch": checked between per-level programs
-        # (never interrupts a compile — relay safety, docs/STATUS.md);
-        # used by bench warm-up
+        # (never interrupts a compile)
         self.dispatch_deadline = None
 
     # ------------------------------------------------------------------ gen
@@ -391,6 +391,7 @@ class DPF(object):
         self.table_num_entries = n
         self.table_effective_entry_size = e
         self._tuned_cache = {}  # shape changed — re-resolve per batch
+        self._kernel_tables = {}
         if self.scheme == "sqrtn":
             # the sqrt-N grid emits natural order — no permutation
             self.table_device = jnp.asarray(tbl)
@@ -413,6 +414,8 @@ class DPF(object):
         """
         if self.table_device is None:
             raise RuntimeError("Must call `eval_init` before `eval_tpu`")
+        from .tune import compcache
+        compcache.enable()
         eff = len(keys)
         if eff == 0:
             raise ValueError("empty key batch")
@@ -847,6 +850,16 @@ class DPF(object):
             out["chunk_leaves_effective"] = chunk
         return out
 
+    def _kernel_table(self, key, build):
+        """The device table in a Pallas kernel's [4, N, E] int8 digit
+        form, built by ``build(table_device)`` once per kernel layout
+        ``key`` and kept until the next ``eval_init``/``eval_free``.  A
+        call then holds no table-sized temporaries; the resident cost is
+        one more table's bytes."""
+        if key not in self._kernel_tables:
+            self._kernel_tables[key] = build(self.table_device)
+        return self._kernel_tables[key]
+
     def _dispatch_packed(self, pk: keygen.PackedKeys):
         """Dispatch one packed batch to the device and return the device
         array WITHOUT forcing a host sync: JAX async dispatch lets the
@@ -874,8 +887,15 @@ class DPF(object):
                 dot_impl=k["dot_impl"], aes_impl=k["aes_impl"],
                 round_unroll=k["round_unroll"],
                 deadline=self.dispatch_deadline)
+        table = self.table_device
+        if k["kernel_impl"] == "pallas" and self.prf_method != PRF_AES128:
+            from .ops.pallas_level import subtree_digits
+            fl = k.get("f_levels") or depth - chunk.bit_length() + 1
+            table = self._kernel_table(
+                ("subtree", fl),
+                lambda t: subtree_digits(t, 1 << fl, (2,) * (depth - fl)))
         return expand.expand_and_contract(
-            cw1, cw2, last, self.table_device, depth=depth,
+            cw1, cw2, last, table, depth=depth,
             prf_method=self.prf_method, chunk_leaves=chunk,
             dot_impl=k["dot_impl"], aes_impl=k["aes_impl"],
             round_unroll=k["round_unroll"], kernel_impl=k["kernel_impl"],
@@ -919,8 +939,12 @@ class DPF(object):
                 note_swallowed("api.sqrt_kernel_unsupported",
                                ValueError(reason))
                 kernel = "xla"
+        table = self.table_device
+        if kernel == "pallas":
+            from .ops.pallas_level import table_digits
+            table = self._kernel_table(("sqrt",), table_digits)
         return sqrtn.eval_contract_batched(
-            pk.seeds, pk.cw1, pk.cw2, self.table_device,
+            pk.seeds, pk.cw1, pk.cw2, table,
             prf_method=self.prf_method, dot_impl=kn["dot_impl"],
             row_chunk=rc, kernel_impl=kernel,
             kernel_variant=kn.get("kernel_variant"))
@@ -944,8 +968,12 @@ class DPF(object):
         n = self.table_num_entries
         k = self.resolved_eval_knobs(pk.batch)
         if k["kernel_impl"] == "pallas":
+            table = self.table_device
+            if self.prf_method != PRF_AES128:
+                table = self._kernel_table(
+                    ("mixed",), lambda t: radix4.mixed_digits(t, n))
             out = radix4.expand_and_contract_mixed_pallas(
-                cw1, cw2, last, self.table_device, n=n,
+                cw1, cw2, last, table, n=n,
                 prf_method=self.prf_method, aes_impl=k["aes_impl"],
                 dot_impl=k["dot_impl"])
         elif k["kernel_impl"] == "dispatch":
@@ -1049,6 +1077,7 @@ class DPF(object):
 
     def eval_free(self, buffers=None):
         self.table_device = None
+        self._kernel_tables = {}
         self.buffers = None
 
     def __repr__(self):

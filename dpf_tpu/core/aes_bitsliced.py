@@ -312,9 +312,9 @@ def aes128_multi_bitsliced(seeds, n_pts: int, unroll: bool | None = None,
     schedule's cost amortizes over four children.  Returns a tuple of
     ``n_pts`` limb arrays shaped like ``seeds``, bit-identical to
     ``prf_ref.prf_aes128(seed, b)``.  Under JAX the nine uniform middle
-    rounds run in a ``fori_loop`` (honoring ``unroll``, default =
-    prf.ROUND_UNROLL auto); ``sbox`` selects the circuit (``_sbox_bits``),
-    threaded from a jit-static arg.
+    rounds run in a ``fori_loop`` (honoring ``unroll``; rolled unless
+    it or prf.ROUND_UNROLL is True); ``sbox`` selects the circuit
+    (``_sbox_bits``), threaded from a jit-static arg.
     """
     assert 1 <= n_pts <= 255
     is_np = isinstance(seeds, np.ndarray)
@@ -372,8 +372,9 @@ def aes128_multi_bitsliced(seeds, n_pts: int, unroll: bool | None = None,
             return (xp.stack(sl), xp.stack(rkl))
 
         carry = (xp.stack(st), xp.stack(rk))
+        # rolled unless forced: the unrolled circuit is too big to compile
         carry = jax.lax.fori_loop(0, 9, body, carry,
-                                  unroll=_prf._round_unroll()
+                                  unroll=bool(_prf.ROUND_UNROLL)
                                   if unroll is None else unroll)
         st = [carry[0][i] for i in range(8)]
         rk = [carry[1][i] for i in range(8)]

@@ -310,8 +310,11 @@ def prf_aes128_v(seeds, pos: int):
 # Runtime trade-off: a rolled fori_loop materializes its [16, B, w] carry in
 # HBM every iteration (the cipher is memory-bound that way); fully unrolling
 # lets XLA fuse all rounds into one elementwise kernel.  ``ROUND_UNROLL``
-# picks per backend: unroll on TPU (fast compiles there), rolled elsewhere
-# (CPU XLA chokes on the big graphs).  Override by setting the module flag.
+# picks per backend: unroll on TPU, rolled elsewhere (CPU XLA chokes on the
+# big graphs).  Bitsliced AES is the exception: its rounds stay rolled
+# unless forced (``aes_bitsliced.py``), since its unrolled N = 2^20
+# program did not compile for a v5e in 20 min (PR 21).  Override by
+# setting the module flag or EvalConfig.round_unroll.
 # ---------------------------------------------------------------------------
 
 ROUND_UNROLL = None  # None = auto (unroll on TPU), True/False = force
@@ -320,11 +323,8 @@ ROUND_UNROLL = None  # None = auto (unroll on TPU), True/False = force
 def _round_unroll() -> bool:
     if ROUND_UNROLL is not None:
         return bool(ROUND_UNROLL)
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    import jax
+    return jax.default_backend() == "tpu"
 
 def _salsa_state(seeds, pos: int):
     import jax.numpy as jnp
@@ -587,16 +587,19 @@ def _aes_pair_impl() -> str:
     jitted programs as a static argument."""
     if AES_PAIR_IMPL != "auto":
         return AES_PAIR_IMPL
-    return "bitsliced" if _default_backend_tpu() else "gather"
+    # gathers are slow on the TPU: one 512-key gather-AES eval at
+    # N = 2^20 did not finish in 25 min on a v5e (PR 21)
+    import jax
+    return "bitsliced" if jax.default_backend() == "tpu" else "gather"
 
 
 def prf_pair(method: int, seeds, aes_impl: str | None = None,
              unroll: bool | None = None):
     """Both children PRF(seed, 0), PRF(seed, 1) — fused where profitable.
 
-    For AES the key schedule is shared between the two children; on TPU the
-    whole cipher additionally runs bitsliced (no gathers) — see
-    ``aes_bitsliced.py``.  All variants are bit-identical.  ``aes_impl``
+    For AES the key schedule is shared between the two children;
+    ``aes_impl="bitsliced"`` runs the whole cipher on boolean planes (no
+    gathers) — see ``aes_bitsliced.py``.  All variants are bit-identical.  ``aes_impl``
     and ``unroll`` must be threaded from jit *static* arguments by callers
     inside jit (module defaults otherwise) so switching retraces.
     """
@@ -633,10 +636,3 @@ def prf_multi(method: int, seeds, arity: int,
         return prf_aes128_multi_jax(seeds, arity, unroll)
     return tuple(prf_v(method, seeds, b, unroll) for b in range(arity))
 
-
-def _default_backend_tpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False

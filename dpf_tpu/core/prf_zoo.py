@@ -138,7 +138,7 @@ def benchmark_zoo(n_calls=1 << 20, reps=5, names=None):
     selects on.  For the block-PRG candidates the timed program
     materializes ALL FOUR 128-bit children from the one core block (the
     ``prf_multi`` serving path), so the x4 scaling never excludes the
-    extraction cost (ADVICE.md round 5).  Prints one result-dict line
+    extraction cost.  Prints one result-dict line
     per candidate (the paper's PRF-selection experiment, on TPU).
     """
     import json

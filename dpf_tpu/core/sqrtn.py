@@ -691,12 +691,12 @@ def _eval_sharded_sqrt_jit(seeds, cw1, cw2, table, *, prf_method,
 
     from ..ops import matmul128
     from ..parallel.sharded import (_pvary, _scan_psum_groups,
-                                    _shard_map, _valid_psum_group)
+                                    _valid_psum_group)
 
     n_shards = mesh.shape["table"]
     k = seeds.shape[1]
     r = cw1.shape[1]
-    e = table.shape[1]
+    e = table.shape[-1]
     r_local = r // n_shards
     rc = row_chunk
     steps = r_local // rc
@@ -760,10 +760,14 @@ def _eval_sharded_sqrt_jit(seeds, cw1, cw2, table, *, prf_method,
             c2s.reshape(n_groups, g, bsz, rc, 4),
             tbl_chunks.reshape(n_groups, g, rc * k, e)), "table")
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
-        in_specs=(P("batch"), P("batch"), P("batch"), P("table", None)),
-        out_specs=P("batch", None))
+        in_specs=(P("batch"), P("batch"), P("batch"),
+                  # pallas may get the table's [4, N, E] digit planes
+                  P(*(None,) * (table.ndim - 2), "table", None)),
+        out_specs=P("batch", None),
+        # a pallas_call's out_shape carries no mesh-axis typing
+        check_vma=(kernel_impl or "xla") != "pallas")
     return fn(seeds, cw1, cw2, table)
 
 

@@ -24,10 +24,13 @@ Layout: limb-major ``[4, B, w]`` — the wide node axis rides the 128-wide
 lanes; the ``[B, w, 4]`` boundary transposes sit inside jit where they are
 negligible next to the cipher.
 
+Contraction: the v5e MXU has no int32 matmul, so the kernels contract
+on its int8 path (``_dot_digits``: 4 balanced int8 digits per operand,
+10 digit products, exact mod 2^32).
+
 Correctness: asserted against the portable XLA path in tests (interpret
-mode on CPU, compiled on TPU).  ChaCha20-12 and Salsa20-12 cores; the
-bitsliced-AES variant stays on the XLA dispatch path (its pack/unpack
-transposes do not benefit from manual scheduling).
+mode on CPU, compiled on TPU).  ChaCha20-12 and Salsa20-12 cores; AES
+has its own plane-domain level kernel (``ops/aes_planes.py``).
 """
 
 from __future__ import annotations
@@ -45,20 +48,9 @@ from ..core.prf import _SIGMA
 
 def _compiler_params(dimension_semantics):
     """Mosaic grid-dimension semantics ("parallel" dims may be pipelined
-    /parallelized; "arbitrary" = sequential, for accumulation dims).
-    Returns None when the running jax has no CompilerParams (interpret
-    engines ignore it anyway)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:
-        return None
-    for name in ("CompilerParams", "TPUCompilerParams"):  # new/old spelling
-        try:
-            return getattr(pltpu, name)(
-                dimension_semantics=dimension_semantics)
-        except (AttributeError, TypeError):
-            continue
-    return None
+    /parallelized; "arbitrary" = sequential, for accumulation dims)."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def _rotl(x, b):
@@ -180,6 +172,45 @@ def _add128_planes(val, cw):
     return out
 
 
+def _digits_i8(x):
+    """int32 -> 4 signed int8 digits d_k with x == sum_k d_k 2^(8k) mod
+    2^32 (balanced: each d_k in [-128, 127]).  Traceable in and out of
+    a kernel: arithmetic shifts only."""
+    out = []
+    for _ in range(4):
+        d = (x << 24) >> 24
+        out.append(d.astype(jnp.int8))
+        x = (x - d) >> 8
+    return out
+
+
+@jax.jit
+def table_digits(table):
+    """[N, E] int32 table -> [4, N, E] int8 digit planes, the form the
+    in-kernel contraction reads (the v5e MXU has no int32 matmul)."""
+    return jnp.stack(_digits_i8(jnp.asarray(table, jnp.int32)))
+
+
+def _dot_digits(lhs, rhs_ref):
+    """Exact wrapping int32 ``lhs [M, K] @ rhs [K, E]`` on the MXU's
+    int8 path: rhs comes as its 4 digit planes (``table_digits``), lhs
+    is split here, and the 10 digit products with shift < 32 are
+    accumulated.  Each product sums K terms of magnitude <= 2^14, so it
+    is exact in int32 for K < 2^17."""
+    a = _digits_i8(lhs)
+    acc = None
+    for s in range(4):
+        t = None
+        for i in range(s + 1):
+            p = lax.dot_general(a[i], rhs_ref[s - i],
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.int32)
+            t = p if t is None else t + p
+        t = t << (8 * s) if s else t
+        acc = t if acc is None else acc + t
+    return acc
+
+
 # ---------------------------------------------------------------------------
 # Tiled single level step
 # ---------------------------------------------------------------------------
@@ -273,7 +304,13 @@ def _make_subtree_kernel(sched: tuple, prf_method: int = 2):
     fan-out of kernel level k; the sliced codeword arrays hold the levels'
     slots back to back in the same order (see the wrapper's ``idx``).
     Block-PRG methods evaluate ONE core per node per level and split the
-    512-bit block into the children (4x fewer cores at arity 4)."""
+    512-bit block into the children (4x fewer cores at arity 4).
+
+    Children are concatenated along the lanes (``new[b*w + j]``), not
+    interleaved: Mosaic refuses the lane-splitting reshape an
+    interleave needs.  The leaves thus come out digit-reversed against
+    the XLA path's order, and the launcher hands the kernel its table in
+    that order (``_kernel_leaf_order``)."""
     from jax.experimental import pallas as pl
 
     blk = _BLK_CORES.get(prf_method)
@@ -281,7 +318,8 @@ def _make_subtree_kernel(sched: tuple, prf_method: int = 2):
 
     def kernel(seeds_ref, cw1_ref, cw2_ref, table_ref, out_ref):
         f = pl.program_id(1)
-        planes = [seeds_ref[i] for i in range(4)]     # [TB, 1]
+        x = seeds_ref[...]                            # [TB, 4]
+        planes = [x[:, i:i + 1] for i in range(4)]    # [TB, 1]
         off = 0
         for a in sched:
             sel = (planes[0] & np.uint32(1)).astype(jnp.bool_)  # [TB, w]
@@ -299,14 +337,10 @@ def _make_subtree_kernel(sched: tuple, prf_method: int = 2):
                       for i in range(4)]
                 children.append(_add128_planes(val, cw))
             off += a
-            w = planes[0].shape[1]
-            planes = [jnp.stack([children[b][i] for b in range(a)],
-                                axis=2).reshape(-1, a * w)
-                      for i in range(4)]
-        leaves = planes[0].astype(jnp.int32)          # [TB, C]
-        contrib = lax.dot_general(
-            leaves, table_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)         # x [E, C] -> [TB, E]
+            planes = [jnp.concatenate([children[b][i] for b in range(a)],
+                                      axis=1) for i in range(4)]
+        contrib = _dot_digits(planes[0].astype(jnp.int32),
+                              table_ref)              # [TB, E]
 
         @pl.when(f == 0)
         def _():
@@ -324,14 +358,37 @@ PALLAS_TB = 32       # key tile (sublane-friendly multiple of 8)
 PALLAS_MAX_C = 4096  # leaves per subtree -> ~4 MB cipher state in VMEM
 
 
+def _kernel_leaf_order(table_perm, f_cnt: int, sched: tuple):
+    """Table rows in the subtree kernel's leaf order.  Within a subtree
+    chunk the XLA order makes the first level's branch the most
+    significant digit; the kernel's concatenated children make it the
+    least significant, so each chunk's digit axes are reversed (a
+    static row gather: a transpose over the tiny digit axes would pad
+    every [2, E] tile to the chip's (8, 128) layout)."""
+    n = table_perm.shape[0]
+    depth = len(sched)
+    idx = np.arange(n).reshape((f_cnt,) + tuple(sched))
+    idx = np.transpose(idx, (0,) + tuple(range(depth, 0, -1))).reshape(n)
+    return table_perm[idx]
+
+
+@functools.partial(jax.jit, static_argnames=("f_cnt", "sched"))
+def subtree_digits(table_perm, f_cnt: int, sched: tuple):
+    """The subtree kernel's table: [4, N, E] int8 digit planes in its
+    leaf order.  ``DPF`` builds it once per layout and passes it in place
+    of the int32 table, so a call holds no table-sized temporaries."""
+    return table_digits(_kernel_leaf_order(table_perm, f_cnt, sched))
+
+
 def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
                           prf_method, interpret, tb):
     """Shared launcher: slice codeword slots (``idx``, level-major), pad
-    the batch to the key-tile multiple, run the schedule kernel."""
+    the batch to the key-tile multiple, run the schedule kernel.
+    ``table_perm`` is the [N, E] int32 table or its ``subtree_digits``."""
     from jax.experimental import pallas as pl
 
     bsz, f_cnt, _ = frontier.shape
-    n, e = table_perm.shape
+    n, e = table_perm.shape[-2:]
     c = n // f_cnt
     assert c == int(np.prod(sched)), (c, sched)
 
@@ -347,8 +404,12 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
     idx = np.asarray(idx)
     cw1_sl = jnp.transpose(cw1[:, idx, :], (2, 0, 1))
     cw2_sl = jnp.transpose(cw2[:, idx, :], (2, 0, 1))
-    seeds = jnp.transpose(frontier, (2, 0, 1))        # [4, B, F]
-    table_t = table_perm.T                            # [E, N]
+    # [tiles, F, TB, 4]: one (TB, 4) block per grid step, whose dims
+    # equal the array's last two, as Mosaic requires
+    seeds = jnp.transpose(frontier.reshape(bp // tb, tb, f_cnt, 4),
+                          (0, 2, 1, 3))
+    digits = (table_perm if table_perm.ndim == 3
+              else subtree_digits(table_perm, f_cnt, tuple(sched)))
 
     grid = (bp // tb, f_cnt)
     kernel = _make_subtree_kernel(tuple(sched), prf_method)
@@ -356,10 +417,11 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((4, tb, 1), lambda i, f: (0, i, f)),
+            pl.BlockSpec((pl.squeezed, pl.squeezed, tb, 4),
+                         lambda i, f: (i, f, 0, 0)),
             pl.BlockSpec((4, tb, n_slots), lambda i, f: (0, i, 0)),
             pl.BlockSpec((4, tb, n_slots), lambda i, f: (0, i, 0)),
-            pl.BlockSpec((e, c), lambda i, f: (0, f)),
+            pl.BlockSpec((4, c, e), lambda i, f: (0, f, 0)),
         ],
         out_specs=pl.BlockSpec((tb, e), lambda i, f: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, e), jnp.int32),
@@ -367,7 +429,7 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
         # key tiles are independent; the subtree axis accumulates into
         # the same [tb, E] output block (reduction dim -> "arbitrary")
         compiler_params=_compiler_params(("parallel", "arbitrary")),
-    )(seeds, cw1_sl, cw2_sl, table_t)
+    )(seeds, cw1_sl, cw2_sl, digits)
     return out[:bsz]
 
 
@@ -379,7 +441,8 @@ def _subtree_contract_pallas_impl(frontier, cw1, cw2, table_perm, *,
 
     frontier:   [B, F, 4] u32 — phase-1 output seeds (subtree f of key b).
     cw1, cw2:   [B, 64, 4] u32 — full codeword arrays (wire layout).
-    table_perm: [N, E] int32 — bit-reverse-permuted table, N = F * C.
+    table_perm: [N, E] int32 — bit-reverse-permuted table, N = F * C —
+    or its ``subtree_digits``.
     prf_method: 2 = ChaCha20-12, 1 = Salsa20-12 (for AES see
     ``subtree_contract_pallas_aes``).
     Returns [B, E] int32 shares: sum_f leaves(f) . chunk(f).

@@ -21,7 +21,7 @@ one-hot leaf share never touches HBM.
 Cell order is natural: cell ``m = t*K + c`` of a tile holds grid row
 ``row0 + t``, column seed ``c`` — table rows line up with no
 permutation, and a traced ``row0`` (the sharded path's per-shard row
-base) rides in as a tiny ``[steps, 1]`` VMEM operand.
+base) rides in as one SMEM scalar; each tile adds ``j * rc``.
 
 Only the low 32 output bits are contracted, and 128-bit adds carry
 upward only, so the codeword add needs just the low limb — the kernel
@@ -57,10 +57,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from .pallas_level import (_BLK_CORES, _CORES, _add128_planes,
-                           _compiler_params)
+                           _compiler_params, _dot_digits, table_digits)
 
 # default tile knobs: widest live state = 16 cipher words x [TB, cells]
 # u32 (the block-PRG ids quarter that — one block per 4 rows).  These
@@ -117,70 +116,70 @@ def _make_sqrt_kernel(prf_method: int, tb: int, rc: int, k: int,
     ``j_axis``: which grid axis is the row-tile (accumulation) axis.
     ``limbs``/``cw_add``: emission and codeword-select structure (see
     the module docstring); every combination is bit-identical.
+
+    Cell planes are built by concatenating ``rc`` K-wide row segments
+    along the lanes: Mosaic refuses the lane-merging reshape of a
+    ``[TB, rc, K]`` broadcast.
     """
     from jax.experimental import pallas as pl
 
     blk = _BLK_CORES.get(prf_method)
     core = None if blk is not None else _CORES[prf_method]
-    cells = rc * k
     nlimb = 4 if limbs == "multi" else 1
 
-    def tile(p):
-        """[TB, rc, K]-broadcast -> [TB, cells] cell plane."""
-        return jnp.broadcast_to(p, (tb, rc, k)).reshape(tb, cells)
+    def cat(parts):
+        return jnp.concatenate(parts, axis=1)
+
+    def rows(c):
+        """[TB, rc] per-row values -> [TB, cells] (row t over K cells)."""
+        return cat([jnp.broadcast_to(c[:, t:t + 1], (tb, k))
+                    for t in range(rc)])
 
     def kernel(row0_ref, seeds_ref, cw1_ref, cw2_ref, table_ref, out_ref):
         j = pl.program_id(j_axis)
-        row0 = row0_ref[0, 0]                          # this tile's base row
+        # this tile's base row
+        row0 = row0_ref[0] + j.astype(jnp.uint32) * np.uint32(rc)
         s = [seeds_ref[i] for i in range(4)]           # [TB, K]
+        zero = s[0] - s[0]
         # cell m = t*K + c: grid row row0+t under column seed c —
         # natural order, matching the table tile rows directly
         if blk is not None:
             # ONE core block per 4 grid rows: counter plane c for rows
             # 4c..4c+3 (row0 is a multiple of 4 by the row-chunk rules)
             nctr = rc // 4
-            planes = [jnp.broadcast_to(p[:, None, :], (tb, nctr, k))
-                      .reshape(tb, nctr * k) for p in s]
-            ctr = ((row0 >> np.uint32(2))
-                   + lax.broadcasted_iota(jnp.uint32, (tb, nctr, k), 1)
-                   .reshape(tb, nctr * k))
+            planes = [cat([p] * nctr) for p in s]
+            ctr = cat([zero + ((row0 >> np.uint32(2)) + np.uint32(c))
+                       for c in range(nctr)])
             out16 = blk(planes, ctr)
             # row 4c+g = block words [4g..4g+3] MSW-first, so limb l of
             # that row is word 4g+3-l (``_grid_vals``/``_blk_group``)
-            vals = [jnp.stack([out16[4 * g + 3 - l].reshape(tb, nctr, k)
-                               for g in range(4)],
-                              axis=2).reshape(tb, cells)
-                    for l in range(nlimb)]
+            vals = [cat([out16[4 * (t % 4) + 3 - l][
+                :, (t // 4) * k:(t // 4 + 1) * k] for t in range(rc)])
+                for l in range(nlimb)]
         else:
-            planes = [tile(p[:, None, :]) for p in s]
-            pos = (row0 + lax.broadcasted_iota(jnp.uint32, (tb, rc, k), 1)
-                   .reshape(tb, cells))
+            planes = [cat([p] * rc) for p in s]
+            pos = cat([zero + (row0 + np.uint32(t)) for t in range(rc)])
             vals = list(core(planes, pos)[:nlimb])
-        sel = (s[0] & np.uint32(1))                    # [TB, K] u32 0/1
+        sel = cat([s[0] & np.uint32(1)] * rc)          # [TB, cells] 0/1
 
         def select(c1, c2):
             """The codeword the LSB picks, as a [TB, cells] plane."""
             if cw_add == "staged":
                 # base + masked correction: cw1 + sel*(cw2-cw1), exact
                 # mod 2^32 (u32 wraps) — two staged adds, no select op
-                return tile(c1[:, :, None]) + tile(sel[:, None, :]) * \
-                    tile((c2 - c1)[:, :, None])
-            return jnp.where(tile(sel.astype(jnp.bool_)[:, None, :]),
-                             tile(c2[:, :, None]), tile(c1[:, :, None]))
+                return rows(c1) + sel * rows(c2 - c1)
+            return jnp.where(sel.astype(jnp.bool_), rows(c2), rows(c1))
 
         if limbs == "multi":
             # the scan path's exact arithmetic: all four value limbs +
             # the full 128-bit carry chain, low limb contracted (carries
             # only propagate upward, so the bits match the low-only path)
-            cw = [select(cw1_ref[..., l], cw2_ref[..., l])
-                  for l in range(4)]
+            cw = [select(cw1_ref[l], cw2_ref[l]) for l in range(4)]
             leaves = _add128_planes(vals, cw)[0].astype(jnp.int32)
         else:
-            leaves = (vals[0] + select(cw1_ref[:], cw2_ref[:])) \
+            leaves = (vals[0] + select(cw1_ref[...], cw2_ref[...])) \
                 .astype(jnp.int32)                     # [TB, cells]
-        contrib = lax.dot_general(
-            leaves, table_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32)          # x [E, cells]
+        contrib = _dot_digits(leaves, table_ref)       # [TB, E]
 
         @pl.when(j == 0)
         def _():
@@ -204,16 +203,18 @@ def _sqrt_grid_contract_impl(seeds, cw1, cw2, table, row0, *,
     its own jit/shard_map with a TRACED ``row0``).
 
     seeds: [B, K, 4] u32; cw1/cw2: [B, R, 4] u32; table: [R*K, E] int32
-    natural-order rows for grid rows row0..row0+R-1.  Returns [B, E]
+    natural-order rows for grid rows row0..row0+R-1, or their
+    ``table_digits`` (what ``DPF`` passes, built once).  Returns [B, E]
     int32 shares, bit-identical to the scan oracle for EVERY variant of
     (tb, max_cells, grid_order, dim_semantics, limbs, cw_add).
     """
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     bsz, k, _ = seeds.shape
     r = cw1.shape[1]
-    e = table.shape[1]
-    assert table.shape[0] == r * k, (table.shape, r, k)
+    e = table.shape[-1]
+    assert table.shape[-2] == r * k, (table.shape, r, k)
     reason = pallas_sqrt_unsupported(prf_method, r)
     if reason:
         raise ValueError(reason)
@@ -249,33 +250,35 @@ def _sqrt_grid_contract_impl(seeds, cw1, cw2, table, row0, *,
             "output blocks non-consecutively" % (bp, tb))
 
     sm = jnp.transpose(seeds, (2, 0, 1))               # [4, B, K]
+    # codewords as [steps, (4,) B, rc]: a (TB, rc) block then spans the
+    # array's last dim whole, as Mosaic requires
     if limbs == "multi":
-        cw1_in, cw2_in = cw1, cw2                      # [B, R, 4] full
-        cw_spec = lambda im: pl.BlockSpec((tb, rc, 4), im)  # noqa: E731
-        cw_maps = (lambda i, j: (i, j, 0)), (lambda j, i: (i, j, 0))
+        cw1_in, cw2_in = (jnp.transpose(c.reshape(bp, steps, rc, 4),
+                                        (1, 3, 0, 2)) for c in (cw1, cw2))
+        cw_block = (pl.squeezed, 4, tb, rc)
+        cw_maps = (lambda i, j: (j, 0, i, 0)), (lambda j, i: (j, 0, i, 0))
     else:
-        cw1_in, cw2_in = cw1[:, :, 0], cw2[:, :, 0]    # [B, R] low limbs
-        cw_spec = lambda im: pl.BlockSpec((tb, rc), im)  # noqa: E731
-        cw_maps = (lambda i, j: (i, j)), (lambda j, i: (i, j))
-    table_t = table.T                                  # [E, R*K]
-    row0s = (jnp.asarray(row0, jnp.uint32)
-             + jnp.arange(steps, dtype=jnp.uint32)
-             * jnp.uint32(rc))[:, None]                # [steps, 1]
+        cw1_in, cw2_in = (jnp.transpose(c[:, :, 0].reshape(bp, steps, rc),
+                                        (1, 0, 2)) for c in (cw1, cw2))
+        cw_block = (pl.squeezed, tb, rc)
+        cw_maps = (lambda i, j: (j, i, 0)), (lambda j, i: (j, i, 0))
+    digits = (table if table.ndim == 3                 # [4, R*K, E] i8
+              else table_digits(table))
+    # the traced base row rides whole in SMEM; tiles add j * rc
+    row0 = jnp.asarray(row0, jnp.uint32).reshape(1)
 
     if grid_order == "bk":
         grid = (bp // tb, steps)
         j_axis, cw_map = 1, cw_maps[0]
-        maps = (lambda i, j: (j, 0),          # row0s
-                lambda i, j: (0, i, 0),       # seeds
-                lambda i, j: (0, j),          # table
+        maps = (lambda i, j: (0, i, 0),       # seeds
+                lambda i, j: (0, j, 0),       # table digits
                 lambda i, j: (i, 0))          # out
         semantics = (dim_semantics, "arbitrary")
     else:
         grid = (steps, bp // tb)
         j_axis, cw_map = 0, cw_maps[1]
-        maps = (lambda j, i: (j, 0),
-                lambda j, i: (0, i, 0),
-                lambda j, i: (0, j),
+        maps = (lambda j, i: (0, i, 0),
+                lambda j, i: (0, j, 0),
                 lambda j, i: (i, 0))
         semantics = ("arbitrary", dim_semantics)
 
@@ -285,19 +288,19 @@ def _sqrt_grid_contract_impl(seeds, cw1, cw2, table, row0, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), maps[0]),
-            pl.BlockSpec((4, tb, k), maps[1]),
-            cw_spec(cw_map),
-            cw_spec(cw_map),
-            pl.BlockSpec((e, rc * k), maps[2]),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((4, tb, k), maps[0]),
+            pl.BlockSpec(cw_block, cw_map),
+            pl.BlockSpec(cw_block, cw_map),
+            pl.BlockSpec((4, rc * k, e), maps[1]),
         ],
-        out_specs=pl.BlockSpec((tb, e), maps[3]),
+        out_specs=pl.BlockSpec((tb, e), maps[2]),
         out_shape=jax.ShapeDtypeStruct((bp, e), jnp.int32),
         interpret=interpret,
         # key tiles are independent; the row-tile axis accumulates into
         # the same [tb, E] output block (reduction dim -> "arbitrary")
         compiler_params=_compiler_params(semantics),
-    )(row0s, sm, cw1_in, cw2_in, table_t)
+    )(row0, sm, cw1_in, cw2_in, digits)
     return out[:bsz]
 
 

@@ -33,6 +33,7 @@ unchanged.
 
 from __future__ import annotations
 
+import os
 import pickle
 import socket
 import struct
@@ -269,9 +270,12 @@ def spawn_worker(config: dict, *, timeout_s: float = 60.0):
     n/entry_size/table_seed/prf_method (see cluster_worker.main)."""
     cfg = dict(config)
     cfg.setdefault("port", 0)
+    # a CPU rehearsal of several hosts on one machine: the children stay
+    # off the accelerator, which belongs to one process (the parent)
     proc = subprocess.Popen(
         [sys.executable, "-m", "dpf_tpu.parallel.cluster_worker",
          pickle.dumps(cfg).hex()],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
     deadline = time.monotonic() + timeout_s
     port = None

@@ -41,24 +41,14 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import expand, u128
-from ..core.expand import _level_step  # shared level recurrence
-
-# jax.shard_map graduated from jax.experimental in newer releases;
-# accept both so the mesh path runs on older jaxlibs too
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _pvary(x, axes):
-    """Type a shard_map scan carry as varying over the mesh axes.  On
-    jaxlibs without varying-types (no ``lax.pvary``) the carry mismatch
-    this guards against does not exist — identity is correct.  Empty
+    """Type a shard_map scan carry as varying over the mesh axes.  Empty
     ``axes`` (a caller outside any shard_map, e.g. the cluster tier's
-    host-local leaf-range eval) is always identity: ``lax.pvary`` over
-    axis names that don't exist would raise."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None and axes else x
+    host-local leaf-range eval) is identity: a cast over axis names
+    that don't exist would raise."""
+    return jax.lax.pcast(x, tuple(axes), to="varying") if axes else x
 
 
 def make_mesh(n_table: int | None = None, n_batch: int = 1,
@@ -77,7 +67,9 @@ def shard_table(table_i32: np.ndarray, mesh: Mesh):
     """Permute (bit-reversal) and row-shard a table over the "table" axis."""
     perm = expand.permute_table(np.asarray(table_i32, dtype=np.int32))
     sharding = NamedSharding(mesh, P("table", None))
-    return jax.device_put(jnp.asarray(perm), sharding)
+    # from host memory, so each device receives its own shard only (a jnp
+    # array here would first stage the whole table on device 0)
+    return jax.device_put(perm, sharding)
 
 
 def _valid_psum_group(psum_group, n_chunks: int) -> int:
@@ -106,8 +98,7 @@ def _scan_psum_groups(body, zeros, xs, axis_name: str,
     OUTER carry holds only psum outputs — invariant along ``axis_name``
     — so it is typed varying over ``outer_axes`` alone.  Typing it over
     the reduced axis too would trip shard_map's out_specs invariance
-    check on jaxlibs with varying types (``lax.pvary`` present); on
-    older jaxlibs both ``_pvary`` calls are identity.  The 2D row x
+    check.  The 2D row x
     entry-byte path passes ``outer_axes=("batch", "byte")``: its psum
     runs over "table" only, so the carry still varies over the byte
     axis (each byte shard holds a different entry block)."""
@@ -153,7 +144,7 @@ def eval_sharded(cw1, cw2, last, table_perm, *, depth: int, prf_method: int,
             axis_name="table")
         return out if psummed else jax.lax.psum(out, "table")
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P("batch"), P("batch"), P("batch"), P("table", None)),
         out_specs=P("batch", None))
@@ -192,7 +183,7 @@ def shard_table_2d(table_i32: np.ndarray, mesh: Mesh):
             "entry columns (%d) must divide over %d byte shards"
             % (perm.shape[1], mesh.shape["byte"]))
     sharding = NamedSharding(mesh, P("table", "byte"))
-    return jax.device_put(jnp.asarray(perm), sharding)
+    return jax.device_put(perm, sharding)
 
 
 @functools.partial(jax.jit,
@@ -239,7 +230,7 @@ def eval_sharded_2d(cw1, cw2, last, table_perm, *, depth: int,
             out = jax.lax.psum(out, "table")
         return out
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P("batch"), P("batch"), P("batch"), P("table", "byte")),
         out_specs=P("batch", "byte"))
@@ -276,19 +267,16 @@ def _eval_leaf_range(cw1, cw2, last, tbl, row0, *, depth: int,
     f_total = n_total // c                   # global frontier width
     f_levels = int(np.log2(f_total))
 
-    seeds = last[:, None, :]
-    for l in range(f_levels):
-        seeds = _level_step(seeds, cw1, cw2, depth - 1 - l, prf_method,
-                            aes_impl)
+    seeds = expand.expand_levels(last[:, None, :], cw1, cw2, depth - 1,
+                                 f_levels, prf_method, aes_impl)
     # take the local frontier window [row0/c, row0/c + f_local)
     node0 = row0 // c
     seeds = jax.lax.dynamic_slice_in_dim(seeds, node0, f_local, axis=1)
 
     def expand_subtree(node_seeds):
-        s = node_seeds[:, None, :]
-        for l in range(f_levels, depth):
-            s = _level_step(s, cw1, cw2, depth - 1 - l, prf_method,
-                            aes_impl)
+        s = expand.expand_levels(node_seeds[:, None, :], cw1, cw2,
+                                 depth - 1 - f_levels, depth - f_levels,
+                                 prf_method, aes_impl)
         return s[..., 0].astype(jnp.int32)
 
     tbl_chunks = tbl.reshape(f_local, c, e)
@@ -352,8 +340,7 @@ def shard_table_mixed(table_i32: np.ndarray, mesh: Mesh):
     tbl = np.asarray(table_i32, dtype=np.int32)
     perm = radix4.mixed_reverse_indices(radix4.arities(tbl.shape[0]))
     sharding = NamedSharding(mesh, P("table", None))
-    return jax.device_put(jnp.asarray(np.ascontiguousarray(tbl[perm])),
-                          sharding)
+    return jax.device_put(np.ascontiguousarray(tbl[perm]), sharding)
 
 
 def shard_table_sqrt(table_i32: np.ndarray, mesh: Mesh):
@@ -362,8 +349,7 @@ def shard_table_sqrt(table_i32: np.ndarray, mesh: Mesh):
     a contiguous N/shards row block is exactly R/shards whole grid rows
     for any key split whose R divides over the shards."""
     sharding = NamedSharding(mesh, P("table", None))
-    return jax.device_put(
-        jnp.asarray(np.asarray(table_i32, dtype=np.int32)), sharding)
+    return jax.device_put(np.asarray(table_i32, dtype=np.int32), sharding)
 
 
 @functools.partial(jax.jit,
@@ -432,7 +418,7 @@ def eval_sharded_mixed(cw1, cw2, last, table_perm, *, n: int,
             frontier.reshape(f_local // g, g, bsz, 4),
             tbl_chunks.reshape(f_local // g, g, c, e)), "table")
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P("batch"), P("batch"), P("batch"), P("table", None)),
         out_specs=P("batch", None))
@@ -517,6 +503,7 @@ class ShardedDPFServer:
         self.dot_impl = dot_impl
         self.kernel_impl = kernel_impl  # sqrtn: "xla" | "pallas" | None
         self._tuned_memo = {}  # batch -> (mesh-tuned, single-tuned) dicts
+        self._digits = None    # sqrt-N grid kernel's table, built once
 
     def _resolve_auto_scheme(self, batch_size: int, prf_method: int):
         """scheme="auto" -> the concrete construction, the DPF way:
@@ -692,8 +679,14 @@ class ShardedDPFServer:
                     note_swallowed("sharded.sqrt_kernel_unsupported",
                                    ValueError(reason))
                     kernel = "xla"
+            table = self.table_sharded
+            if kernel == "pallas":
+                if self._digits is None:  # [4, N, E] int8, rows sharded
+                    from ..ops.pallas_level import table_digits
+                    self._digits = table_digits(table)
+                table = self._digits
             return sqrtn.eval_sharded_sqrt(
-                pk.seeds, pk.cw1, pk.cw2, self.table_sharded,
+                pk.seeds, pk.cw1, pk.cw2, table,
                 prf_method=self.prf_method, mesh=self.mesh,
                 dot_impl=kn["dot_impl"], row_chunk=rc,
                 psum_group=kn["psum_group"], kernel_impl=kernel)

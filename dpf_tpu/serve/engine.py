@@ -135,8 +135,8 @@ class ServingEngine:
 
     ``deadline`` (a ``time.monotonic()`` value — immune to NTP steps;
     pass ``timeout_s`` to have the engine compute it) is checked
-    cooperatively between dispatches and resolutions — never mid-compile
-    (relay safety, docs/STATUS.md) — raising ``expand.DeadlineExceeded``
+    cooperatively between dispatches and resolutions — never mid-compile —
+    raising ``expand.DeadlineExceeded``
     and counting the trip in ``stats.deadline_misses``.
     """
 
@@ -270,7 +270,7 @@ class ServingEngine:
                 # Unwind a partially submitted batch: its dispatched parts
                 # must not stay orphaned in the window (the future is never
                 # returned), so block on each (never interrupt an in-flight
-                # program — relay safety) and drop it from the queue.
+                # program) and drop it from the queue.
                 for p in fut._parts:
                     try:
                         self._queue.remove(p)

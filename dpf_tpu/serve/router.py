@@ -505,7 +505,7 @@ class SchemeRouter:
 
     def dispatch_kernel(self, lb: str, bucket: int) -> str | None:
         """The bare ``kernel_impl`` of :meth:`dispatch_kernel_info`
-        (kept as the EWMA cost-table metrics label so a relay-TPU
+        (kept as the EWMA cost-table metrics label so a TPU
         ``--load`` run can attribute latency shifts to kernel
         selection)."""
         return self.dispatch_kernel_info(lb, bucket).get("kernel_impl")

@@ -8,7 +8,7 @@ maps ``fingerprint.cache_key`` strings to tuned-knob records:
 
     {"version": 1,
      "entries": {
-       "eval|cpu/cpu/x1/jax0.4.37+...|n16384.e16.b512.prf0.logn.r2": {
+       "eval|cpu/cpu/x1/jax0.9.0+...|n16384.e16.b512.prf0.logn.r2": {
          "knobs": {"chunk_leaves": 8192, "dot_impl": "i32",
                    "kernel_impl": "xla", "dispatch_group": null,
                    "aes_impl": "gather"},
@@ -38,21 +38,14 @@ _OFF = ("0", "off", "none", "disabled")
 VERSION = 1
 
 
-def env_cache_path(env_name: str, *default_tail: str) -> str | None:
-    """Shared env-var convention for the tune caches (this JSON cache
-    and compcache's XLA directory): unset -> the ~/.cache/dpf_tpu
-    default, "0"/"off"/"none"/"disabled" -> disabled (None), anything
-    else -> that path."""
-    v = os.environ.get(env_name)
+def default_path() -> str | None:
+    """Resolved cache file path: unset -> ~/.cache/dpf_tpu/tuning.json,
+    "0"/"off"/"none"/"disabled" -> None (disabled), else that path."""
+    v = os.environ.get(_ENV)
     if v is not None:
         return None if v.strip().lower() in _OFF or not v.strip() else v
     return os.path.join(os.path.expanduser("~"), ".cache", "dpf_tpu",
-                        *default_tail)
-
-
-def default_path() -> str | None:
-    """Resolved cache file path, or None when disabled via env."""
-    return env_cache_path(_ENV, "tuning.json")
+                        "tuning.json")
 
 
 class TuningCache:

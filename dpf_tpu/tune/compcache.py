@@ -1,23 +1,24 @@
 """JAX persistent compilation cache wiring (+ hit/miss counters).
 
 Tuned programs are worthless if every process pays the XLA compile
-again — cold-start warmup is real serving latency (the engine's
-``warmup()`` precompiles one program per bucket, which on the bitsliced
-AES configs is *minutes* of XLA work).  ``enable()`` points JAX's
-persistent compilation cache at a directory (default
-``~/.cache/dpf_tpu/xla_cache``, override ``DPF_TPU_COMPILE_CACHE=<dir>``,
-disable ``DPF_TPU_COMPILE_CACHE=0``) with the entry-size/compile-time
+again — cold-start warmup is real serving latency.  ``enable()`` turns
+JAX's persistent compilation cache on with the entry-size/compile-time
 floors removed, so *every* executable serializes; a second process then
 deserializes instead of recompiling.
 
-The serve path turns this on by default (``ServingEngine.__init__``) —
-batch/offline scripts opt in via ``enable()`` or ``benchmark.py
---autotune``.  A ``jax.monitoring`` listener mirrors the
-``/jax/compilation_cache/{cache_hits,cache_misses}`` events into
-``utils.profiling.CACHE_COUNTERS.compile_{hits,misses}`` (plus
+There is one way to place the cache: ``JAX_COMPILATION_CACHE_DIR``.
+When it is set, JAX itself reads it and nothing here sets another
+directory.  Otherwise the cache goes to the fixed path
+``<repo>/.jax_compile_cache`` (ignored by git) — fixed, because the
+path is part of what makes a later process find the entries.
+``DPF_TPU_COMPILE_CACHE=0`` turns the cache off (the test suite does).
+
+``DPF.eval_tpu``, the serving engine, the tuners, ``bench.py`` and
+``chip_smoke.py`` call ``enable()``.  A ``jax.monitoring`` listener
+mirrors the ``/jax/compilation_cache/{cache_hits,cache_misses}`` events
+into ``utils.profiling.CACHE_COUNTERS.compile_{hits,misses}`` (plus
 ``compile_time_saved_s``), giving tests and benchmark records a
-process-local view of recompiles skipped.  Verified working on the CPU
-backend with jax 0.4.37 (cache files appear, second process hits).
+process-local view of recompiles skipped.
 """
 
 from __future__ import annotations
@@ -27,15 +28,26 @@ import os
 from ..utils.profiling import CACHE_COUNTERS
 
 _ENV = "DPF_TPU_COMPILE_CACHE"
+_JAX_ENV = "JAX_COMPILATION_CACHE_DIR"
+REPO_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
 
 _ENABLED_DIR: str | None = None
 _LISTENING = False
 
 
 def default_dir() -> str | None:
-    """Resolved cache directory, or None when disabled via env."""
-    from .cache import env_cache_path
-    return env_cache_path(_ENV, "xla_cache")
+    """The directory ``enable()`` will use: ``JAX_COMPILATION_CACHE_DIR``
+    when set, else ``REPO_DIR``; None when ``DPF_TPU_COMPILE_CACHE=0``."""
+    off = os.environ.get(_ENV)
+    if off is not None:
+        if off.strip().lower() not in ("0", "off"):
+            raise ValueError(
+                "%s only takes 0 (cache off); place the cache with %s"
+                % (_ENV, _JAX_ENV))
+        return None
+    return os.environ.get(_JAX_ENV) or REPO_DIR
 
 
 def _listener(event: str, **kw) -> None:
@@ -56,48 +68,27 @@ def _install_listeners() -> None:
         return
     from jax import monitoring
     monitoring.register_event_listener(_listener)
-    try:
-        monitoring.register_event_duration_secs_listener(
-            _duration_listener)
-    except Exception:  # pragma: no cover — counter is best-effort
-        pass
+    monitoring.register_event_duration_secs_listener(_duration_listener)
     _LISTENING = True
 
 
-def enable(cache_dir: str | None = None) -> str | None:
+def enable() -> str | None:
     """Turn the persistent compilation cache on; returns the directory
-    in use (None when disabled via env).  Idempotent; safe to call after
-    backend init — only compiles *after* the call get cached.  If the
-    process already configured ``jax_compilation_cache_dir`` itself,
-    that configuration (dir and floors) is adopted untouched — only the
-    hit/miss counters are wired.
-    """
+    in use (None when off).  Idempotent; safe to call after backend
+    init — only compiles *after* the call get cached."""
     global _ENABLED_DIR
-    import jax
-    if cache_dir is None:
-        # never clobber a cache the process already configured (e.g. a
-        # relay script with its own dir + conservative floors): adopt
-        # it, wire the counters, and leave every setting alone
-        existing = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if existing and _ENABLED_DIR != existing:
-            _install_listeners()
-            _ENABLED_DIR = existing
-            return existing
-    d = cache_dir if cache_dir is not None else default_dir()
-    if d is None:
-        return None
-    if _ENABLED_DIR == d:
+    d = default_dir()
+    if d is None or _ENABLED_DIR == d:
         return d
+    import jax
     os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
+    if not os.environ.get(_JAX_ENV):
+        jax.config.update("jax_compilation_cache_dir", d)
     # cache everything: the default floors (1 s compile, 0-byte entry)
     # skip exactly the small per-level programs the dispatch kernel and
     # the bucket ladder produce in bulk
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # pragma: no cover — older jax without the knob
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _install_listeners()
     _ENABLED_DIR = d
     return d

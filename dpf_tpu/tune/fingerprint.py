@@ -25,7 +25,7 @@ from __future__ import annotations
 
 def device_fingerprint() -> str:
     """Stable id of the measuring hardware+toolchain, e.g.
-    ``cpu/cpu/x1/jax0.4.37+jaxlib0.4.36``."""
+    ``cpu/cpu/x1/jax0.9.0+jaxlib0.9.0``."""
     import jax
     try:
         import jaxlib

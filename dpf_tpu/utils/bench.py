@@ -133,8 +133,8 @@ def test_matmul_perf(B=512, K=65536, E=16, reps=10, quiet=False):
         out.block_until_ready()
         elapsed = time.time() - t0
         r = {"impl": name, "B": B, "K": K, "E": E, "reps": reps,
-             "elapsed_s": round(elapsed, 4),
-             "gops_per_sec": round(2e-9 * B * K * E * reps / elapsed, 2)}
+             "elapsed_s": elapsed,
+             "gops_per_sec": 2e-9 * B * K * E * reps / elapsed}
         results[name] = r
         if not quiet:
             print(json.dumps(r))

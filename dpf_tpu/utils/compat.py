@@ -1,72 +1,21 @@
-"""Capability probes for jax/jaxlib features the repo degrades around.
-
-The pinned container toolchain (jax/jaxlib 0.4.37) predates several
-features the test suite and the mesh path lean on; each probe here
-answers "can THIS process do X" so callers (tests, mostly) can skip
-cleanly instead of failing on a known toolchain gap.  Everything is a
-cheap attribute/version check — no backend initialization, so the
-probes are safe to call before ``hermetic.force_cpu_mesh``.
-"""
+"""Backend probes: can THIS process run the Pallas TPU kernels, and
+what device memory does it see."""
 
 from __future__ import annotations
 
 
-def jax_version() -> tuple:
-    """jax's version as an int tuple (best effort: non-int parts drop)."""
-    import jax
-    out = []
-    for part in jax.__version__.split("."):
-        digits = "".join(c for c in part if c.isdigit())
-        if not digits:
-            break
-        out.append(int(digits))
-    return tuple(out)
-
-
-def has_tpu_interpret_mode() -> bool:
-    """True when Pallas ships the TPU-semantics interpreter
-    (``pltpu.force_tpu_interpret_mode``, jax >= 0.4.38).  Without it the
-    interpret-mode kernel tests cannot run on this host: the generic
-    ``interpret=True`` engine compiles interpreted grids with XLA-CPU
-    and blows up super-linearly (tests/test_pallas_level.py docstring).
-    """
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except Exception:  # pragma: no cover - pallas not shipped at all
-        return False
-    return hasattr(pltpu, "force_tpu_interpret_mode")
-
-
 def has_pallas_sqrt_kernel(backend: str | None = None) -> bool:
-    """True when the fused sqrt-N grid kernel (``ops/pallas_sqrt.py``)
-    can compile AND run in this process: Pallas importable and the
-    backend is TPU.  Elsewhere resolvers degrade to ``kernel_impl=
-    "xla"`` with provenance (``api.resolved_eval_knobs`` reports
-    ``kernel_resolved_from="degraded"`` and counts it via
-    ``note_swallowed``) — the generic ``interpret=True`` engine is a
-    debugging device, not a serving path (``has_tpu_interpret_mode``).
-    Pass ``backend`` to probe without initializing one."""
-    try:
-        from jax.experimental import pallas  # noqa: F401
-    except Exception:  # pragma: no cover - pallas not shipped at all
-        return False
+    """True when the Pallas TPU kernels can compile AND run in this
+    process: the backend is TPU.  Elsewhere resolvers degrade a tuned
+    or searched ``kernel_impl="pallas"`` to ``"xla"`` with provenance
+    (``api.resolved_eval_knobs`` reports ``kernel_resolved_from=
+    "degraded"`` and counts it via ``note_swallowed``) — the interpreter
+    is a debugging device, not a serving path.  Pass ``backend`` to
+    probe without initializing one."""
     if backend is None:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:  # pragma: no cover - no usable backend
-            return False
+        import jax
+        backend = jax.default_backend()
     return backend == "tpu"
-
-
-def has_effects_barrier() -> bool:
-    """True when ``jax.effects_barrier()`` exists (jax >= 0.4.x late
-    line).  ``utils.profiling.Timer`` uses it to drain ALL in-flight
-    async dispatches at exit; the legacy fallback — blocking on a fresh
-    ``jnp.zeros(())`` — only proves one new dispatch completed, which
-    on TPU leaves prior independent work un-drained."""
-    import jax
-    return callable(getattr(jax, "effects_barrier", None))
 
 
 def device_memory_stats(device=None) -> dict | None:
@@ -95,12 +44,3 @@ def device_memory_stats(device=None) -> dict | None:
     except Exception:  # pragma: no cover - backend-specific failures
         return None
     return dict(out) if out else None
-
-
-def has_cpu_multiprocess() -> bool:
-    """True when the CPU backend supports multi-process computations
-    (cross-process collectives).  jaxlib 0.4.x's CPU client raises
-    ``INVALID_ARGUMENT: Multiprocess computations aren't implemented on
-    the CPU backend`` from the first sharded ``device_put``; the
-    capability landed in the 0.5 line."""
-    return jax_version() >= (0, 5)

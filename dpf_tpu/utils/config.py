@@ -48,7 +48,7 @@ class EvalConfig:
     chunk_leaves: int | None = None  # None = auto (tuned, else choose_chunk)
     dot_impl: str | None = "i32"   # "i32" | "mxu" (ops/matmul128) |
     #                 None/"auto" (tuned, else module default)
-    round_unroll: bool | None = None  # None = auto (unroll on TPU)
+    round_unroll: bool | None = None  # None = auto (rolled rounds)
     aes_impl: str = "auto"  # "auto"|"gather"|"bitsliced"[":bp"|":tower"]
     kernel_impl: str | None = "xla"  # "xla" | "pallas" (ChaCha/Salsa subtree
     #                 kernel) | "dispatch" (per-level programs; fast compile)

@@ -454,18 +454,15 @@ class Timer:
     The old exit barrier — ``block_until_ready(jnp.zeros(()))`` — only
     proves ONE fresh dispatch finished; on an asynchronous backend (TPU)
     independent prior computations may still be in flight, so the timer
-    under-reported.  The exit now drains via ``jax.effects_barrier()``
-    when the runtime has it (probed once through ``utils.compat``),
-    else blocks on the outputs handed to ``note()``, and only as a last
-    resort falls back to the legacy zeros sync."""
+    under-reported.  The exit blocks on the outputs handed to the
+    constructor or ``note()``, then drains via ``jax.effects_barrier()``."""
 
     def __init__(self, *outputs):
         self.elapsed = 0.0
         self._outputs = list(outputs)
 
     def note(self, *outputs) -> "Timer":
-        """Register result arrays the exit barrier must block on when
-        ``jax.effects_barrier`` is unavailable."""
+        """Register result arrays the exit barrier must block on."""
         self._outputs.extend(outputs)
         return self
 
@@ -476,13 +473,9 @@ class Timer:
     def __exit__(self, *exc):
         import jax
 
-        from . import compat
-        # drain any async dispatch before stopping the clock
-        if compat.has_effects_barrier():
-            jax.effects_barrier()
-        elif self._outputs:
+        # drain async dispatch before stopping the clock
+        if self._outputs:
             jax.block_until_ready(self._outputs)
-        else:
-            jax.block_until_ready(jax.numpy.zeros(()))
+        jax.effects_barrier()
         self.elapsed = time.perf_counter() - self._t0
         return False

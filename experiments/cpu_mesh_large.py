@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Large-N functional run on the virtual 8-device CPU mesh.
 
-VERDICT.md (round 2) item 4 asks for "an 8-way CPU-mesh functional run at
-the largest N memory allows" to back the large-N story with an executed
+An 8-way CPU-mesh functional run at the largest N memory allows, to back the large-N story with an executed
 multi-device data point (the reference exercises 2^22..2^26 single-GPU in
 ``paper/kernel/gpu/scripts/sweep.sh:3-14`` and claims 2^32 support,
 ``README.md:119``; the TPU build's 2^32 path is the row-sharded mesh in

@@ -89,3 +89,29 @@ def test_end_to_end_dpf_with_bitsliced_aes():
         assert (a == a2).all()
     finally:
         prf.AES_PAIR_IMPL = old
+
+
+@pytest.mark.parametrize("w0,levels,batch", [(1, 6, 8), (4, 4, 3)])
+def test_tiled_levels_match_per_level_steps(monkeypatch, w0, levels, batch):
+    """Bitsliced AES runs its levels through one tiled level program
+    (``expand._expand_tiled``); with a tile of a few nodes, levels
+    narrower than a tile (padded) and wider (several tiles, last first)
+    both occur, and the seeds equal the per-level gather-AES chain."""
+    import jax.numpy as jnp
+
+    from dpf_tpu.core import expand
+    monkeypatch.setattr(expand, "TILE_SEEDS", 8 * batch)
+    rng = np.random.default_rng(w0)
+    seeds = rng.integers(0, 2 ** 32, (batch, w0, 4), dtype=np.uint32)
+    cw1, cw2 = (rng.integers(0, 2 ** 32, (batch, 64, 4), dtype=np.uint32)
+                for _ in range(2))
+    top = 20
+    want = jnp.asarray(seeds)
+    for i in range(top, top - levels, -1):
+        want = expand._level_step(want, cw1, cw2, i, prf.PRF_AES128,
+                                  "gather")
+    got = expand.expand_levels(jnp.asarray(seeds), jnp.asarray(cw1),
+                               jnp.asarray(cw2), top, levels,
+                               prf.PRF_AES128, "bitsliced")
+    assert expand._tile(batch, w0 << levels) == 8
+    assert np.array_equal(np.asarray(got), np.asarray(want))

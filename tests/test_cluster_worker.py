@@ -83,10 +83,9 @@ def test_two_process_cluster_survives_host_kill():
     mid-stream, assert the flight-recorded drop -> degrade decision and
     bit-exact answers before AND after the loss.
 
-    ISSUE r14 asked for this gated on ``has_cpu_multiprocess`` — but
-    the socket tier needs no cross-process jax collectives (each worker
-    is its own single-process runtime), so it runs on every toolchain;
-    only a sandbox that cannot spawn subprocesses skips.
+    The socket tier needs no cross-process jax collectives (each worker
+    is its own single-process runtime); only a sandbox that cannot
+    spawn subprocesses skips.
     """
     from dpf_tpu.obs.flight import FLIGHT, flight_dump
     from dpf_tpu.parallel.cluster import ClusterRouter

@@ -2,7 +2,7 @@
 
 Each loader must produce the same dataclass contract as the synthetic
 generators (datasets.py) so the whole experiment stack runs unchanged on
-real files (VERDICT r2 item 5).
+real files.
 """
 
 import os

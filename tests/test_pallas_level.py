@@ -9,26 +9,17 @@ runs the same case in ~2 s AND models the Mosaic memory spaces the real
 kernel will see.  Every test here therefore uses the TPU interpreter;
 cases stay tiny while covering the structure that matters: multiple key
 tiles, multiple width tiles, multiple frontier subtrees, both ciphers,
-both radices.  On TPU the same kernels compile for real
-(experiments/tpu_all.py tuning stage).
+both radices.  Their compiles for a described v5e are in
+tests/test_tpu_compile.py; chip_smoke.py runs them on the chip.
 """
 
 import numpy as np
-import pytest
 
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 from dpf_tpu.core import expand, keygen
-from dpf_tpu.utils.compat import has_tpu_interpret_mode
 
-# the TPU-semantics interpreter these tests depend on shipped after the
-# container's jax 0.4.37 — without it they can only fail (AttributeError
-# here, or an XLA-CPU interpreted-grid compile blowup, see module
-# docstring), so they skip as a known toolchain gap, not a regression
-needs_tpu_interpret = pytest.mark.skipif(
-    not has_tpu_interpret_mode(),
-    reason="pltpu.force_tpu_interpret_mode unavailable (jax >= 0.4.38)")
 
 
 def _keys(n, n_keys, method=2):
@@ -56,12 +47,10 @@ def _level_case(width_levels, n_keys=1, tb=4, tw=2):
     assert (np.asarray(got) == np.asarray(want)).all()
 
 
-@needs_tpu_interpret
 def test_pallas_chacha_level_matches_portable():
     _level_case(0)
 
 
-@needs_tpu_interpret
 def test_pallas_chacha_level_multi_tile():
     """Several (batch, width) grid tiles — same tiny kernel, real tiling:
     3 keys pad to 4 = 2 tb-tiles of 2; width 4 = 2 tw-tiles of 2."""
@@ -92,25 +81,21 @@ def _subtree_case(n, n_keys, chunk, tb=None, method=2):
     assert (np.asarray(got) == np.asarray(want)).all()
 
 
-@needs_tpu_interpret
 def test_pallas_subtree_contract_minimal():
     # 2 subtrees of 64 leaves, 2 keys (padded to one tile of 8)
     _subtree_case(128, 2, 64)
 
 
-@needs_tpu_interpret
 def test_pallas_subtree_contract_salsa():
     _subtree_case(128, 2, 64, method=1)
 
 
-@needs_tpu_interpret
 def test_pallas_subtree_contract_multi_tile():
     # several key tiles (10 keys, tb=4 -> 3 tiles) and 4 frontier nodes,
     # same small per-tile kernel as the minimal case
     _subtree_case(256, 10, 64, tb=4)
 
 
-@needs_tpu_interpret
 def test_pallas_subtree_mixed_radix4():
     """Radix-4 ChaCha through the mixed-arity subtree kernel
     (subtree_contract_pallas_mixed) vs the XLA mixed-radix path."""

@@ -2,15 +2,13 @@
 
 Two interpreter lanes, same trade-off as test_pallas_level.py:
 
-- ``interpret=True`` (the generic Pallas interpreter) runs EAGERLY on
-  any backend including the container's jax 0.4.37, so the small parity
-  cases and the full-API-path test below always execute — they are the
+- ``interpret=True`` (the generic Pallas interpreter) runs EAGERLY, so
+  the small parity cases and the full-API-path test below are the
   tier-1 guarantee that the kernel is bit-identical to the scan oracle.
-- ``pltpu.force_tpu_interpret_mode()`` (TPU-semantics interpreter,
-  jax >= 0.4.38) models the Mosaic memory spaces and runs the REAL
-  jit-wrapped entry point; those tests skip on older jax as a known
-  toolchain gap, not a regression.  On an actual TPU they compile for
-  real.
+- ``pltpu.force_tpu_interpret_mode()`` (the TPU-semantics interpreter)
+  models the Mosaic memory spaces and runs the REAL jit-wrapped entry
+  point.  On an actual TPU the kernels compile for real; their
+  compiles for a described v5e are in ``tests/test_tpu_compile.py``.
 
 The knob-resolution tests (degradation provenance, old-grammar cache
 entries, the row_chunk riding rule) are plain CPU tests: the whole
@@ -30,12 +28,8 @@ from jax.experimental.pallas import tpu as pltpu
 import dpf_tpu
 from dpf_tpu.core import prf_ref, sqrtn
 from dpf_tpu.ops import pallas_sqrt
-from dpf_tpu.utils.compat import has_tpu_interpret_mode
 from dpf_tpu.utils.config import EvalConfig
 
-needs_tpu_interpret = pytest.mark.skipif(
-    not has_tpu_interpret_mode(),
-    reason="pltpu.force_tpu_interpret_mode unavailable (jax >= 0.4.38)")
 
 PLANE_PRFS = [prf_ref.PRF_SALSA20, prf_ref.PRF_CHACHA20,
               prf_ref.PRF_SALSA20_BLK, prf_ref.PRF_CHACHA20_BLK]
@@ -179,7 +173,6 @@ def test_api_shape_gate_degrades_unsupported_prf(monkeypatch):
 # ------------------------------------------- TPU-interpreter parity fuzz
 
 
-@needs_tpu_interpret
 @pytest.mark.parametrize("prf_method", PLANE_PRFS)
 @pytest.mark.parametrize("n,n_keys", [(64, None), (64, 16), (256, None)])
 def test_grid_kernel_parity_tpu_interpret(prf_method, n, n_keys):
@@ -197,7 +190,6 @@ def test_grid_kernel_parity_tpu_interpret(prf_method, n, n_keys):
         assert np.array_equal(got, oracle), (prf_method, n, n_keys, rc)
 
 
-@needs_tpu_interpret
 def test_grid_kernel_traced_row0_tpu_interpret():
     """row0 through the jit boundary (traced, the sharded path's
     contract): half-grids at both ciphers sum to the full oracle."""
@@ -221,7 +213,6 @@ def test_grid_kernel_traced_row0_tpu_interpret():
     reason="large-N grid-kernel cell (N=2^18, B=512) runs in the "
            "DPF_RUN_SLOW lane; the small parity cells above cover the "
            "kernel structure per-commit")
-@needs_tpu_interpret
 def test_grid_kernel_large_n_bounded_vmem():
     """Acceptance cell mirroring test_sqrt_bounded_memory_large_grid:
     N=2^18 at B=512 — the kernel's VMEM cell cap must engage (rc*K <=
@@ -449,3 +440,36 @@ def test_router_route_event_records_kernel(monkeypatch):
     text = reg.openmetrics()
     assert ('dpf_router_cost_seconds{bucket="4",construction="sqrtn",'
             'kernel="xla"} 0.002' in text)
+
+
+def test_sharded_server_builds_digit_planes_once(eight_devices,
+                                                 monkeypatch):
+    """The mesh server's grid kernel reads the table's int8 digit planes,
+    built once (sharded like the table) and reused by every call; the
+    shares equal the host reference."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dpf_tpu.parallel import sharded
+    from dpf_tpu.utils import compat
+    monkeypatch.setattr(compat, "has_pallas_sqrt_kernel",
+                        lambda backend=None: True)
+    n = 1024
+    rng = np.random.default_rng(5)
+    table = rng.integers(-2 ** 31, 2 ** 31, (n, 3),
+                         dtype=np.int64).astype(np.int32)
+    mesh = sharded.make_mesh(n_table=4, n_batch=2)
+    srv = sharded.ShardedDPFServer(table, mesh,
+                                   prf_method=dpf_tpu.PRF_CHACHA20,
+                                   scheme="sqrtn", kernel_impl="pallas")
+    d = dpf_tpu.DPF(prf=dpf_tpu.PRF_CHACHA20, scheme="sqrtn")
+    d.eval_init(table)
+    keys = [d.gen(i * 97 % n, n)[0] for i in range(4)]
+    assert srv.resolved_eval_knobs(4)["kernel_resolved_from"] == "config"
+    with pltpu.force_tpu_interpret_mode():
+        got = srv.eval(keys)
+        digits = srv._digits
+        again = srv.eval(keys)
+    assert digits is not None and srv._digits is digits
+    assert digits.shape == (4, n, 3)
+    assert np.array_equal(got, np.asarray(d.eval_cpu(keys)))
+    assert np.array_equal(again, got)

@@ -157,13 +157,6 @@ def test_blk_api_round_trip_engines():
 def test_blk_pallas_subtree_interpret():
     """Fused Pallas subtree kernel with the block core (one core call
     per node per level) vs the XLA path — TPU-semantics interpreter."""
-    from dpf_tpu.utils.compat import has_tpu_interpret_mode
-    if not has_tpu_interpret_mode():
-        # known toolchain gap, not a regression: the TPU-semantics
-        # interpreter shipped after jax 0.4.37 (and the generic
-        # interpret engine blows up on XLA-CPU — test_pallas_level.py)
-        pytest.skip("pltpu.force_tpu_interpret_mode unavailable "
-                    "(jax >= 0.4.38)")
     from jax.experimental.pallas import tpu as pltpu
 
     from dpf_tpu.ops import pallas_level

@@ -108,8 +108,6 @@ def test_timer_blocks_on_device():
 def test_timer_exit_uses_effects_barrier(monkeypatch):
     import jax
 
-    from dpf_tpu.utils import compat
-    assert compat.has_effects_barrier()   # pinned jax 0.4.37 has it
     called = []
     monkeypatch.setattr(jax, "effects_barrier",
                         lambda: called.append(True))
@@ -118,11 +116,9 @@ def test_timer_exit_uses_effects_barrier(monkeypatch):
     assert called == [True]
 
 
-def test_timer_exit_fallback_blocks_on_noted_outputs(monkeypatch):
+def test_timer_exit_blocks_on_noted_outputs(monkeypatch):
     import jax
 
-    from dpf_tpu.utils import compat
-    monkeypatch.setattr(compat, "has_effects_barrier", lambda: False)
     blocked = []
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda x: blocked.append(x) or x)
@@ -131,9 +127,9 @@ def test_timer_exit_fallback_blocks_on_noted_outputs(monkeypatch):
         pass
     assert blocked == [[a, b]]
     blocked.clear()
-    with Timer():                         # no outputs: legacy zeros sync
+    with Timer():                         # no outputs: the barrier alone
         pass
-    assert len(blocked) == 1 and not isinstance(blocked[0], list)
+    assert blocked == []
 
 
 # ------------------------------------------------------- EngineCounters

@@ -1,0 +1,187 @@
+"""Compile the main path's kernels for a described TPU v5e, with no chip.
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached (``on-chip-measurement`` guide, section 2).
+That catches what interpret mode cannot: block shapes Mosaic refuses,
+scoped-VMEM overflow, programs that do not fit HBM.  Nothing runs, so
+these tests say nothing about results (the interpret-mode parity tests
+do) or times (only the chip does).
+
+Only this file describes the chip.  The topology is built inside a
+module-scoped fixture, never at import: one process at a time may load
+the TPU library, and every xdist worker imports every test file.  The
+persistent compilation cache is off around the compiles (an entry
+written for a described device cannot be read back without one).
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+B = 512          # the reference's batch
+N = 1 << 20      # the reference's largest table
+E = 16           # int32 words per entry
+U32, I32 = jnp.uint32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def compile_tpu(topo):
+    """compile(fn, *shapes) -> optimized HLO text, on one described chip
+    (``sharding=None``) or on the shardings the shapes carry."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topo.devices[0])
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *shapes):
+        args = [s if s.sharding is not None
+                else jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)
+                for s in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", prior)
+    compilation_cache.reset_cache()
+
+
+def S(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _level_step():
+    from dpf_tpu.ops.pallas_level import _chacha_level_step_impl
+    return (_chacha_level_step_impl,
+            S((B, 4096, 4), U32), S((B, 2, 4), U32), S((B, 2, 4), U32))
+
+
+def _subtree(prf):
+    from dpf_tpu.ops.pallas_level import (_subtree_contract_pallas_impl,
+                                          pallas_chunk_leaves)
+    depth = N.bit_length() - 1
+    c = pallas_chunk_leaves(N)
+    fl = depth - (c.bit_length() - 1)
+
+    def fn(f, a, b, t):
+        return _subtree_contract_pallas_impl(
+            f, a, b, t, depth=depth, f_levels=fl, prf_method=prf)
+    return (fn, S((B, N // c, 4), U32), S((B, 64, 4), U32),
+            S((B, 64, 4), U32), S((N, E), I32))
+
+
+def _mixed(prf):
+    from dpf_tpu.core import radix4
+
+    def fn(cw1, cw2, last, t):
+        return radix4._expand_contract_mixed_pallas_jit(
+            cw1, cw2, last, t, n=N, prf_method=prf, interpret=False)
+    return (fn, S((B, 64, 4), U32), S((B, 64, 4), U32), S((B, 4), U32),
+            S((N, E), I32))
+
+
+def _sqrt(prf, order):
+    from dpf_tpu.core import sqrtn
+    from dpf_tpu.ops.pallas_sqrt import _sqrt_grid_contract_impl
+    k, r = sqrtn.default_split(N)
+    b = B if order == "bk" else 32    # "kb" needs one key tile
+
+    def fn(s, c1, c2, t):
+        return _sqrt_grid_contract_impl(s, c1, c2, t, 0, prf_method=prf,
+                                        grid_order=order)
+    return (fn, S((b, k, 4), U32), S((b, r, 4), U32), S((b, r, 4), U32),
+            S((N, E), I32))
+
+
+def _planes(arity):
+    from dpf_tpu.ops.aes_planes import _aes_level_step_impl
+
+    def fn(s, c1, c2):
+        return _aes_level_step_impl(s, c1, c2, arity=arity)
+    return (fn, S((B, 256, 4), U32), S((B, arity, 4), U32),
+            S((B, arity, 4), U32))
+
+
+# prf ids: 1 Salsa20, 2 ChaCha20, 3 AES-128, 4 Salsa20-BLK, 5 ChaCha20-BLK
+PALLAS_CASES = {
+    "level_step.chacha": _level_step,
+    "subtree.chacha": lambda: _subtree(2),
+    "subtree.salsa": lambda: _subtree(1),
+    "subtree.chacha_blk": lambda: _subtree(5),
+    "subtree.salsa_blk": lambda: _subtree(4),
+    "mixed.chacha": lambda: _mixed(2),
+    "mixed.chacha_blk": lambda: _mixed(5),
+    "sqrt.chacha.bk": lambda: _sqrt(2, "bk"),
+    "sqrt.chacha.kb": lambda: _sqrt(2, "kb"),
+    "sqrt.salsa.bk": lambda: _sqrt(1, "bk"),
+    "sqrt.salsa.kb": lambda: _sqrt(1, "kb"),
+    "planes.aes.a2": lambda: _planes(2),
+    "planes.aes.a4": lambda: _planes(4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PALLAS_CASES))
+def test_pallas_kernel_compiles_for_v5e(compile_tpu, case):
+    fn, *shapes = PALLAS_CASES[case]()
+    assert "tpu_custom_call" in compile_tpu(fn, *shapes)
+
+
+@pytest.mark.parametrize("prf,unroll", [(2, True), (3, None)])
+def test_xla_fused_path_compiles_for_v5e(compile_tpu, prf, unroll):
+    """The default path (``kernel_impl="xla"``) at N = 2^16 with the TPU
+    defaults spelled out (this process's backend is the CPU): ChaCha20
+    with unrolled rounds, and bitsliced AES, whose levels share one
+    tiled level program with rolled rounds."""
+    from dpf_tpu.core import expand
+    n = 1 << 16
+    depth = n.bit_length() - 1
+    chunk = expand.clamp_chunk(None, n, B)
+
+    def fn(cw1, cw2, last, t):
+        return expand.expand_and_contract(
+            cw1, cw2, last, t, depth=depth, prf_method=prf,
+            chunk_leaves=chunk, aes_impl="bitsliced", round_unroll=unroll)
+    compile_tpu(fn, S((B, 64, 4), U32), S((B, 64, 4), U32),
+                S((B, 4), U32), S((n, E), I32))
+
+
+def test_sharded_binary_eval_compiles_on_four_chips(compile_tpu, topo):
+    """The row-sharded binary ChaCha20 eval (what ``chip_smoke.py
+    --multichip`` serves) over all four described chips, with every
+    argument's ``NamedSharding`` on the described mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dpf_tpu.core import expand
+    from dpf_tpu.parallel import sharded
+    mesh = sharded.make_mesh(devices=np.asarray(topo.devices))
+    depth = N.bit_length() - 1
+    chunk = expand.clamp_chunk(None, N // 4, B)
+
+    def on(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def fn(cw1, cw2, last, t):
+        return sharded.eval_sharded(
+            cw1, cw2, last, t, depth=depth, prf_method=2,
+            chunk_leaves=chunk, mesh=mesh)
+    text = compile_tpu(fn, on((B, 64, 4), U32, P("batch")),
+                       on((B, 64, 4), U32, P("batch")),
+                       on((B, 4), U32, P("batch")),
+                       on((N, E), I32, P("table", None)))
+    assert "all-reduce" in text
