@@ -14,7 +14,6 @@
 #   --multichip        serve/bench_multichip.py     MULTICHIP_r06.json
 #   --load             serve/bench_load.py          BENCH_LOAD_r10.json
 #   --chaos            serve/bench_chaos.py         BENCH_CHAOS_r11.json
-#   --trace            obs/bench_trace.py           BENCH_TRACE_r12.json
 #   --multihost        serve/bench_multihost.py     MULTIHOST_r14.json
 #   --multitenant      serve/bench_multitenant.py   MULTITENANT_r16.json
 #   --plan             plan/bench_plan.py           PLAN_r17.json
@@ -114,14 +113,6 @@
 # the twin's paging-stall fidelity legs); --dryrun is the seconds-long
 # CI smoke.  See docs/SHARDING.md "2D sharding" and docs/PLANNING.md
 # "Memory-aware planning".
-#
-# --trace: end-to-end observability — span tracing over the serving
-# path with a joint host+device digest for one tuned shape, the
-# OpenMetrics snapshot (engine/router/breaker series), a chaos slice
-# whose flight-recorder dump attributes injected faults to their route
-# decisions, and the measured tracing-on vs tracing-off qps delta on
-# the bursty trace (gated at <= 2%); --dryrun is the seconds-long CI
-# smoke.  See docs/OBSERVABILITY.md.
 
 import sys
 
@@ -265,10 +256,6 @@ if __name__ == "__main__":
     if "--plan" in sys.argv:
         from dpf_tpu.plan.bench_plan import main
         main([a for a in sys.argv[1:] if a != "--plan"])
-        sys.exit(0)
-    if "--trace" in sys.argv:
-        from dpf_tpu.obs.bench_trace import main
-        main([a for a in sys.argv[1:] if a != "--trace"])
         sys.exit(0)
     if "--autotune-kernel" in sys.argv:
         _autotune_kernel_main(

@@ -19,6 +19,7 @@ import os
 import numpy as np
 
 from .core import evalref, expand, keygen
+from .obs.tracer import span
 from .utils.config import check_construction
 from .core.prf_ref import (PRF_AES128, PRF_CHACHA20, PRF_CHACHA20_BLK,
                            PRF_DUMMY, PRF_NAMES, PRF_SALSA20,
@@ -419,17 +420,18 @@ class DPF(object):
         eff = len(keys)
         if eff == 0:
             raise ValueError("empty key batch")
-        results = []
-        for i in range(0, eff, self.BATCH_SIZE):
-            cur = keys[i:i + self.BATCH_SIZE]
-            n_real = len(cur)
-            # pad to the next power of two (bounded compile-cache churn,
-            # reference pads to a fixed 512: dpf.py:123-126)
-            cur = cur + [cur[-1]] * (self._pow2_domain(n_real) - n_real)
-            # trim per chunk: with a non-power-of-two BATCH_SIZE, pad rows
-            # would otherwise land mid-output
-            results.append(self._eval_batch(cur)[:n_real])
-        out = np.concatenate(results)[:, :self.table_effective_entry_size]
+        with span("eval_tpu", batch=eff):
+            results = []
+            for i in range(0, eff, self.BATCH_SIZE):
+                cur = keys[i:i + self.BATCH_SIZE]
+                n_real = len(cur)
+                # pad to the next power of two (bounded compile-cache
+                # churn, reference pads to a fixed 512: dpf.py:123-126)
+                cur = cur + [cur[-1]] * (self._pow2_domain(n_real) - n_real)
+                # trim per chunk: with a non-power-of-two BATCH_SIZE, pad
+                # rows would otherwise land mid-output
+                results.append(self._eval_batch(cur)[:n_real])
+            out = np.concatenate(results)[:, :self.table_effective_entry_size]
         return _maybe_torch(out, self._torch_io)
 
     # Reference scripts call eval_gpu; on this framework that IS the TPU.
@@ -529,7 +531,14 @@ class DPF(object):
         return sk
 
     def _eval_batch(self, keys) -> np.ndarray:
-        return np.asarray(self._dispatch_packed(self._decode_batch(keys)))
+        # the spans live here, not in the two methods: the serving engine
+        # calls those under its own ``pack`` and ``dispatch`` spans
+        with span("eval_tpu.decode", batch=len(keys)):
+            pk = self._decode_batch(keys)
+        with span("eval_tpu.dispatch"):
+            dev = self._dispatch_packed(pk)
+        with span("eval_tpu.fetch"):   # the device's wait and the copy back
+            return np.asarray(dev)
 
     def _decode_batch(self, keys):
         """Vectorized ingest: wire keys -> packed batch, validated

@@ -118,21 +118,37 @@ def _level_step_pair(seeds, cw1_pair, cw2_pair, prf_method: int,
     """One GGM level with this level's codeword pairs passed directly.
 
     seeds [B, w, 4]; cw*_pair [B, 2, 4] (branch, limb) -> [B, 2w, 4]."""
-    sel = (seeds[..., 0] & np.uint32(1)).astype(bool)[..., None]  # [B, w, 1]
-    prf_out = prf_pair(prf_method, seeds, aes_impl, round_unroll)
-    children = []
-    for b in (0, 1):
-        cw = jnp.where(sel, cw2_pair[:, None, b, :],
-                       cw1_pair[:, None, b, :])           # [B, w, 4]
-        children.append(u128.add128(prf_out[b], cw))
-    stacked = jnp.stack(children, axis=2)                 # [B, w, 2, 4]
-    bsz, w = seeds.shape[0], seeds.shape[1]
-    return stacked.reshape(bsz, 2 * w, 4)
+    with jax.named_scope("dpf.prf"):
+        prf_out = prf_pair(prf_method, seeds, aes_impl, round_unroll)
+    with jax.named_scope("dpf.cw_add"):
+        sel = (seeds[..., 0] & np.uint32(1)).astype(bool)[..., None]
+        children = []
+        for b in (0, 1):
+            cw = jnp.where(sel, cw2_pair[:, None, b, :],
+                           cw1_pair[:, None, b, :])       # [B, w, 4]
+            children.append(u128.add128(prf_out[b], cw))
+        stacked = jnp.stack(children, axis=2)             # [B, w, 2, 4]
+        bsz, w = seeds.shape[0], seeds.shape[1]
+        return stacked.reshape(bsz, 2 * w, 4)
 
 
-_level_step_jit = jax.jit(_level_step_pair,
-                          static_argnames=("prf_method", "aes_impl",
-                                           "round_unroll"))
+def _scoped(scope: str, fn):
+    """``fn`` traced under ``jax.named_scope(scope)``.  A program that
+    is dispatched on its own is traced afresh, outside any scope open
+    at its call site, so its phase's name has to go inside it."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+    return run
+
+
+# one level program per phase of eval_dispatch: (seeds, cw1 pair, cw2
+# pair, prf_method, aes_impl, round_unroll), the last three static
+_frontier_step_jit = jax.jit(_scoped("dpf.frontier", _level_step_pair),
+                             static_argnums=(3, 4, 5))
+_subtree_step_jit = jax.jit(_scoped("dpf.subtree", _level_step_pair),
+                            static_argnums=(3, 4, 5))
 
 
 def _level_step(seeds, cw1, cw2, i: int, prf_method: int,
@@ -257,13 +273,15 @@ def _expand_contract_core(cw1, cw2, last, per_chunk_tables, dot_fn, *,
                              prf_method, aes_impl, round_unroll)
 
     # Phase 1: root -> frontier (levels depth-1 .. depth-f_levels)
-    seeds = expand(seeds, 0, f_levels)
+    with jax.named_scope("dpf.frontier"):
+        seeds = expand(seeds, 0, f_levels)
     g = (1 << f_levels) // f  # frontier nodes per contraction chunk
 
     def expand_subtree(node_seeds):
         """[B, g, 4] frontier seeds -> [B, C] low-32 leaf shares."""
-        s = expand(node_seeds, f_levels, depth)
-        return s[..., 0].astype(jnp.int32)  # low limb, [B, C]
+        with jax.named_scope("dpf.subtree"):
+            s = expand(node_seeds, f_levels, depth)
+            return s[..., 0].astype(jnp.int32)  # low limb, [B, C]
 
     if f == 1:
         return dot_fn(expand_subtree(seeds), per_chunk_tables[0])
@@ -401,8 +419,8 @@ def eval_dispatch(cw1, cw2, last, table_perm, *, depth: int,
     for l in range(f_levels):
         check_deadline()
         p1, p2 = pairs(depth - 1 - l)
-        seeds = _level_step_jit(seeds, p1, p2, prf_method, aes_impl,
-                                round_unroll)                 # [B, f, 4]
+        seeds = _frontier_step_jit(seeds, p1, p2, prf_method, aes_impl,
+                                   round_unroll)              # [B, f, 4]
 
     tables = jnp.asarray(table_perm).reshape(f, c, e)
     acc = jnp.zeros((bsz, e), dtype=jnp.int32)
@@ -411,8 +429,8 @@ def eval_dispatch(cw1, cw2, last, table_perm, *, depth: int,
         for l in range(f_levels, depth):
             check_deadline()
             p1, p2 = pairs(depth - 1 - l)
-            s = _level_step_jit(s, p1, p2, prf_method, aes_impl,
-                                round_unroll)
+            s = _subtree_step_jit(s, p1, p2, prf_method, aes_impl,
+                                  round_unroll)
         leaves = s[..., 0].astype(jnp.int32).reshape(bsz, g, c)
         acc = _group_contract(acc, leaves, tables[start:start + g],
                               dot_impl)
@@ -431,11 +449,13 @@ def _expand_contract_pallas(cw1, cw2, last, table_perm, *, depth: int,
     from ..ops.pallas_level import subtree_contract_pallas
     seeds = last[:, None, :]
     f_levels = int(np.log2(f)) if f_levels is None else int(f_levels)
-    for l in range(f_levels):
-        seeds = _level_step(seeds, cw1, cw2, depth - 1 - l, prf_method)
-    return subtree_contract_pallas(
-        seeds, cw1, cw2, table_perm, depth=depth, f_levels=f_levels,
-        interpret=interpret, tb=tb, prf_method=prf_method)
+    with jax.named_scope("dpf.frontier"):
+        for l in range(f_levels):
+            seeds = _level_step(seeds, cw1, cw2, depth - 1 - l, prf_method)
+    with jax.named_scope("dpf.subtree"):   # the contraction is inside
+        return subtree_contract_pallas(
+            seeds, cw1, cw2, table_perm, depth=depth, f_levels=f_levels,
+            interpret=interpret, tb=tb, prf_method=prf_method)
 
 
 def choose_group(f: int, c: int) -> int:
@@ -498,14 +518,16 @@ def _expand_contract_pallas_aes(cw1, cw2, last, table_perm, *, depth: int,
             arity=2, sbox=sbox, interpret=interpret)
 
     seeds = last[:, None, :]
-    for l in range(f_levels):
-        seeds = level(seeds, l)                       # [B, F, 4]
+    with jax.named_scope("dpf.frontier"):
+        for l in range(f_levels):
+            seeds = level(seeds, l)                   # [B, F, 4]
 
     def expand_fn(node_seeds):
         s = node_seeds
-        for l in range(f_levels, depth):
-            s = level(s, l)
-        return s[..., 0].astype(jnp.int32)            # [B, g*c]
+        with jax.named_scope("dpf.subtree"):
+            for l in range(f_levels, depth):
+                s = level(s, l)
+            return s[..., 0].astype(jnp.int32)        # [B, g*c]
 
     return grouped_scan_contract(seeds, table_perm, expand_fn, f=f, c=c,
                                  dot_impl=dot_impl)
@@ -516,7 +538,8 @@ def _dot_i32(a, b, impl: str | None = None):
 
     Delegates to ops.matmul128 (switchable VPU int32 vs MXU int8-limb)."""
     from ..ops import matmul128
-    return matmul128.dot(a, b, impl)
+    with jax.named_scope("dpf.contract"):
+        return matmul128.dot(a, b, impl)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "prf_method",
@@ -540,6 +563,7 @@ def expand_and_contract_per_key_tables(
     f = n // c
     assert c * f == n and depth == int(np.log2(n))
 
+    @jax.named_scope("dpf.contract")
     def bdot(leaves, chunk):
         # [B, C] x [B, C, E] -> [B, E], batched over keys, mod 2^32
         from ..ops import matmul128

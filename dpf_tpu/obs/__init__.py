@@ -2,12 +2,19 @@
 
 Three coupled pieces, one per module:
 
-* ``tracer``  — per-batch span tracing (``submit`` > ``admit`` /
-  ``pack`` / ``dispatch``, ``wait``, ``decode``, ``route``, ``retry``,
-  ``failover``, ``rebuild``), bounded ring, JSONL + Chrome-trace
-  export for Perfetto, joint host+device digest via
-  ``utils.profiling.summarize_trace``.  Off by default — the serving
-  hot path pays one global read (``span()`` returns the shared no-op).
+* ``tracer``  — nested span tracing of the host path (``eval_tpu`` >
+  ``eval_tpu.decode`` / ``.dispatch`` / ``.fetch``; ``submit`` >
+  ``admit`` / ``pack`` / ``backpressure`` / ``dispatch``, ``wait``,
+  ``decode``, each carrying the request's ``rid``; ``route``,
+  ``retry``, ``failover``, ``rebuild``; ``gc`` for each full garbage
+  collection) into a bounded ring.  Off by default — the serving hot
+  path pays one global read (``span()`` returns the shared no-op).
+  Installed (``enable()``), every span also writes a ``dpf.<name>``
+  ``jax.profiler`` annotation, so a profiler capture holds the host
+  spans on the device trace's clock: open that trace in Perfetto.
+  The device program names its parts with ``jax.named_scope``
+  (``dpf.frontier``, ``dpf.subtree``, ``dpf.prf``, ``dpf.cw_add``,
+  ``dpf.contract``) on the same trace.
 * ``metrics`` — typed Counter/Gauge/Histogram registry with an
   OpenMetrics text exporter and JSON snapshot; ``EngineCounters``,
   ``CacheCounters``, ``SWALLOWED_ERRORS``, breaker states and the
@@ -15,10 +22,6 @@ Three coupled pieces, one per module:
 * ``flight``  — a bounded ring of recent structured DECISIONS (route,
   shed, breaker transition, retry, failover, injected fault, rebuild),
   dumpable on demand and embedded in benchmark records.
-
-``benchmark.py --trace`` (``obs/bench_trace.py``) captures a joint
-host+device profile for one tuned shape and measures the whole stack's
-overhead (committed record: BENCH_TRACE_r12.json).
 """
 
 from .flight import FLIGHT, FlightRecorder, flight_dump  # noqa: F401
@@ -26,7 +29,7 @@ from .metrics import (REGISTRY, Counter, Gauge, Histogram,  # noqa: F401
                       MetricsRegistry, default_registry,
                       register_cluster, register_engine, register_router)
 from .tracer import (NULL_SPAN, Span, Tracer, disable,  # noqa: F401
-                     enable, get_tracer, joint_digest, span, tracing)
+                     enable, get_tracer, span, tracing)
 
 
 def set_process_index(index: int | None) -> None:

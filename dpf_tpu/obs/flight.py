@@ -34,8 +34,7 @@ Multi-host runs stamp each event with the recording process's
 ``process`` index (``set_process_index``), so merged rings stay
 attributable per host.
 Recording is always on: one dict + deque append per DECISION (not per
-query), bounded memory, no I/O — the ``--trace`` bench's overhead leg
-measures the full observability stack under 2% of qps.
+query), bounded memory, no I/O.
 """
 
 from __future__ import annotations

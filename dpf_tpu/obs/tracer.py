@@ -1,33 +1,32 @@
-"""Per-batch span tracing for the serving stack.
+"""Span tracing for the host side of the serving stack, on the
+profiler's clock.
 
-``jax.profiler`` answers "where did DEVICE time go" (op-level tracks,
-``utils/profiling.summarize_trace``); nothing answered the same
-question for the HOST half of a served batch — the decode, the bucket
-pad, the admission wait, the blocking ``np.asarray`` — even though the
-engine's aggregate counters prove the host side dominates on the
-synchronous CPU backend.  This module is the host-side mirror: a
-lightweight ``Tracer`` producing NESTED spans (``submit`` > ``admit`` /
-``pack`` / ``dispatch``, ``wait``, ``decode``, plus the router's
-``route`` / ``retry`` / ``failover`` and the supervisor's ``rebuild``),
-carried through ``ServingEngine.submit``/``_resolve_one``,
-``SchemeRouter``, ``EngineSupervisor`` and ``LookupStream`` via the
-module-level ``span()`` helper.
+``jax.profiler`` shows where DEVICE time goes; this module names what
+the HOST was doing meanwhile: a lightweight ``Tracer`` producing NESTED
+spans (``eval_tpu`` > ``eval_tpu.decode`` / ``.dispatch`` / ``.fetch``;
+``submit`` > ``admit`` / ``pack`` / ``backpressure`` / ``dispatch``,
+``wait``, ``decode``; the router's ``route`` / ``retry`` /
+``failover``, the supervisor's ``rebuild``; and ``gc``, each full
+garbage collection), carried through ``DPF.eval_tpu``,
+``ServingEngine``, ``SchemeRouter``, ``EngineSupervisor`` and
+``LookupStream`` via the module-level ``span()`` helper.
 
 Design constraints (docs/OBSERVABILITY.md):
 
 * **Tracing-off fast path** — ``span()`` with no tracer installed
   returns one shared no-op context manager: a single global read on the
-  serving hot path, no allocation.  The load harness's overhead leg
-  (``benchmark.py --trace``) measures the on/off qps delta and the
-  committed record keeps it under 2%.
+  serving hot path, no allocation.
+* **One clock with the device** — while a tracer is installed
+  (``enable()``), every span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``dpf.<span name>`` with the
+  span's attrs as its arguments, so inside a profiler capture the spans
+  land on the host plane of the same trace as the device ops, nested as
+  they were opened.  Open that trace in Perfetto; there is no separate
+  export.  JAX is imported at ``enable()``, never by this module.
 * **Bounded memory** — finished spans land in a ring
   (``deque(maxlen=capacity)``); a long-lived serving process keeps the
-  most recent window, like the latency ring.
-* **Perfetto-ready export** — ``export_chrome()`` writes the Chrome
-  trace-event JSON Perfetto opens directly, so host spans sit alongside
-  a ``jax.profiler`` device trace of the same run; ``joint_digest``
-  merges the two into the one small digest benchmark records embed
-  (extending ``summarize_trace``'s ncu-report role to the host).
+  most recent window, like the latency ring.  ``events()`` and
+  ``digest()`` read it.
 
 Spans are thread-aware (one nesting stack per thread, thread id on
 every span), so supervisor rebuilds and background resolution show up
@@ -36,8 +35,8 @@ on their own tracks.
 
 from __future__ import annotations
 
+import gc
 import itertools
-import json
 import threading
 import time
 from collections import deque
@@ -46,6 +45,9 @@ from .flight import _env_capacity
 
 #: default bounded span-ring capacity per tracer
 SPAN_RING = 8192
+
+#: ``jax.profiler.TraceAnnotation`` while a tracer is installed, else None
+_ANNOTATION = None
 
 
 class NullSpan:
@@ -75,13 +77,14 @@ class Span:
 
     ``set(**attrs)`` attaches attributes any time before exit (e.g. the
     routed construction, the bucket size).  On exit the span computes
-    its SELF time (duration minus direct children — the same
-    double-count subtraction ``summarize_trace`` applies to profiler
-    tracks) and lands in the tracer's ring.
+    its SELF time (duration minus direct children) and lands in the
+    tracer's ring.  While a tracer is installed the span also holds a
+    profiler annotation ``dpf.<name>`` open over the same interval,
+    carrying the same attrs.
     """
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "tid",
-                 "t0", "dur_s", "_children_s", "_tracer")
+                 "t0", "dur_s", "_children_s", "_tracer", "_ann")
 
     def __init__(self, tracer, name, span_id, parent_id, tid, attrs):
         self._tracer = tracer
@@ -93,20 +96,30 @@ class Span:
         self.t0 = None
         self.dur_s = 0.0
         self._children_s = 0.0
+        self._ann = None
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self):
         self._tracer._push(self)
+        annotation = _ANNOTATION
+        if annotation is not None:
+            self._ann = annotation("dpf." + self.name, **self.attrs)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_s = time.perf_counter() - self.t0
-        if exc_type is not None:
-            self.attrs.setdefault("error", exc_type.__name__)
+        if exc_type is not None and "error" not in self.attrs:
+            self.set(error=exc_type.__name__)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         self._tracer._pop(self)
         return False
 
@@ -116,17 +129,20 @@ class Tracer:
 
     All methods are thread-safe; each thread keeps its own nesting
     stack so concurrent submits/rebuilds produce correctly-parented
-    spans on separate tracks.
+    spans on separate tracks.  The lock is reentrant: a full collection
+    can start between any two bytecodes, also inside a locked block,
+    and its ``gc`` span lands in the ring from the same thread.
     """
 
     def __init__(self, capacity: int | None = None):
         if capacity is None:
             capacity = _env_capacity("DPF_SPAN_RING", SPAN_RING)
         self._ring = deque(maxlen=int(capacity))
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._ids = itertools.count(1)
         self._local = threading.local()
         self._epoch = time.perf_counter()
+        self._gc_span = None      # the open ``gc`` span, if one is open
         self.dropped = 0          # spans evicted from the full ring
         self.recorded = 0
 
@@ -180,9 +196,8 @@ class Tracer:
             self.recorded = 0
 
     def digest(self, top: int = 12) -> dict | None:
-        """Aggregate SELF time per span name — the host-side half of
-        the joint digest (mirrors ``summarize_trace``'s shape: small
-        enough to embed in a benchmark record)."""
+        """Aggregate SELF time per span name: small enough to embed in
+        a benchmark record (``obs.record_sections``)."""
         events = self.events()
         if not events:
             return None
@@ -201,65 +216,56 @@ class Tracer:
                                "ms": round(us / 1e3, 3)}
                               for k, (c, us) in spans]}
 
-    # --------------------------------------------------------- exports
-
-    def export_jsonl(self, path: str) -> int:
-        """One span per line; returns the span count."""
-        events = self.events()
-        with open(path, "w") as f:
-            for e in events:
-                f.write(json.dumps(e) + "\n")
-        return len(events)
-
-    def chrome_trace(self) -> dict:
-        """Chrome trace-event JSON (``ph="X"`` complete events, µs
-        timestamps) — open in Perfetto (ui.perfetto.dev) next to the
-        ``jax.profiler`` device trace of the same run."""
-        events = [{"ph": "M", "pid": 1, "tid": 0, "name": "process_name",
-                   "args": {"name": "dpf_tpu host spans"}}]
-        tids = {}
-        for e in self.events():
-            tid = tids.setdefault(e["tid"], len(tids))
-            ev = {"ph": "X", "pid": 1, "tid": tid, "name": e["name"],
-                  "ts": e["ts_us"], "dur": e["dur_us"]}
-            if "attrs" in e:
-                ev["args"] = {k: str(v) for k, v in e["attrs"].items()}
-            events.append(ev)
-        for raw, tid in tids.items():
-            events.append({"ph": "M", "pid": 1, "tid": tid,
-                           "name": "thread_name",
-                           "args": {"name": "host thread %d" % raw}})
-        return {"traceEvents": events,
-                "displayTimeUnit": "ms"}
-
-    def export_chrome(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-        return path
-
 
 # ------------------------------------------------- process-wide tracer
 
 _TRACER: Tracer | None = None
 
 
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook while a tracer is installed: each full
+    (generation 2) collection becomes a ``gc`` span on the thread that
+    ran it.  Younger generations collect too often to be worth a span."""
+    t = _TRACER
+    if t is None or info["generation"] != 2:
+        return
+    if phase == "start":
+        t._gc_span = t.span("gc", generation=2)
+        t._gc_span.__enter__()
+    elif t._gc_span is not None:
+        sp, t._gc_span = t._gc_span, None
+        sp.set(collected=info["collected"])
+        sp.__exit__(None, None, None)
+
+
 def enable(capacity: int | None = None) -> Tracer:
     """Install (and return) the process tracer; idempotent unless a
     different capacity is requested.  ``capacity=None`` resolves the
-    ``DPF_SPAN_RING`` environment knob (else ``SPAN_RING``)."""
-    global _TRACER
+    ``DPF_SPAN_RING`` environment knob (else ``SPAN_RING``).  From here
+    on every span also writes a ``dpf.<name>`` profiler annotation, and
+    full garbage collections are recorded as ``gc`` spans."""
+    global _TRACER, _ANNOTATION
     if capacity is None:
         capacity = _env_capacity("DPF_SPAN_RING", SPAN_RING)
     if _TRACER is None or _TRACER._ring.maxlen != int(capacity):
         _TRACER = Tracer(capacity)
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
     return _TRACER
 
 
 def disable() -> None:
     """Remove the process tracer: ``span()`` reverts to the no-op fast
-    path (already-captured spans are dropped with the tracer)."""
-    global _TRACER
+    path, the GC hook comes out (already-captured spans are dropped
+    with the tracer)."""
+    global _TRACER, _ANNOTATION
     _TRACER = None
+    _ANNOTATION = None
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def get_tracer() -> Tracer | None:
@@ -277,26 +283,3 @@ def span(name: str, **attrs):
     if t is None:
         return NULL_SPAN
     return t.span(name, **attrs)
-
-
-# ---------------------------------------------------------- digesting
-
-def joint_digest(tracer: Tracer | None = None,
-                 trace_dir: str | None = None, top: int = 12) -> dict:
-    """The one digest benchmark records embed: host span self-times
-    (this module) merged with the device op self-times
-    (``utils.profiling.summarize_trace`` over a ``jax.profiler``
-    capture of the same run).  Either half may be absent (no tracer /
-    no profiler capture); ``total_ms`` sums whatever is present."""
-    host = None
-    t = tracer if tracer is not None else _TRACER
-    if t is not None:
-        host = t.digest(top=top)
-    device = None
-    if trace_dir:
-        from ..utils.profiling import summarize_trace
-        device = summarize_trace(trace_dir, top=top)
-    total = sum(d[k] for d, k in ((host, "host_ms"),
-                                  (device, "device_ms")) if d)
-    return {"host": host, "device": device,
-            "total_ms": round(total, 3)}

@@ -380,6 +380,7 @@ def _aes_level_step_impl(seeds, cw1_lvl, cw2_lvl, *, arity: int = 2,
     kernel = _make_aes_level_kernel(arity, sbox, unroll)
     outs = pl.pallas_call(
         kernel,
+        name="dpf_aes_level",
         grid=grid,
         # key tiles and column tiles are fully independent
         compiler_params=_compiler_params(("parallel", "parallel")),

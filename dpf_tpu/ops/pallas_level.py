@@ -261,6 +261,7 @@ def _chacha_level_step_impl(seeds, cw1_lvl, cw2_lvl, interpret=False,
     spec_out = pl.BlockSpec((4, tb, tw), lambda i, j: (0, i, j))
     out0, out1 = pl.pallas_call(
         _level_kernel,
+        name="dpf_chacha_level",
         grid=grid,
         compiler_params=_compiler_params(("parallel", "parallel")),
         in_specs=[spec_seeds, spec_cw, spec_cw],
@@ -415,6 +416,7 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
     kernel = _make_subtree_kernel(tuple(sched), prf_method)
     out = pl.pallas_call(
         kernel,
+        name="dpf_subtree_contract",
         grid=grid,
         in_specs=[
             pl.BlockSpec((pl.squeezed, pl.squeezed, tb, 4),

@@ -286,6 +286,7 @@ def _sqrt_grid_contract_impl(seeds, cw1, cw2, table, row0, *,
                                limbs=limbs, cw_add=cw_add)
     out = pl.pallas_call(
         kernel,
+        name="dpf_sqrt_grid_contract",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -32,6 +32,7 @@ per-key math is batch-shape independent).
 
 from __future__ import annotations
 
+import itertools
 import time
 from collections import deque
 
@@ -67,12 +68,13 @@ class EngineClosed(RuntimeError):
 
 class _Part:
     """One dispatched (bucket-padded) chunk of a submitted batch."""
-    __slots__ = ("dev", "n_real", "bucket", "out")
+    __slots__ = ("dev", "n_real", "bucket", "rid", "out")
 
-    def __init__(self, dev, n_real, bucket):
+    def __init__(self, dev, n_real, bucket, rid):
         self.dev = dev          # device array, possibly still in flight
         self.n_real = n_real    # rows that are real queries (not pad)
         self.bucket = bucket    # padded dispatch size (fault targeting)
+        self.rid = rid          # the submitting future's request id
         self.out = None         # resolved host array
 
 
@@ -81,15 +83,17 @@ class EngineFuture:
 
     ``result()`` blocks until this batch — and, FIFO, every batch
     submitted before it — has left the device, then returns the
-    ``[batch, entry_size]`` int32 share array.
+    ``[batch, entry_size]`` int32 share array.  ``rid`` numbers the
+    engine's submits from 1; the request's spans carry it.
     """
-    __slots__ = ("_engine", "_parts", "_value", "_t0")
+    __slots__ = ("_engine", "_parts", "_value", "_t0", "rid")
 
-    def __init__(self, engine):
+    def __init__(self, engine, rid: int):
         self._engine = engine
         self._parts = []
         self._value = None
         self._t0 = None     # submit-entry perf_counter (latency ring)
+        self.rid = rid
 
     def done(self) -> bool:
         return self._value is not None
@@ -187,6 +191,7 @@ class ServingEngine:
         self._closed = False      # set by close(); submit rejects after
         self._queue = deque()     # _Part refs, dispatch order, unresolved
         self._pending = deque()   # futures with unresolved parts, FIFO
+        self._rids = itertools.count(1)
         # Persistent XLA compilation cache, on by default for the serve
         # path (disable: DPF_TPU_COMPILE_CACHE=0): warmup is real
         # serving latency, and a warm cache turns each bucket's compile
@@ -213,7 +218,8 @@ class ServingEngine:
         The host-side work here is the vectorized decode and the bucket
         pad; the device program is enqueued asynchronously.  When the
         in-flight window is full, blocks on the oldest outstanding
-        dispatch first (backpressure).  Admission control
+        dispatch first (backpressure, a ``backpressure`` span around
+        the ``wait`` spans it makes).  Admission control
         (``max_queue_depth``/``slo_s``) runs first: over the bound the
         batch either waits on the oldest pending future or — with
         ``shed=True`` — is rejected with ``LoadShed`` before any decode
@@ -227,14 +233,16 @@ class ServingEngine:
         t_enter = time.perf_counter()
         # pre-decoded packed batches (LookupStream) carry .batch
         b_req = getattr(keys, "batch", None) or len(keys)
-        with span("submit", engine=self.label or "engine", batch=b_req):
+        rid = next(self._rids)
+        with span("submit", engine=self.label or "engine", batch=b_req,
+                  rid=rid):
             with span("admit"):
                 self._admit(b_req)
             t0 = time.perf_counter()
             with span("pack", phase="decode"):
                 pk = self._server._decode_batch(keys)
             b = pk.batch
-            fut = EngineFuture(self)
+            fut = EngineFuture(self, rid)
             # the latency ring measures from submit ENTRY: a blocking
             # admission wait is exactly the client-observed queueing the
             # p99 SLO trigger exists to see (pack_time_s stays post-admit)
@@ -246,9 +254,11 @@ class ServingEngine:
                     with span("pack", phase="pad", bucket=size):
                         padded = pk.slice(lo, hi).pad_to(size)
                     self.stats.pack_time_s += time.perf_counter() - t0
-                    while len(self._queue) >= self.max_in_flight:
-                        self._check_deadline()
-                        self._resolve_one()
+                    if len(self._queue) >= self.max_in_flight:
+                        with span("backpressure"):
+                            while len(self._queue) >= self.max_in_flight:
+                                self._check_deadline()
+                                self._resolve_one()
                     with span("dispatch", bucket=size):
                         if self._injector is not None:
                             # first-class injection point: may sleep
@@ -260,7 +270,7 @@ class ServingEngine:
                         dev = self._server._dispatch_packed(padded)
                         self.stats.dispatch_time_s += (time.perf_counter()
                                                        - t1)
-                    part = _Part(dev, hi - lo, size)
+                    part = _Part(dev, hi - lo, size, rid)
                     fut._parts.append(part)
                     self._queue.append(part)
                     self.stats.note_dispatch(padded=size - (hi - lo),
@@ -290,7 +300,7 @@ class ServingEngine:
     def _resolve_one(self):
         """Block on the oldest in-flight dispatch and store its rows."""
         part = self._queue.popleft()
-        with span("wait", bucket=part.bucket):
+        with span("wait", bucket=part.bucket, rid=part.rid):
             t0 = time.perf_counter()
             part.out = np.asarray(part.dev)[:part.n_real]
             if self._injector is not None:
@@ -303,7 +313,7 @@ class ServingEngine:
             part.dev = None
 
     def _finalize(self, fut: EngineFuture):
-        with span("decode", parts=len(fut._parts)):
+        with span("decode", parts=len(fut._parts), rid=fut.rid):
             parts = fut._parts
             if len(parts) == 1:
                 out = parts[0].out

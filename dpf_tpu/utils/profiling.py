@@ -1,115 +1,17 @@
-"""Profiling helpers (the reference's Nsight-Compute role, SURVEY.md §5).
+"""Host-side measurement helpers: the serving engine's counters
+(``EngineCounters``: work, waits, latency ring and histogram), the
+cache counters, the swallowed-error registry and ``Timer``.
 
-``jax.profiler`` traces viewable in XProf/Perfetto replace ``ncu``; the
-trace directory naming mirrors the reference's artifact-per-config scheme
-(``paper/kernel/gpu/Makefile:24-26``).
+Device traces come from ``jax.profiler``; the program's host spans land
+on the same trace through ``obs.tracer`` (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import os
 import threading
 import time
 import warnings
-
-
-@contextlib.contextmanager
-def trace(config_name: str, base_dir: str = "/tmp/dpf_tpu_traces"):
-    """Capture a jax.profiler trace named after the benchmark config."""
-    import jax
-    path = os.path.join(base_dir, config_name)
-    os.makedirs(path, exist_ok=True)
-    jax.profiler.start_trace(path)
-    try:
-        yield path
-    finally:
-        jax.profiler.stop_trace()
-
-
-def _self_times(track_events):
-    """(name, self_us) per complete event of ONE track, with nested
-    children's durations subtracted from their parents (host python
-    stacks and runtime tracks nest; summing raw durations would count
-    a frame once per ancestor)."""
-    evs = sorted(track_events,
-                 key=lambda e: (float(e.get("ts", 0)),
-                                -float(e.get("dur", 0))))
-    out = []
-    stack = []  # indices into out; parents below children
-    for e in evs:
-        ts = float(e.get("ts", 0))
-        dur = float(e.get("dur", 0))
-        while stack and stack[-1][0] <= ts:
-            stack.pop()
-        if stack:
-            parent = stack[-1][1]
-            out[parent][1] -= dur
-        out.append([str(e.get("name", "?"))[:80], dur])
-        stack.append((ts + dur, len(out) - 1))
-    return out
-
-
-def summarize_trace(trace_dir: str, top: int = 12):
-    """Digest a captured trace into {device_ms, top_ops} (or None).
-
-    Reads the Chrome-trace export (``*.trace.json.gz``) the profiler
-    writes next to the xplane protobuf, picks the op-level tracks —
-    "XLA Ops" threads (TPU device traces), else ``tf_XLA*`` runtime
-    threads (CPU backend), else everything — and aggregates SELF time
-    per op name (module/parent rows span their children and would
-    otherwise double-count).  The digest is small enough to live as a
-    row in the measurement JSONL, so the TPU session's profile stage
-    records WHERE the time went (the ncu-report role,
-    ``paper/kernel/gpu/Makefile:24-32``) even if the raw trace
-    directory is lost.
-    """
-    import glob
-    import gzip
-    import json as _json
-
-    paths = sorted(glob.glob(os.path.join(
-        trace_dir, "**", "*.trace.json.gz"), recursive=True))
-    if not paths:
-        return None
-    with gzip.open(paths[-1], "rt") as f:
-        events = _json.load(f).get("traceEvents", [])
-    thread_names = {}
-    for e in events:
-        if e.get("ph") == "M" and e.get("name") == "thread_name":
-            thread_names[(e.get("pid"), e.get("tid"))] = str(
-                e.get("args", {}).get("name", ""))
-    tracks = {}
-    for e in events:
-        if e.get("ph") == "X":
-            tracks.setdefault((e.get("pid"), e.get("tid")), []).append(e)
-
-    def pick(pred):
-        return {k: v for k, v in tracks.items()
-                if pred(thread_names.get(k, ""))}
-    chosen = pick(lambda n: "XLA Ops" in n)          # TPU device tracks
-    track_kind = "xla_ops"
-    if not chosen:
-        chosen = pick(lambda n: n.startswith("tf_XLA"))  # CPU runtime
-        track_kind = "tf_xla"
-    if not chosen:
-        # unknown thread-naming scheme: totals include HOST tracks —
-        # tagged so the digest is never mistaken for pure device time
-        chosen = tracks
-        track_kind = "all_tracks_incl_host"
-    by_op = {}
-    total_us = 0.0
-    for track in chosen.values():
-        for name, self_us in _self_times(track):
-            total_us += self_us
-            by_op[name] = by_op.get(name, 0.0) + self_us
-    ops = sorted(by_op.items(), key=lambda kv: -kv[1])[:top]
-    return {"trace_file": os.path.basename(paths[-1]),
-            "tracks": track_kind,
-            "device_ms": round(total_us / 1e3, 3),
-            "top_ops": [{"op": k, "ms": round(v / 1e3, 3)}
-                        for k, v in ops]}
 
 
 def quantile(samples, q: float, *, presorted: bool = False) -> float:

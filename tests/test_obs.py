@@ -1,7 +1,8 @@
 """Observability stack (dpf_tpu/obs/, docs/OBSERVABILITY.md): span
-tracer nesting/ring/exports, the metrics registry's OpenMetrics
-rendering and weakref collector pruning, the flight recorder ring, and
-the serving engine's span wiring end to end."""
+tracer nesting/ring, its profiler annotations and GC spans, the metrics
+registry's OpenMetrics rendering and weakref collector pruning, the
+flight recorder ring, the span wiring of ``DPF.eval_tpu`` and the
+serving engine end to end, and the device program's named scopes."""
 
 import gc
 import json
@@ -16,7 +17,7 @@ from dpf_tpu.obs import tracer as obs_tracer
 from dpf_tpu.obs.flight import FLIGHT, FlightRecorder, flight_dump
 from dpf_tpu.obs.metrics import (MetricsRegistry, register_engine,
                                  register_router)
-from dpf_tpu.obs.tracer import NULL_SPAN, Tracer, joint_digest, span
+from dpf_tpu.obs.tracer import NULL_SPAN, Tracer, span
 
 
 @pytest.fixture(autouse=True)
@@ -59,8 +60,7 @@ def test_nested_spans_parenting_and_self_time():
     assert evs["inner"]["parent_id"] == outer.span_id
     assert evs["outer"]["parent_id"] is None
     assert inner.parent_id == outer.span_id
-    # self time = duration minus direct children (same subtraction
-    # summarize_trace applies to profiler tracks); 0.1 us rounding
+    # self time = duration minus direct children; 0.1 us rounding
     assert evs["outer"]["self_us"] == pytest.approx(
         evs["outer"]["dur_us"] - evs["inner"]["dur_us"], abs=0.5)
     assert evs["inner"]["self_us"] == evs["inner"]["dur_us"]
@@ -116,36 +116,172 @@ def test_threads_get_their_own_nesting_stacks():
     assert evs["worker"]["tid"] != evs["main"]["tid"]
 
 
-def test_exports_jsonl_and_chrome(tmp_path):
-    t = Tracer()
-    with t.span("submit", batch=4):
-        with t.span("dispatch", bucket=8):
-            pass
-    p = tmp_path / "spans.jsonl"
-    assert t.export_jsonl(str(p)) == 2
-    lines = [json.loads(ln) for ln in p.read_text().splitlines()]
-    assert [ln["name"] for ln in lines] == ["dispatch", "submit"]
-    doc = t.chrome_trace()
-    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    metas = [e for e in doc["traceEvents"] if e["ph"] == "M"]
-    assert {e["name"] for e in xs} == {"submit", "dispatch"}
-    assert all("ts" in e and "dur" in e and e["pid"] == 1 for e in xs)
-    assert any(m["name"] == "thread_name" for m in metas)
-    cp = tmp_path / "spans.chrome.json"
-    t.export_chrome(str(cp))
-    json.loads(cp.read_text())            # Perfetto-loadable JSON
+def _host_events(trace_dir):
+    """Every host-plane event of a profiler capture as (name, start_ns,
+    end_ns, stats)."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path = glob.glob(str(trace_dir) + "/**/*.xplane.pb", recursive=True)[-1]
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
 
 
-def test_joint_digest_host_only_and_empty():
-    t = Tracer()
-    with t.span("submit"):
-        pass
-    d = joint_digest(tracer=t)
-    assert d["device"] is None
-    assert d["host"]["spans_recorded"] == 1
-    assert d["total_ms"] == d["host"]["host_ms"]
-    assert joint_digest(tracer=Tracer()) == {
-        "host": None, "device": None, "total_ms": 0}
+def test_spans_land_on_the_profiler_host_plane(tmp_path):
+    import jax
+    t = obs_tracer.enable()
+    t.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("submit", batch=4, rid=7):
+            with span("pack", phase="decode") as sp:
+                sp.set(bucket=8)
+    finally:
+        jax.profiler.stop_trace()
+    evs = {n: (s0, s1, st) for n, s0, s1, st in _host_events(tmp_path)
+           if n.startswith("dpf.")}
+    assert set(evs) == {"dpf.submit", "dpf.pack"}
+    (o0, o1, ost), (i0, i1, ist) = evs["dpf.submit"], evs["dpf.pack"]
+    assert o0 <= i0 <= i1 <= o1            # nested as in the ring
+    assert ost == {"batch": 4, "rid": 7}
+    assert ist == {"phase": "decode", "bucket": 8}
+    ring = {e["name"]: e for e in t.events()}
+    assert ring["pack"]["parent_id"] == ring["submit"]["span_id"]
+
+
+def test_no_tracer_means_no_span_and_no_gc_hook():
+    obs_tracer.disable()
+    before = list(gc.callbacks)
+    assert span("submit") is NULL_SPAN
+    obs_tracer.enable()
+    assert obs_tracer._on_gc in gc.callbacks
+    obs_tracer.enable()                   # idempotent: hooked once
+    assert gc.callbacks.count(obs_tracer._on_gc) == 1
+    obs_tracer.disable()
+    assert gc.callbacks == before
+    assert span("submit") is NULL_SPAN
+
+
+def test_full_collection_is_a_gc_span_and_younger_ones_are_not():
+    t = obs_tracer.enable()
+    t.clear()
+    gc.collect(0)
+    gc.collect(1)
+    with span("outer"):
+        gc.collect(2)
+    gcs = [e for e in t.events() if e["name"] == "gc"]
+    assert len(gcs) == 1
+    assert gcs[0]["attrs"]["generation"] == 2
+    assert gcs[0]["attrs"]["collected"] >= 0
+    outer = [e for e in t.events() if e["name"] == "outer"][0]
+    assert gcs[0]["parent_id"] == outer["span_id"]
+
+
+def _small_dpf(n=1024, e=7):
+    from dpf_tpu import DPF
+    dpf = DPF(prf=DPF.PRF_DUMMY)
+    table = np.random.default_rng(3).integers(
+        0, 2 ** 31, (n, e), dtype=np.int64).astype(np.int32)
+    dpf.eval_init(table)
+    return dpf, [dpf.gen((i * 31) % n, n)[0] for i in range(6)]
+
+
+def test_eval_tpu_spans_decode_dispatch_fetch_per_chunk():
+    dpf, keys = _small_dpf()
+    dpf.BATCH_SIZE = 4                    # six keys: two chunks
+    ref = np.asarray(dpf.eval_tpu(keys))  # compile outside the record
+    t = obs_tracer.enable()
+    t.clear()
+    assert np.array_equal(np.asarray(dpf.eval_tpu(keys)), ref)
+    evs = t.events()
+    top = [e for e in evs if e["name"] == "eval_tpu"]
+    assert len(top) == 1 and top[0]["attrs"] == {"batch": 6}
+    for part in ("decode", "dispatch", "fetch"):
+        kids = [e for e in evs if e["name"] == "eval_tpu." + part]
+        assert len(kids) == 2, part       # once per chunk
+        assert all(k["parent_id"] == top[0]["span_id"] for k in kids)
+    assert [e["attrs"]["batch"] for e in evs
+            if e["name"] == "eval_tpu.decode"] == [4, 2]
+
+
+def test_backpressure_span_only_when_the_window_is_full():
+    dpf, keys = _small_dpf()
+    engine = dpf.serving_engine(buckets=(4,), max_in_flight=1)
+    engine.submit(keys[:1]).result()      # compile outside the record
+    t = obs_tracer.enable()
+    t.clear()
+    first = engine.submit(keys[:1])
+    second = engine.submit(keys[1:2])
+    second.result()
+    evs = t.events()
+    subs = {e["attrs"]["rid"]: e for e in evs if e["name"] == "submit"}
+    bps = [e for e in evs if e["name"] == "backpressure"]
+    assert len(bps) == 1                  # the second submit's, only
+    assert bps[0]["parent_id"] == subs[second.rid]["span_id"]
+    waits = [e for e in evs if e["name"] == "wait"
+             and e["parent_id"] == bps[0]["span_id"]]
+    assert [w["attrs"]["rid"] for w in waits] == [first.rid]
+
+
+def test_spans_of_one_request_share_its_rid():
+    dpf, keys = _small_dpf()
+    engine = dpf.serving_engine(buckets=(2, 4), max_in_flight=2)
+    engine.submit(keys[:4]).result()      # compile outside the record
+    t = obs_tracer.enable()
+    t.clear()
+    futs = [engine.submit(keys[:6]), engine.submit(keys[:1])]
+    engine.drain()
+    assert futs[1].rid == futs[0].rid + 1  # counted per engine
+    for fut, parts in zip(futs, (2, 1)):
+        mine = [e for e in t.events()
+                if e.get("attrs", {}).get("rid") == fut.rid]
+        names = [e["name"] for e in mine]
+        assert names.count("submit") == 1 and names.count("decode") == 1
+        assert names.count("wait") == parts
+    assert np.array_equal(futs[0].result(),
+                          np.asarray(dpf.eval_tpu(keys[:6])))
+
+
+# -------------------------------------------- device program named scopes
+
+def _scopes(hlo_text):
+    import re
+    return {p for name in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for p in name.split("/") if p.startswith("dpf.")}
+
+
+def test_fused_program_carries_dpf_scopes():
+    import jax
+    import jax.numpy as jnp
+
+    from dpf_tpu.core import expand
+    n, u32 = 1 << 10, jnp.uint32
+
+    def fn(cw1, cw2, last, t):
+        return expand.expand_and_contract(
+            cw1, cw2, last, t, depth=10, prf_method=2, chunk_leaves=256)
+    S = jax.ShapeDtypeStruct
+    text = jax.jit(fn).lower(S((4, 64, 4), u32), S((4, 64, 4), u32),
+                             S((4, 4), u32),
+                             S((n, 16), jnp.int32)).compile().as_text()
+    assert {"dpf.frontier", "dpf.subtree", "dpf.contract", "dpf.prf",
+            "dpf.cw_add"} <= _scopes(text)
+
+
+def test_dispatch_path_level_programs_carry_their_phase():
+    import jax
+    import jax.numpy as jnp
+
+    from dpf_tpu.core import expand
+    S, u32 = jax.ShapeDtypeStruct, jnp.uint32
+    args = (S((4, 8, 4), u32), S((4, 2, 4), u32), S((4, 2, 4), u32),
+            2, None, None)
+    for fn, scope in ((expand._frontier_step_jit, "dpf.frontier"),
+                      (expand._subtree_step_jit, "dpf.subtree")):
+        got = _scopes(fn.lower(*args).compile().as_text())
+        assert {scope, "dpf.prf", "dpf.cw_add"} <= got, (scope, got)
 
 
 class _Fake:

@@ -135,10 +135,45 @@ PALLAS_CASES = {
 }
 
 
+# each case's kernel, by the stable name its pallas_call gives it
+KERNEL_NAMES = {"level_step": "dpf_chacha_level",
+                "subtree": "dpf_subtree_contract",
+                "mixed": "dpf_subtree_contract",
+                "sqrt": "dpf_sqrt_grid_contract",
+                "planes": "dpf_aes_level"}
+
+
+def _custom_calls(text):
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
 @pytest.mark.parametrize("case", sorted(PALLAS_CASES))
 def test_pallas_kernel_compiles_for_v5e(compile_tpu, case):
     fn, *shapes = PALLAS_CASES[case]()
-    assert "tpu_custom_call" in compile_tpu(fn, *shapes)
+    calls = _custom_calls(compile_tpu(fn, *shapes))
+    assert calls
+    name = KERNEL_NAMES[case.split(".")[0]]
+    assert all(name in ln for ln in calls), (name, calls[0][:200])
+
+
+def test_subtree_kernel_keeps_its_name_at_a_small_table(compile_tpu):
+    """The device trace finds the production kernel by its name,
+    whatever the shape: here 2^14 rows and 64 keys."""
+    from dpf_tpu.ops.pallas_level import (_subtree_contract_pallas_impl,
+                                          pallas_chunk_leaves)
+    n, b = 1 << 14, 64
+    depth = n.bit_length() - 1
+    c = pallas_chunk_leaves(n)
+
+    def fn(f, a, bb, t):
+        return _subtree_contract_pallas_impl(
+            f, a, bb, t, depth=depth, f_levels=depth - (c.bit_length() - 1),
+            prf_method=2)
+    calls = _custom_calls(compile_tpu(
+        fn, S((b, n // c, 4), U32), S((b, 64, 4), U32), S((b, 64, 4), U32),
+        S((n, E), I32)))
+    assert len(calls) == 1
+    assert 'op_name="' in calls[0] and "dpf_subtree_contract" in calls[0]
 
 
 @pytest.mark.parametrize("prf,unroll", [(2, True), (3, None)])
