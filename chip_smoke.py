@@ -16,7 +16,9 @@ reference's largest shape (N = 2^20 rows x 16 int32, batch 512,
 * ``pallas``: the Pallas kernels (binary ChaCha20 subtree, radix-4
   ChaCha20-BLK subtree, sqrt-N ChaCha20 grid, plane-AES level) match the
   XLA path bit for bit, resolve from ``"config"`` (never ``"degraded"``),
-  and their compiled programs hold a ``tpu_custom_call``.
+  and their compiled programs hold a ``tpu_custom_call``.  Binary
+  ChaCha20's default already is the subtree kernel on a TPU, so its XLA
+  reference is a server pinned to ``kernel_impl="xla"``.
 
 ``--multichip`` serves a 2^22 x 16 table (ChaCha20 with rolled rounds,
 64 keys) from ``ShardedDPFServer``s (binary and sqrt-N over
@@ -183,6 +185,8 @@ def check_pallas(label, dpf, keys, want, log, want_kernel=True):
     kn = dpf.resolved_eval_knobs(len(keys))
     assert kn["kernel_impl"] == "pallas" and \
         kn["kernel_resolved_from"] == "config", (label, kn)
+    import jax
+    jax.clear_caches()  # the default path may have compiled it already
     with compiled_programs() as programs:
         got = timed(label, lambda: np.asarray(dpf.eval_tpu(keys)), log)
     assert programs, "%s: no program was compiled" % label
@@ -242,9 +246,12 @@ def phase_one_chip(table, batch, log, want_kernel=True,
         servers, idx, keys, shares = phase_pir(table, prf, batch, log)
         if prf == DPF.PRF_CHACHA20:
             phase_engine(servers, idx, keys, table, log, engine_sizes)
+        # the Pallas phase reuses these shares as its XLA reference only
+        # where the default resolved to the XLA path
+        if servers[0].resolved_eval_knobs(batch)["kernel_impl"] == "xla":
+            pir[prf] = (keys[0], shares[0])
         for s in servers:
             s.eval_free()
-        pir[prf] = (keys[0], shares[0])
     phase_pallas(table, batch, log, pir, want_kernel, families=families)
 
 

@@ -19,7 +19,7 @@ import os
 import numpy as np
 
 from .core import evalref, expand, keygen
-from .obs.tracer import span
+from .obs.tracer import annotate, span
 from .utils.config import check_construction
 from .core.prf_ref import (PRF_AES128, PRF_CHACHA20, PRF_CHACHA20_BLK,
                            PRF_DUMMY, PRF_NAMES, PRF_SALSA20,
@@ -757,7 +757,7 @@ class DPF(object):
         elif tuned.get("kernel_impl") is not None:
             kernel_impl, kernel_from = tuned["kernel_impl"], "tuned"
         else:
-            kernel_impl, kernel_from = "xla", "heuristic"
+            kernel_impl, kernel_from = self._heuristic_kernel(), "heuristic"
         if kernel_from != "searched":
             searched, variant = {}, {}
         if kernel_impl == "pallas" and kernel_from in ("searched",
@@ -791,11 +791,12 @@ class DPF(object):
                 "searched"
             chunk = expand.clamp_chunk(chunk_req, n, batch)
         elif (tuned.get("chunk_leaves")
-                and tuned.get("kernel_impl", kernel_impl) == kernel_impl):
+                and tuned.get("kernel_impl", "xla") == kernel_impl):
             # the tuner gated (chunk, kernel) together — a tuned chunk
-            # rides only with ITS kernel (an explicit kernel_impl that
-            # differs, e.g. pallas with its VMEM-bounded tile chunk,
-            # falls through to that kernel's own heuristic) and is
+            # rides only with ITS kernel (an entry naming none was timed
+            # on the xla scan; a kernel that differs, e.g. pallas with
+            # its VMEM-bounded tile chunk, falls through to that
+            # kernel's own heuristic) and is
             # re-checked against the live-seed budget (nearest-batch
             # fallback can pair a small-batch chunk with a bigger batch)
             chunk_req, chunk_from = int(tuned["chunk_leaves"]), "tuned"
@@ -859,6 +860,20 @@ class DPF(object):
             out["chunk_leaves_effective"] = chunk
         return out
 
+    def _heuristic_kernel(self) -> str:
+        """The logn kernel when no config, tuned or searched entry names
+        one: binary GGM over a PRF with a subtree core runs the
+        VMEM-resident subtree kernel where it compiles (a TPU), which
+        is bit-identical to the xla scan and 2.5-6x faster on a v5e
+        (PERF.md); AES, DUMMY, radix 4 and every other backend keep the
+        scan."""
+        from .ops.pallas_level import has_subtree_core
+        from .utils.compat import has_pallas_sqrt_kernel
+        if (self.radix == 2 and has_subtree_core(self.prf_method)
+                and has_pallas_sqrt_kernel()):
+            return "pallas"
+        return "xla"
+
     def _kernel_table(self, key, build):
         """The device table in a Pallas kernel's [4, N, E] int8 digit
         form, built by ``build(table_device)`` once per kernel layout
@@ -884,6 +899,7 @@ class DPF(object):
         n = self.table_num_entries
         depth = n.bit_length() - 1
         k = self.resolved_eval_knobs(pk.batch)
+        annotate(kernel=k["kernel_impl"])
         chunk = k["chunk_leaves"]
         if n % chunk:
             raise ValueError(
@@ -948,6 +964,7 @@ class DPF(object):
                 note_swallowed("api.sqrt_kernel_unsupported",
                                ValueError(reason))
                 kernel = "xla"
+        annotate(kernel=kernel)
         table = self.table_device
         if kernel == "pallas":
             from .ops.pallas_level import table_digits
@@ -976,6 +993,7 @@ class DPF(object):
         cw1, cw2, last = pk.cw1, pk.cw2, pk.last
         n = self.table_num_entries
         k = self.resolved_eval_knobs(pk.batch)
+        annotate(kernel=k["kernel_impl"])
         if k["kernel_impl"] == "pallas":
             table = self.table_device
             if self.prf_method != PRF_AES128:

@@ -331,16 +331,15 @@ def expand_and_contract(cw1, cw2, last, table_perm, *, depth: int,
     f = n // c  # frontier width
     assert c * f == n and depth == int(np.log2(n))
     if kernel_impl == "pallas":
-        from ..core.prf import (PRF_AES128, PRF_CHACHA20, PRF_CHACHA20_BLK,
-                                PRF_SALSA20, PRF_SALSA20_BLK)
+        from ..core.prf import PRF_AES128
+        from ..ops.pallas_level import has_subtree_core
         if prf_method == PRF_AES128:
             sbox = (aes_impl.split(":", 1)[1]
                     if aes_impl and ":" in aes_impl else None)
             return _expand_contract_pallas_aes(
                 cw1, cw2, last, table_perm, depth=depth,
                 chunk_leaves=c, dot_impl=dot_impl, sbox=sbox)
-        assert prf_method in (PRF_CHACHA20, PRF_SALSA20,
-                              PRF_CHACHA20_BLK, PRF_SALSA20_BLK), (
+        assert has_subtree_core(prf_method), (
             "kernel_impl='pallas' supports ChaCha20/Salsa20(+_BLK)/AES128")
         return _expand_contract_pallas(cw1, cw2, last, table_perm,
                                        depth=depth, f=f,

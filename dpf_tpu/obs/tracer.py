@@ -283,3 +283,16 @@ def span(name: str, **attrs):
     if t is None:
         return NULL_SPAN
     return t.span(name, **attrs)
+
+
+def annotate(**attrs) -> None:
+    """Attach ``attrs`` to the calling thread's innermost open span:
+    code that learns a fact inside a span its caller opened (the kernel
+    a dispatch resolved) names it there.  One global read when tracing
+    is off."""
+    t = _TRACER
+    if t is None:
+        return
+    stack = getattr(t._local, "stack", None)
+    if stack:
+        stack[-1].set(**attrs)

@@ -154,6 +154,12 @@ _CORES = {2: _chacha_core_planes, 1: _salsa_core_planes}  # prf id -> core
 _BLK_CORES = {4: _salsa_block_planes, 5: _chacha_block_planes}
 
 
+def has_subtree_core(prf_method: int) -> bool:
+    """True when the subtree kernel has a plane core for this PRF id
+    (Salsa20/ChaCha20 and their block-PRG forms; not AES or DUMMY)."""
+    return prf_method in _CORES or prf_method in _BLK_CORES
+
+
 def _add128_planes(val, cw):
     """val + cw mod 2^128 on two 4-plane lists (explicit carry chain)."""
     out = []
@@ -354,9 +360,14 @@ def _make_subtree_kernel(sched: tuple, prf_method: int = 2):
     return kernel
 
 
-# default tile knobs: widest level state = 16 words x [TB, C/2] u32
-PALLAS_TB = 32       # key tile (sublane-friendly multiple of 8)
-PALLAS_MAX_C = 4096  # leaves per subtree -> ~4 MB cipher state in VMEM
+# default tile knobs: widest level state = 16 words x [TB, C/2] u32.
+# On a v5e at N = 2^16, B = 64 and 512 (PERF.md): the binary kernel
+# (ChaCha20) ran 11-29 % faster per key at TB = 8 than at 16, 32 or 64,
+# and slower at C = 2048; the radix-4 kernel (ChaCha20-BLK) ran 3 %
+# faster at TB = 32 than at 8.
+PALLAS_TB = 8         # binary key tile (one sublane group)
+PALLAS_TB_MIXED = 32  # radix-4 key tile (a smaller batch: its size, >= 8)
+PALLAS_MAX_C = 4096   # leaves per subtree -> 1 MiB cipher state in VMEM
 
 
 def _kernel_leaf_order(table_perm, f_cnt: int, sched: tuple):
@@ -393,7 +404,6 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
     c = n // f_cnt
     assert c == int(np.prod(sched)), (c, sched)
 
-    tb = tb or min(PALLAS_TB, max(8, bsz))
     pb = (-bsz) % tb
     if pb:
         frontier = jnp.pad(frontier, ((0, pb), (0, 0), (0, 0)))
@@ -456,7 +466,7 @@ def _subtree_contract_pallas_impl(frontier, cw1, cw2, table_perm, *,
            for k in range(levels) for b in (0, 1)]
     return _subtree_contract_run(
         frontier, cw1, cw2, table_perm, idx=idx, sched=(2,) * levels,
-        prf_method=prf_method, interpret=interpret, tb=tb)
+        prf_method=prf_method, interpret=interpret, tb=tb or PALLAS_TB)
 
 
 _subtree_contract_pallas_jit = functools.partial(jax.jit, static_argnames=(
@@ -493,6 +503,7 @@ def _subtree_contract_pallas_mixed_impl(frontier, cw1, cw2, table_perm, *,
     sched = tuple(ars[f_lv:])
     idx = [offs[j] + b for j in range(f_lv, len(ars))
            for b in range(ars[j])]
+    tb = tb or min(PALLAS_TB_MIXED, max(8, frontier.shape[0]))
     return _subtree_contract_run(
         frontier, cw1, cw2, table_perm, idx=idx, sched=sched,
         prf_method=prf_method, interpret=interpret, tb=tb)

@@ -59,7 +59,8 @@ import jax
 import jax.numpy as jnp
 
 from .pallas_level import (_BLK_CORES, _CORES, _add128_planes,
-                           _compiler_params, _dot_digits, table_digits)
+                           _compiler_params, _dot_digits, has_subtree_core,
+                           table_digits)
 
 # default tile knobs: widest live state = 16 cipher words x [TB, cells]
 # u32 (the block-PRG ids quarter that — one block per 4 rows).  These
@@ -75,7 +76,7 @@ def pallas_sqrt_unsupported(prf_method: int, r: int) -> str | None:
     Callers that resolved ``kernel_impl="pallas"`` degrade to the xla
     scan path with provenance (``note_swallowed``) instead of raising —
     only an EXPLICIT pallas pin surfaces the reason as an error."""
-    if prf_method not in _CORES and prf_method not in _BLK_CORES:
+    if not has_subtree_core(prf_method):
         return ("prf id %d has no Pallas plane core (AES stays on the "
                 "XLA dispatch path)" % prf_method)
     if prf_method in _BLK_CORES and r % 4:

@@ -293,8 +293,8 @@ def _ggm_variant_invalid(v: KernelVariant, *, n: int, batch: int,
             v.dot_impl not in matmul128.available_impls():
         return "dot_impl %r unavailable" % (v.dot_impl,)
     if eng == "pallas":
-        from ..ops.pallas_level import _BLK_CORES, _CORES, PALLAS_MAX_C
-        if prf_method not in _CORES and prf_method not in _BLK_CORES:
+        from ..ops.pallas_level import PALLAS_MAX_C, has_subtree_core
+        if not has_subtree_core(prf_method):
             return "prf %d has no Pallas plane core" % (prf_method,)
         if v.f_levels is not None:
             fl = int(v.f_levels)
@@ -841,9 +841,9 @@ def kernel_search_ggm(n: int, batch: int, *, entry_size: int = 16,
     timings: dict[str, float] = {}
 
     import jax
-    from ..ops.pallas_level import _BLK_CORES, _CORES
-    subtree_prf_ok = prf_method in _CORES or prf_method in _BLK_CORES
-    time_pallas = jax.default_backend() == "tpu" and subtree_prf_ok
+    from ..ops.pallas_level import has_subtree_core
+    time_pallas = (jax.default_backend() == "tpu"
+                   and has_subtree_core(prf_method))
 
     def measure(v: KernelVariant) -> float | None:
         nonlocal tried, rejected
@@ -944,7 +944,7 @@ def kernel_search_ggm(n: int, batch: int, *, entry_size: int = 16,
     # --- the subtree-kernel population: parity-gate every member (the
     # gate that makes the search meaningful off-TPU; on TPU they also
     # raced above).  Any parity failure is a correctness escape.
-    gate_prf = prf_method if subtree_prf_ok else PRF_CHACHA20
+    gate_prf = prf_method if has_subtree_core(prf_method) else PRF_CHACHA20
     pallas_pop = [KernelVariant(family="ggm", engine="pallas")]
     while len(pallas_pop) < max(2, population // 2):
         v = (mutate_variant(rng, rng.choice(pallas_pop), n=n, batch=pb,

@@ -51,7 +51,10 @@ SQRT_STAGES = ("row_chunk", "dot_impl", "kernel_impl")
 
 def heuristic_knobs(n: int, batch: int, *, prf_method: int,
                     radix: int = 2, scheme: str = "logn") -> dict:
-    """The static-heuristic knob set (what an untuned process runs)."""
+    """The static-heuristic knob set: what an untuned process runs, but
+    for the kernel on a TPU, where binary Salsa/ChaCha GGM resolves the
+    subtree kernel (``api.DPF._heuristic_kernel``).  The tuners time it
+    as the xla scan it names."""
     from ..core import prf as _prf
     if scheme == "sqrtn":
         from ..core import sqrtn

@@ -6,7 +6,9 @@ from __future__ import annotations
 
 def has_pallas_sqrt_kernel(backend: str | None = None) -> bool:
     """True when the Pallas TPU kernels can compile AND run in this
-    process: the backend is TPU.  Elsewhere resolvers degrade a tuned
+    process: the backend is TPU.  There the logn resolver's heuristic
+    picks the subtree kernel for binary Salsa/ChaCha GGM
+    (``api.DPF._heuristic_kernel``).  Elsewhere resolvers degrade a tuned
     or searched ``kernel_impl="pallas"`` to ``"xla"`` with provenance
     (``api.resolved_eval_knobs`` reports ``kernel_resolved_from=
     "degraded"`` and counts it via ``note_swallowed``) — the interpreter

@@ -52,7 +52,8 @@ class EvalConfig:
     aes_impl: str = "auto"  # "auto"|"gather"|"bitsliced"[":bp"|":tower"]
     kernel_impl: str | None = "xla"  # "xla" | "pallas" (ChaCha/Salsa subtree
     #                 kernel) | "dispatch" (per-level programs; fast compile)
-    #                 | None/"auto" (tuned, else "xla")
+    #                 | None/"auto" (tuned, else "pallas" for binary
+    #                 Salsa/ChaCha GGM on a TPU, "xla" elsewhere)
     dispatch_group: int | None = None  # dispatch mode: frontier subtrees
     #                 expanded per pass (None = auto; larger = fewer host
     #                 round-trips, more live leaf memory per pass)

@@ -206,6 +206,38 @@ def test_eval_tpu_spans_decode_dispatch_fetch_per_chunk():
             if e["name"] == "eval_tpu.decode"] == [4, 2]
 
 
+@pytest.mark.parametrize("construction,kernel", [
+    ("logn", "xla"), ("logn.dispatch", "dispatch"), ("radix4", "xla"),
+    ("sqrtn", "xla")])
+def test_dispatch_spans_carry_the_resolved_kernel(construction, kernel):
+    """``eval_tpu.dispatch`` and the engine's ``dispatch`` name the kernel
+    each dispatch resolved, so a trace shows which path ran."""
+    from dpf_tpu import DPF
+    from dpf_tpu.utils.config import EvalConfig
+    cfg = {"logn": None,
+           "logn.dispatch": EvalConfig(prf_method=DPF.PRF_DUMMY,
+                                       kernel_impl="dispatch"),
+           "radix4": EvalConfig(prf_method=DPF.PRF_DUMMY, radix=4),
+           "sqrtn": EvalConfig(prf_method=DPF.PRF_DUMMY, scheme="sqrtn"),
+           }[construction]
+    dpf = DPF(prf=DPF.PRF_DUMMY, config=cfg)
+    n = 1024
+    dpf.eval_init(np.arange(n * 3, dtype=np.int32).reshape(n, 3))
+    keys = [dpf.gen((i * 31) % n, n)[0] for i in range(3)]
+    engine = dpf.serving_engine(buckets=(4,), max_in_flight=1)
+    ref = np.asarray(dpf.eval_tpu(keys))  # compile outside the record
+    engine.submit(keys).result()
+    t = obs_tracer.enable()
+    t.clear()
+    assert np.array_equal(np.asarray(dpf.eval_tpu(keys)), ref)
+    assert np.array_equal(engine.submit(keys).result(), ref)
+    evs = t.events()
+    for name in ("eval_tpu.dispatch", "dispatch"):
+        spans = [e for e in evs if e["name"] == name]
+        assert len(spans) == 1, name
+        assert spans[0]["attrs"]["kernel"] == kernel, name
+
+
 def test_backpressure_span_only_when_the_window_is_full():
     dpf, keys = _small_dpf()
     engine = dpf.serving_engine(buckets=(4,), max_in_flight=1)

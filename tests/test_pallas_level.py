@@ -14,6 +14,7 @@ tests/test_tpu_compile.py; chip_smoke.py runs them on the chip.
 """
 
 import numpy as np
+import pytest
 
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
@@ -141,3 +142,65 @@ def test_pallas_full_path_via_config(monkeypatch):
     got = np.asarray(d.eval_tpu(keys))
     want = np.asarray(ref.eval_tpu(keys))
     assert (got == want).all()
+
+
+# the heuristic kernel rule: (case id) -> (DPF kwargs, probe patched to
+# report a TPU, planted tuning entry, kernel, provenance)
+_RULE_CASES = {
+    "chacha.tpu": (dict(prf=2), True, None, "pallas", "heuristic"),
+    "salsa.tpu": (dict(prf=1), True, None, "pallas", "heuristic"),
+    "salsa_blk.tpu": (dict(prf=4), True, None, "pallas", "heuristic"),
+    "chacha_blk.tpu": (dict(prf=5), True, None, "pallas", "heuristic"),
+    "aes.tpu": (dict(prf=3), True, None, "xla", "heuristic"),
+    "dummy.tpu": (dict(prf=0), True, None, "xla", "heuristic"),
+    "radix4.tpu": (dict(radix=4), True, None, "xla", "heuristic"),
+    "sqrtn.tpu": (dict(prf=2, scheme="sqrtn"), True, None, "xla",
+                  "heuristic"),
+    "config_xla.tpu": (dict(kernel_impl="xla"), True, None, "xla",
+                       "config"),
+    "tuned_xla.tpu": (dict(prf=2), True,
+                      {"kernel_impl": "xla", "chunk_leaves": 1024},
+                      "xla", "tuned"),
+    # a tuned chunk naming no kernel was timed on the scan: it never
+    # rides the subtree kernel
+    "tuned_chunk_only.tpu": (dict(prf=2), True, {"chunk_leaves": 1024},
+                             "pallas", "heuristic"),
+    "chacha.cpu": (dict(prf=2), False, None, "xla", "heuristic"),
+    "salsa.cpu": (dict(prf=1), False, None, "xla", "heuristic"),
+    "salsa_blk.cpu": (dict(prf=4), False, None, "xla", "heuristic"),
+    "chacha_blk.cpu": (dict(prf=5), False, None, "xla", "heuristic"),
+    "aes.cpu": (dict(prf=3), False, None, "xla", "heuristic"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE_CASES))
+def test_heuristic_kernel_rule(case, monkeypatch):
+    """With no config, tuned or searched kernel, binary GGM over a PRF
+    with a subtree core resolves the subtree kernel where it compiles
+    (the probe says TPU), with its own chunk; everything else, and
+    every PRF on this CPU, resolves the xla scan."""
+    import dpf_tpu
+    from dpf_tpu.ops.pallas_level import pallas_chunk_leaves
+    from dpf_tpu.utils import compat
+    from dpf_tpu.utils.config import EvalConfig
+
+    kwargs, on_tpu, tuned, kernel, prov = _RULE_CASES[case]
+    if on_tpu:
+        monkeypatch.setattr(compat, "has_pallas_sqrt_kernel",
+                            lambda backend=None: True)
+    if "radix" in kwargs or "kernel_impl" in kwargs:
+        d = dpf_tpu.DPF(config=EvalConfig(
+            prf_method=kwargs.get("prf", 2), radix=kwargs.get("radix", 2),
+            kernel_impl=kwargs.get("kernel_impl")))
+    else:
+        d = dpf_tpu.DPF(**kwargs)
+    n, batch = 1 << 12, 8
+    d.eval_init(np.zeros((n, 16), np.int32))
+    if tuned is not None:
+        d._tuned_cache[batch] = dict(tuned)
+    kn = d.resolved_eval_knobs(batch)
+    assert (kn["kernel_impl"], kn["kernel_resolved_from"]) == (kernel, prov)
+    if kernel == "pallas":
+        assert kn["chunk_leaves"] == pallas_chunk_leaves(n)
+    elif tuned is not None:
+        assert kn["chunk_leaves"] == tuned["chunk_leaves"]

@@ -220,3 +220,34 @@ def test_sharded_binary_eval_compiles_on_four_chips(compile_tpu, topo):
                        on((B, 4), U32, P("batch")),
                        on((N, E), I32, P("table", None)))
     assert "all-reduce" in text
+
+
+@pytest.mark.parametrize("batch", [64, 512])
+def test_resolved_binary_chacha_program_compiles_for_v5e(compile_tpu,
+                                                         monkeypatch,
+                                                         batch):
+    """The program ``DPF(prf=PRF_CHACHA20)`` dispatches on a TPU at
+    N = 2^16 (the benchmark's deployment), from the knobs the resolver
+    gives there: one subtree kernel, reading the int8 digit table."""
+    from dpf_tpu import DPF
+    from dpf_tpu.core import expand
+    from dpf_tpu.utils import compat
+    monkeypatch.setattr(compat, "has_pallas_sqrt_kernel",
+                        lambda backend=None: True)
+    n = 1 << 16
+    d = DPF(prf=DPF.PRF_CHACHA20)
+    d.eval_init(np.zeros((n, E), np.int32))
+    kn = d.resolved_eval_knobs(batch)
+    assert kn["kernel_impl"] == "pallas"
+
+    def fn(cw1, cw2, last, t):
+        return expand.expand_and_contract(
+            cw1, cw2, last, t, depth=n.bit_length() - 1,
+            prf_method=DPF.PRF_CHACHA20, chunk_leaves=kn["chunk_leaves"],
+            dot_impl=kn["dot_impl"], aes_impl=kn["aes_impl"],
+            round_unroll=kn["round_unroll"], kernel_impl=kn["kernel_impl"],
+            f_levels=kn["f_levels"])
+    calls = _custom_calls(compile_tpu(
+        fn, S((batch, 64, 4), U32), S((batch, 64, 4), U32),
+        S((batch, 4), U32), S((4, n, E), jnp.int8)))
+    assert len(calls) == 1 and "dpf_subtree_contract" in calls[0]
