@@ -5,7 +5,11 @@ metrics.  Everything that belongs to one of them sits in a file of its
 own, found by name:
 
 * ``configs/<config>.json``: the deployment (table, PRF, the
-  guarantees) and how its answers are checked;
+  guarantees) and how its answers are checked: ``check.recover`` and
+  ``check.reference`` answers sampled, and ``check.reference_on``, where
+  the plain reference runs: absent or ``"host"``, NumPy on the host;
+  ``"devices"``, ``jax.numpy`` on the cell's devices
+  (``reference_devices.py``), for tables too large for the host walk;
 * ``traffic/<mix>.json``: the parameters of one mix and the name of
   the driver in ``drivers/`` that plays them;
 * ``metrics/<metric>.py``: a reader, ``read(record)``, of one per-layer
@@ -18,8 +22,9 @@ seconds, annotate) -> Window``, ``server1(state, idx) -> shares`` and
 the profiler with ``--trace 1``), reads the device's memory peak, has
 the program compute server 1's shares of a sample outside the window,
 frees the program, and only then runs the plain reference
-(``reference.py``) over a second sample.  The numbers compared, each
-with its limit, go last, on standard error and in the result line.
+(``reference.py``, block by block) over a second sample.  The numbers
+compared, each with its limit, go last, on standard error and in the
+result line.
 """
 
 from __future__ import annotations
@@ -41,6 +46,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 KEYGEN_THREADS = 4
 REFERENCE_THREADS = 4
+TABLE_BLOCK_BYTES = 256 << 20
+REFERENCE_PLACES = ("host", "devices")
 
 
 def use_program_defaults() -> None:
@@ -205,10 +212,19 @@ def rng_for(seed: int, *tag: int) -> np.random.Generator:
 
 
 def make_table(config: dict, seed: int) -> np.ndarray:
+    """The [2^log2_rows, entry_words] int32 table, drawn from the seed in
+    row blocks of about ``TABLE_BLOCK_BYTES`` of int64 draws into one
+    int32 array: one generator drawn in blocks gives the stream a single
+    draw gives, so the table does not depend on the block size."""
     rng = rng_for(seed, 0)
-    return rng.integers(-2 ** 31, 2 ** 31,
-                        (1 << config["log2_rows"], config["entry_words"]),
-                        dtype=np.int64).astype(np.int32)
+    n, e = 1 << config["log2_rows"], config["entry_words"]
+    table = np.empty((n, e), np.int32)
+    step = max(1, TABLE_BLOCK_BYTES // (8 * e))
+    for lo in range(0, n, step):
+        hi = min(n, lo + step)
+        table[lo:hi] = rng.integers(-2 ** 31, 2 ** 31, (hi - lo, e),
+                                    dtype=np.int64)
+    return table
 
 
 def mint_keys(dpf, n: int, count: int, seed: int, tag: int):
@@ -330,8 +346,7 @@ def compare(cell: Cell, state, drv, win: Window, seed: int, table,
     s1 = drv.server1(state, served[pick_rec])
     drv.release(state)
     keys0 = state.keys0
-    want = reference_shares(keys0[served[pick_ref]], table,
-                            cell.config["prf"])
+    want = reference_shares(keys0[served[pick_ref]], table, cell)
     truth = table[state.rows[served[pick_rec]]]
 
     def numbers(shares):
@@ -345,8 +360,8 @@ def compare(cell: Cell, state, drv, win: Window, seed: int, table,
     if control:
         shares = win.shares.copy()
         both = np.union1d(pick_rec, pick_ref)
-        shares[both] = reference_shares(keys0[served[both]], table,
-                                        cell.config["prf"], "float32")
+        shares[both] = reference_shares(keys0[served[both]], table, cell,
+                                        "float32")
         ctrl = numbers(shares)
     return nums, ctrl
 
@@ -355,10 +370,35 @@ def passes(nums: dict) -> bool:
     return all(v <= LIMITS[k] for k, v in nums.items())
 
 
-def reference_shares(keys, table, prf: str, contraction="exact"):
+def reference_place(config: dict) -> str:
+    """Where the configuration's plain reference runs; exits, before any
+    set-up, on a place it does not know or a PRF that cannot run
+    there."""
     from benchmarks import reference
+    place = config["check"].get("reference_on", "host")
+    if place not in REFERENCE_PLACES:
+        raise SystemExit("check.reference_on %r: not one of %s"
+                         % (place, ", ".join(REFERENCE_PLACES)))
+    if place == "devices" and not reference.runs_under(config["prf"],
+                                                       "jax.numpy"):
+        raise SystemExit("check.reference_on 'devices': the reference's "
+                         "PRF %r has no jax.numpy path (prfs/%s.py)"
+                         % (config["prf"], config["prf"]))
+    return place
+
+
+def reference_shares(keys, table, cell: Cell, contraction="exact"):
+    """The plain reference's shares of ``keys``: on the host in a few
+    threads, or on the cell's devices."""
+    prf = cell.config["prf"]
     if len(keys) == 0:
         return np.zeros((0, table.shape[1]), np.int32)
+    if reference_place(cell.config) == "devices":
+        import jax
+        from benchmarks import reference_devices
+        return reference_devices.share(keys, table, prf, contraction,
+                                       jax.devices()[:cell.chips])
+    from benchmarks import reference
     parts = np.array_split(np.arange(len(keys)),
                            min(REFERENCE_THREADS, len(keys)))
     with ThreadPoolExecutor(len(parts)) as ex:
@@ -375,6 +415,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
              events: CompileEvents | None = None) -> dict:
     """One run of cell ``name``; returns the result line as a dict."""
     cell = find_cell(name, root)
+    reference_place(cell.config)
     device = look(cell.chips, root)
     events = events or CompileEvents()
     table = make_table(cell.config, seed)

@@ -2,12 +2,15 @@
 
 The seed's 16 little-endian bytes are the key, the position's 16
 little-endian bytes the plaintext, and the ciphertext is read back
-little-endian.  Plain NumPy; it imports nothing of the program.
+little-endian.  Plain NumPy only (it indexes tables and writes in
+place); it imports nothing of the program.
 """
 
 from __future__ import annotations
 
 import numpy as np
+
+NAMESPACES = ("numpy",)
 
 
 def _gf_mul(a: int, b: int) -> int:
@@ -78,9 +81,11 @@ def encrypt(keys: np.ndarray, plaintexts) -> list:
     return states
 
 
-def pair(seeds: np.ndarray):
+def pair(seeds: np.ndarray, xp=np, loop=None):
     """AES-128 under each seed of positions 0 and 1: two [M, 4] uint32
-    arrays (little-endian limbs)."""
+    arrays (little-endian limbs).  ``xp`` has to be NumPy."""
+    if xp is not np:
+        raise ValueError("the AES-128 reference runs under NumPy only")
     m = seeds.shape[0]
     keys = np.ascontiguousarray(seeds).view(np.uint8).reshape(m, 16)
     pts = []

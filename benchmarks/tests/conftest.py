@@ -4,7 +4,8 @@
 
 ``tiny_root`` is a copy of the benchmark (``BENCHMARK.json`` and
 ``benchmarks/``) whose configurations hold 2^10 rows and whose pools
-are small, so a whole run of a cell fits a test."""
+are small, so a whole run of a cell fits a test; ``tiny_devices_root``
+is the same with each configuration's reference on the devices."""
 
 import json
 import os
@@ -39,13 +40,12 @@ def edit_json(path: str, **changes) -> None:
         json.dump(d, f)
 
 
-@pytest.fixture(scope="session")
-def tiny_root(tmp_path_factory):
-    root = copy_benchmark(str(tmp_path_factory.mktemp("bench")))
+def make_tiny_root(dst: str, **check) -> str:
+    root = copy_benchmark(dst)
     spec = json.load(open(os.path.join(root, "BENCHMARK.json")))
     for c in spec["configs"]:
         edit_json(os.path.join(root, c["file"]), log2_rows=10,
-                  check={"recover": 64, "reference": 4})
+                  check=dict({"recover": 64, "reference": 4}, **check))
     for w in spec["workloads"]:
         path = os.path.join(root, "benchmarks", "traffic",
                             w["traffic"] + ".json")
@@ -53,6 +53,17 @@ def tiny_root(tmp_path_factory):
             if "pool_keys" in json.load(f):
                 edit_json(path, pool_keys=2048)
     return root
+
+
+@pytest.fixture(scope="session")
+def tiny_root(tmp_path_factory):
+    return make_tiny_root(str(tmp_path_factory.mktemp("bench")))
+
+
+@pytest.fixture(scope="session")
+def tiny_devices_root(tmp_path_factory):
+    return make_tiny_root(str(tmp_path_factory.mktemp("bench_devices")),
+                          reference_on="devices")
 
 
 def cpu_device(chips, root):

@@ -127,6 +127,37 @@ def test_exits_nonzero_with_only_the_benchmark_files(tmp_path):
     assert "dpf_tpu" in p.stderr
 
 
+@pytest.mark.parametrize("block_bytes", [8 * 16 * 100 + 8, 8 * 16 * 333,
+                                         harness.TABLE_BLOCK_BYTES])
+def test_table_in_row_blocks_is_the_one_shot_table(monkeypatch,
+                                                   block_bytes):
+    """Drawn in row blocks (here 100, 333 and all 4,096 rows), the table is
+    the one-shot int64 draw cast to int32."""
+    monkeypatch.setattr(harness, "TABLE_BLOCK_BYTES", block_bytes)
+    cfg = {"log2_rows": 12, "entry_words": 16}
+    seed = 2 ** 33 + 5
+    want = harness.rng_for(seed, 0).integers(
+        -2 ** 31, 2 ** 31, (4096, 16), dtype=np.int64).astype(np.int32)
+    got = harness.make_table(cfg, seed)
+    assert got.dtype == np.int32 and np.array_equal(got, want)
+
+
+def test_existing_table_is_unchanged():
+    """The ``ref-chacha20-n16`` table of seed 1, byte for byte as the
+    one-shot draw made it."""
+    import hashlib
+    cfg = harness.find_cell("chacha20-n16.batch512").config
+    digest = hashlib.sha256(harness.make_table(cfg, 1).tobytes())
+    assert digest.hexdigest() == ("4b17359bf81459239398ea0ff4b7afc1"
+                                  "d72dadf61abfc08d67297b9c85a7e7a2")
+
+
+def test_existing_configs_compare_on_the_host():
+    for cell in SPEC["workloads"]:
+        assert harness.reference_place(
+            harness.find_cell(cell["name"]).config) == "host"
+
+
 def test_arrivals_and_keys_follow_the_seed():
     from benchmarks.drivers import open_loop
     a = open_loop.arrival_times(30.0, 10.0, 2 ** 33 + 1)

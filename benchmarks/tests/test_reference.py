@@ -67,18 +67,28 @@ def test_reference_matches_program_leaves(prf):
                           R.leaves_low32(np.asarray(k0), prf))
 
 
-@pytest.mark.parametrize("path", ["reference.py"] + sorted(
+@pytest.mark.parametrize("path", [
+    "reference.py", "reference_devices.py"] + sorted(
     os.path.join("prfs", p) for p in os.listdir(
         os.path.join(ROOT, "benchmarks", "prfs")) if p.endswith(".py")))
 def test_reference_imports_nothing_of_the_program(path):
+    """The reference's files import the standard library, NumPy, JAX
+    (on the devices) and one another, nothing else."""
     tree = ast.parse(open(os.path.join(ROOT, "benchmarks", path)).read())
     names = set()
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):
-            names.update(a.name.split(".")[0] for a in node.names)
+            names.update(a.name for a in node.names)
         elif isinstance(node, ast.ImportFrom):
-            names.add((node.module or "").split(".")[0])
-    assert names <= {"__future__", "functools", "importlib", "numpy", "os"}
+            names.update((node.module or "") + "." + a.name
+                         for a in node.names)
+    allowed = {"__future__", "functools", "importlib", "numpy", "os",
+               "concurrent", "benchmarks.reference"}
+    if path == "reference_devices.py":
+        allowed.add("jax")
+    tops = {n if n.startswith("benchmarks.") else n.split(".")[0]
+            for n in names}
+    assert tops <= allowed, tops - allowed
 
 
 def test_a_new_prf_takes_a_new_file_only(tmp_path, monkeypatch):
@@ -89,11 +99,13 @@ def test_a_new_prf_takes_a_new_file_only(tmp_path, monkeypatch):
     monkeypatch.setattr(R, "PRF_DIR", str(tmp_path))
     seeds = np.arange(8, dtype=np.uint32).reshape(2, 4)
     got = R.prf_pair("chacha20copy", seeds)
+    assert R.runs_under("chacha20copy", "jax.numpy")
     from benchmarks.prfs import chacha20
     for a, b in zip(got, chacha20.pair(seeds)):
         assert np.array_equal(a, b)
     with pytest.raises(ValueError):
         R.prf_pair("no-such-prf", seeds)
+    assert not R.runs_under("aes128", "jax.numpy")
 
 
 def test_wire_key_checks():
@@ -104,3 +116,111 @@ def test_wire_key_checks():
     bad[0, 130 * 4] = 8                 # ... but n = 8
     with pytest.raises(ValueError):
         R.parse_keys(bad)
+
+
+# ----------------------------------------------- the walk before blocks
+# The unblocked walk and contraction as the reference ran them before
+# it ran block by block (carries through uint64), kept as the oracle
+# that the blocked walk has to equal.
+
+def _add128_uint64(a, b):
+    out = np.empty(np.broadcast_shapes(a.shape, b.shape), np.uint32)
+    carry = np.zeros(out.shape[:-1], np.uint64)
+    for j in range(4):
+        s = a[..., j].astype(np.uint64) + b[..., j] + carry
+        out[..., j] = s.astype(np.uint32)
+        carry = s >> np.uint64(32)
+    return out
+
+
+def _unblocked_share(keys, table, prf, contraction):
+    k = R.parse_keys(keys)
+    s_keys = k["seed"].shape[0]
+    seeds = k["seed"][:, None, :]
+    for step in range(k["depth"]):
+        i = k["depth"] - 1 - step
+        width = seeds.shape[1]
+        flat = seeds.reshape(-1, 4)
+        odd = (flat[:, 0] & 1).astype(bool).reshape(s_keys, width, 1)
+        kids = []
+        for b, out in enumerate(R.prf_pair(prf, flat)):
+            cw = np.where(odd, k["cw"][1][:, None, 2 * i + b],
+                          k["cw"][0][:, None, 2 * i + b])
+            kids.append(_add128_uint64(out.reshape(s_keys, width, 4), cw))
+        seeds = np.concatenate(kids, axis=1)
+    low = np.ascontiguousarray(seeds[..., 0])
+    if contraction == "exact":
+        prod = low @ np.ascontiguousarray(table).view(np.uint32)
+        return prod.astype(np.uint32).view(np.int32)
+    f = low.view(np.int32).astype(np.float32) @ table.astype(np.float32)
+    wrapped = np.fmod(f.astype(np.float64), 2.0 ** 32).astype(np.int64)
+    return (wrapped & 0xFFFFFFFF).astype(np.uint32).view(np.int32)
+
+
+def _keys_and_table(prf, logn, count, seed):
+    from dpf_tpu import DPF
+    n = 1 << logn
+    rng = np.random.default_rng(seed)
+    table = rng.integers(-2 ** 31, 2 ** 31, (n, 16),
+                         dtype=np.int64).astype(np.int32)
+    rows = rng.integers(0, n, count)
+    k0, k1 = DPF(prf=PROGRAM_PRF[prf]).gen_batch(
+        rows, n, seeds=[rng.bytes(16) for _ in rows])
+    return np.asarray(k0), np.asarray(k1), rows, table
+
+
+def test_add128_carries_without_uint64():
+    rng = np.random.default_rng(9)
+    a = rng.integers(0, 2 ** 32, (64, 4), dtype=np.uint64).astype(np.uint32)
+    b = rng.integers(0, 2 ** 32, (64, 4), dtype=np.uint64).astype(np.uint32)
+    a[:8] = 0xFFFFFFFF                      # carries through every limb
+    b[:8, 0] = 1
+    b[:8, 1:] = 0
+    b[8:16] = 0xFFFFFFFF
+    assert np.array_equal(R.add128(a, b), _add128_uint64(a, b))
+    assert (R.add128(a[:8], b[:8]) == 0).all()
+
+
+@pytest.mark.parametrize("contraction", ["exact", "float32"])
+@pytest.mark.parametrize("prf", sorted(PROGRAM_PRF))
+@pytest.mark.parametrize("logn", [10, 12, 14])
+def test_blocked_walk_equals_the_unblocked_one(logn, prf, contraction):
+    """In one block (the existing cells' sizes) the blocked walk is the
+    unblocked one bit for bit in both contractions.  Over many blocks the
+    exact share still is; the float32 control, wrapped block by block,
+    still fails every key."""
+    k0, _, _, table = _keys_and_table(prf, logn, 3, logn)
+    want = _unblocked_share(k0, table, prf, contraction)
+    assert R.split(logn, 3) == 0
+    assert np.array_equal(R.share(k0, table, prf, contraction), want)
+    for block_seeds in (3 * 2 ** (logn - 3), 3 * 2 ** (logn // 2), 3):
+        assert R.split(logn, 3, block_seeds) > 0
+        got = R.share(k0, table, prf, contraction, block_seeds)
+        if contraction == "exact":
+            assert np.array_equal(got, want)
+        else:
+            exact = _unblocked_share(k0, table, prf, "exact")
+            assert (got != exact).any(axis=1).all()
+
+
+def test_frontier_node_roots_its_strided_rows():
+    """Node ``r`` after ``t`` steps roots the leaves of rows r::2^t."""
+    k0, _, _, _ = _keys_and_table("chacha20", 10, 2, 1)
+    k = R.parse_keys(k0)
+    leaves = R.leaves_low32(k0, "chacha20")
+    front = R.frontier(k, 4, "chacha20")
+    assert front.shape == (2, 16, 4)
+    for r in (0, 5, 15):
+        sub = R.walk(front[:, r:r + 1], k["cw"], 6, 0, "chacha20")
+        assert np.array_equal(sub[..., 0], leaves[:, r::16])
+
+
+def test_block_split():
+    assert R.split(16, 8) == 0
+    assert R.split(28, 32) == 11
+    assert R.split(28, 8) == 9
+    assert R.split(28, 1) == 6
+    assert R.split(10, 3, 3) == 10
+    with pytest.raises(ValueError):
+        R.contract(np.zeros((1, 1), np.uint32), np.zeros((1, 1), np.int32),
+                   "bfloat16")
