@@ -266,7 +266,9 @@ def in_use(devs):
 
 
 def phase_multichip(log, n=N_MESH, batch=MESH_BATCH, prf=None, seed=3):
-    """Sharded servers over every device vs ``eval_tpu`` on device 0."""
+    """Sharded servers over every device vs ``eval_tpu`` on device 0,
+    each on the kernel it resolves (on a TPU, binary ChaCha20 on the
+    subtree kernel per shard, as ``eval_tpu`` runs it)."""
     import jax
     from dpf_tpu import DPF
     from dpf_tpu.parallel.sharded import make_mesh, make_mesh_2d
@@ -299,6 +301,7 @@ def phase_multichip(log, n=N_MESH, batch=MESH_BATCH, prf=None, seed=3):
             assert np.array_equal(g, w), "%s differs from eval_tpu" % label
         check_rows(label, got[0], got[1], table, idx)
         log({"phase": label, "ok": True, "devices": len(held),
+             "kernel": srvs[0].resolved_eval_knobs(batch)["kernel_impl"],
              "bytes_in_use_rise": [a - b for a, b in zip(after, before)]})
         del srvs, singles  # free before the next layout's baseline
 

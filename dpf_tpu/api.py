@@ -862,17 +862,10 @@ class DPF(object):
 
     def _heuristic_kernel(self) -> str:
         """The logn kernel when no config, tuned or searched entry names
-        one: binary GGM over a PRF with a subtree core runs the
-        VMEM-resident subtree kernel where it compiles (a TPU), which
-        is bit-identical to the xla scan and 2.5-6x faster on a v5e
-        (PERF.md); AES, DUMMY, radix 4 and every other backend keep the
-        scan."""
-        from .ops.pallas_level import has_subtree_core
-        from .utils.compat import has_pallas_sqrt_kernel
-        if (self.radix == 2 and has_subtree_core(self.prf_method)
-                and has_pallas_sqrt_kernel()):
-            return "pallas"
-        return "xla"
+        one (``ops.pallas_level.heuristic_kernel``, the mesh's rule
+        too)."""
+        from .ops.pallas_level import heuristic_kernel
+        return heuristic_kernel(self.prf_method, self.radix)
 
     def _kernel_table(self, key, build):
         """The device table in a Pallas kernel's [4, N, E] int8 digit

@@ -457,6 +457,129 @@ def _expand_contract_pallas(cw1, cw2, last, table_perm, *, depth: int,
             interpret=interpret, tb=tb, prf_method=prf_method)
 
 
+def _pvary(x, axes):
+    """Type a shard_map scan carry as varying over the mesh axes.  Empty
+    ``axes`` (a caller outside any shard_map, e.g. the cluster tier's
+    host-local leaf-range eval) is identity: a cast over axis names
+    that don't exist would raise."""
+    return lax.pcast(x, tuple(axes), to="varying") if axes else x
+
+
+def _valid_psum_group(psum_group, n_chunks: int) -> int:
+    """The effective chunk-group size for grouped psums: 0 (one terminal
+    psum) unless ``psum_group`` divides the chunk count with at least
+    two groups — a tuned value from another shape degrades to the
+    terminal psum rather than failing the program."""
+    g = int(psum_group or 0)
+    return g if 0 < g < n_chunks and n_chunks % g == 0 else 0
+
+
+def _scan_psum_groups(body, zeros, xs, axis_name: str,
+                      outer_axes=("batch",)):
+    """Grouped-psum driver shared by the three sharded constructions.
+
+    Scans ``xs`` (every leaf already reshaped to ``[n_groups, g, ...]``)
+    one chunk-group at a time: each group accumulates locally through
+    ``body`` (a standard per-chunk scan body), the group partial is
+    psummed over ``axis_name``, and the psum result adds onto the outer
+    carry — int32 wrap keeps any grouping exact, and the collective has
+    no data dependency on the NEXT group's PRF expansion, so an async
+    backend overlaps ICI latency with compute.
+
+    Carry typing: the INNER partial is varying over ``outer_axes`` plus
+    ``axis_name`` (its body adds shard-local dot products), but the
+    OUTER carry holds only psum outputs — invariant along ``axis_name``
+    — so it is typed varying over ``outer_axes`` alone.  Typing it over
+    the reduced axis too would trip shard_map's out_specs invariance
+    check.  The 2D row x
+    entry-byte path passes ``outer_axes=("batch", "byte")``: its psum
+    runs over "table" only, so the carry still varies over the byte
+    axis (each byte shard holds a different entry block)."""
+    def gbody(acc, xs_g):
+        part0 = _pvary(zeros, tuple(outer_axes) + (axis_name,))
+        part, _ = lax.scan(body, part0, xs_g)
+        with jax.named_scope("dpf.psum"):
+            return acc + lax.psum(part, axis_name), None
+
+    acc, _ = lax.scan(gbody, _pvary(zeros, tuple(outer_axes)), xs)
+    return acc
+
+
+def eval_leaf_range(cw1, cw2, last, tbl, row0, *, depth: int,
+                    prf_method: int, chunk_leaves: int, n_total: int,
+                    kernel_impl: str = "xla", aes_impl: str | None = None,
+                    psum_group: int = 0, axis_name: str | None = None,
+                    carry_axes=("batch", "table")):
+    """The partial share of one contiguous range of BFS leaves, [row0,
+    row0 + rows): the per-shard step of every binary mesh and cluster
+    evaluation.
+
+    The range is whole frontier subtrees of ``chunk_leaves`` leaves.
+    Phase 1 expands the frontier (cheap: width ``n_total / chunk``) and
+    takes the range's nodes with a dynamic slice, so ``row0`` may be
+    traced (the mesh's axis index, or a cluster granule's offset).
+    Phase 2 is the subtree kernel (``kernel_impl="pallas"``, ``tbl`` the
+    range's ``[4, E, rows]`` int8 digit planes in the kernel's leaf
+    order, see ``parallel.sharded.place_table``) or the xla scan (``tbl``
+    the range's ``[rows, E]`` int32 rows in BFS order).
+
+    Returns ``(out, psummed)``: with a valid ``psum_group`` (and an
+    ``axis_name`` to reduce over) the xla scan psums every chunk group
+    and ``out`` is already the mesh-wide sum (``psummed=True``);
+    otherwise ``out`` is the range's partial and the caller reduces it.
+    ``carry_axes`` types the scan carry for shard_map callers; pass
+    ``()`` outside a mesh program.
+    """
+    pallas = kernel_impl == "pallas"
+    rows, e = (tbl.shape[2], tbl.shape[1]) if pallas else tbl.shape
+    bsz = last.shape[0]
+    c = chunk_leaves
+    f_local = rows // c                      # frontier nodes in the range
+    f_levels = int(np.log2(n_total // c))    # levels down to the frontier
+
+    with jax.named_scope("dpf.frontier"):
+        seeds = expand_levels(last[:, None, :], cw1, cw2, depth - 1,
+                              f_levels, prf_method, aes_impl)
+        seeds = lax.dynamic_slice_in_dim(seeds, row0 // c, f_local, axis=1)
+
+    if pallas:
+        from ..ops.pallas_level import subtree_contract_pallas
+        with jax.named_scope("dpf.subtree"):   # the contraction is inside
+            return subtree_contract_pallas(
+                seeds, cw1, cw2, tbl, depth=depth, f_levels=f_levels,
+                prf_method=prf_method, leaves_minor=True), False
+
+    def expand_subtree(node_seeds):
+        with jax.named_scope("dpf.subtree"):
+            s = expand_levels(node_seeds[:, None, :], cw1, cw2,
+                              depth - 1 - f_levels, depth - f_levels,
+                              prf_method, aes_impl)
+            return s[..., 0].astype(jnp.int32)
+
+    tbl_chunks = tbl.reshape(f_local, c, e)
+    if f_local == 1:
+        return _dot_i32(expand_subtree(seeds[:, 0, :]), tbl_chunks[0]), False
+
+    frontier = jnp.moveaxis(seeds, 1, 0)  # [f_local, B, 4]
+
+    def body(acc, xs):
+        node_seeds, chunk = xs
+        return acc + _dot_i32(expand_subtree(node_seeds), chunk), None
+
+    zeros = jnp.zeros((bsz, e), dtype=jnp.int32)
+    g = _valid_psum_group(psum_group, f_local) if axis_name else 0
+    if not g:
+        # inside shard_map the scan carry must be typed as varying over
+        # the mesh axes (the body's output is), or the carry mismatches
+        acc, _ = lax.scan(body, _pvary(zeros, carry_axes),
+                          (frontier, tbl_chunks))
+        return acc, False
+    return _scan_psum_groups(body, zeros, (
+        frontier.reshape(f_local // g, g, bsz, 4),
+        tbl_chunks.reshape(f_local // g, g, c, e)), axis_name,
+        outer_axes=tuple(a for a in carry_axes if a != axis_name)), True
+
+
 def choose_group(f: int, c: int) -> int:
     """Frontier nodes expanded together: the largest divisor of ``f``
     keeping the live leaf tensor under ~2^18 x batch x 16 B (shared by

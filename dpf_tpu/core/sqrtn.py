@@ -690,8 +690,7 @@ def _eval_sharded_sqrt_jit(seeds, cw1, cw2, table, *, prf_method,
     from jax.sharding import PartitionSpec as P
 
     from ..ops import matmul128
-    from ..parallel.sharded import (_pvary, _scan_psum_groups,
-                                    _valid_psum_group)
+    from .expand import _pvary, _scan_psum_groups, _valid_psum_group
 
     n_shards = mesh.shape["table"]
     k = seeds.shape[1]

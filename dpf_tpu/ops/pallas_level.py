@@ -160,6 +160,21 @@ def has_subtree_core(prf_method: int) -> bool:
     return prf_method in _CORES or prf_method in _BLK_CORES
 
 
+def heuristic_kernel(prf_method: int, radix: int = 2) -> str:
+    """The binary-GGM kernel when no config, tuned or searched entry
+    names one, on one chip (``api.DPF``) and on a mesh
+    (``parallel.sharded.ShardedDPFServer``) alike: binary GGM over a PRF
+    with a subtree core runs the VMEM-resident subtree kernel where it
+    compiles (a TPU), which is bit-identical to the xla scan and 2.5-6x
+    faster on a v5e (PERF.md); AES, DUMMY, radix 4 and every other
+    backend keep the scan."""
+    from ..utils.compat import has_pallas_sqrt_kernel
+    if (radix == 2 and has_subtree_core(prf_method)
+            and has_pallas_sqrt_kernel()):
+        return "pallas"
+    return "xla"
+
+
 def _add128_planes(val, cw):
     """val + cw mod 2^128 on two 4-plane lists (explicit carry chain)."""
     out = []
@@ -197,19 +212,20 @@ def table_digits(table):
     return jnp.stack(_digits_i8(jnp.asarray(table, jnp.int32)))
 
 
-def _dot_digits(lhs, rhs_ref):
+def _dot_digits(lhs, rhs_ref, rhs_k: int = 0):
     """Exact wrapping int32 ``lhs [M, K] @ rhs [K, E]`` on the MXU's
     int8 path: rhs comes as its 4 digit planes (``table_digits``), lhs
     is split here, and the 10 digit products with shift < 32 are
     accumulated.  Each product sums K terms of magnitude <= 2^14, so it
-    is exact in int32 for K < 2^17."""
+    is exact in int32 for K < 2^17.  ``rhs_k=1``: each plane is held
+    [E, K], the K axis minor."""
     a = _digits_i8(lhs)
     acc = None
     for s in range(4):
         t = None
         for i in range(s + 1):
             p = lax.dot_general(a[i], rhs_ref[s - i],
-                                (((1,), (0,)), ((), ())),
+                                (((1,), (rhs_k,)), ((), ())),
                                 preferred_element_type=jnp.int32)
             t = p if t is None else t + p
         t = t << (8 * s) if s else t
@@ -306,7 +322,8 @@ def chacha_level_step_pallas(seeds, cw1_lvl, cw2_lvl, interpret=False,
 # Fused subtree expand + contract (the production kernel)
 # ---------------------------------------------------------------------------
 
-def _make_subtree_kernel(sched: tuple, prf_method: int = 2):
+def _make_subtree_kernel(sched: tuple, prf_method: int = 2,
+                         leaves_minor: bool = False):
     """Kernel over a per-level arity schedule.  ``sched[k]`` is the
     fan-out of kernel level k; the sliced codeword arrays hold the levels'
     slots back to back in the same order (see the wrapper's ``idx``).
@@ -346,8 +363,8 @@ def _make_subtree_kernel(sched: tuple, prf_method: int = 2):
             off += a
             planes = [jnp.concatenate([children[b][i] for b in range(a)],
                                       axis=1) for i in range(4)]
-        contrib = _dot_digits(planes[0].astype(jnp.int32),
-                              table_ref)              # [TB, E]
+        contrib = _dot_digits(planes[0].astype(jnp.int32), table_ref,
+                              int(leaves_minor))      # [TB, E]
 
         @pl.when(f == 0)
         def _():
@@ -392,15 +409,30 @@ def subtree_digits(table_perm, f_cnt: int, sched: tuple):
     return table_digits(_kernel_leaf_order(table_perm, f_cnt, sched))
 
 
+@jax.jit
+def table_digits_t(table):
+    """[N, E] int32 rows -> [4, E, N] int8 digit planes, the leaves on
+    the minor axis.  The kernel reads this form with ``leaves_minor``:
+    the chip tiles an int8 array's minor axis by 128, so [4, N, 16]
+    planes held for the row-major block occupy 8x their bytes, which a
+    table of 2^26 rows a chip cannot afford."""
+    return jnp.swapaxes(table_digits(table), 1, 2)
+
+
 def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
-                          prf_method, interpret, tb):
+                          prf_method, interpret, tb,
+                          leaves_minor: bool = False):
     """Shared launcher: slice codeword slots (``idx``, level-major), pad
     the batch to the key-tile multiple, run the schedule kernel.
-    ``table_perm`` is the [N, E] int32 table or its ``subtree_digits``."""
+    ``table_perm`` is the [N, E] int32 table or its ``subtree_digits``;
+    with ``leaves_minor`` its digits held [4, E, N] (``table_digits_t``
+    of the rows in the kernel's leaf order)."""
     from jax.experimental import pallas as pl
 
     bsz, f_cnt, _ = frontier.shape
     n, e = table_perm.shape[-2:]
+    if leaves_minor:
+        e, n = n, e
     c = n // f_cnt
     assert c == int(np.prod(sched)), (c, sched)
 
@@ -423,7 +455,10 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
               else subtree_digits(table_perm, f_cnt, tuple(sched)))
 
     grid = (bp // tb, f_cnt)
-    kernel = _make_subtree_kernel(tuple(sched), prf_method)
+    kernel = _make_subtree_kernel(tuple(sched), prf_method, leaves_minor)
+    table_spec = (pl.BlockSpec((4, e, c), lambda i, f: (0, 0, f))
+                  if leaves_minor
+                  else pl.BlockSpec((4, c, e), lambda i, f: (0, f, 0)))
     out = pl.pallas_call(
         kernel,
         name="dpf_subtree_contract",
@@ -433,7 +468,7 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
                          lambda i, f: (i, f, 0, 0)),
             pl.BlockSpec((4, tb, n_slots), lambda i, f: (0, i, 0)),
             pl.BlockSpec((4, tb, n_slots), lambda i, f: (0, i, 0)),
-            pl.BlockSpec((4, c, e), lambda i, f: (0, f, 0)),
+            table_spec,
         ],
         out_specs=pl.BlockSpec((tb, e), lambda i, f: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, e), jnp.int32),
@@ -448,13 +483,15 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
 def _subtree_contract_pallas_impl(frontier, cw1, cw2, table_perm, *,
                                   depth: int, f_levels: int,
                                   interpret=False, tb: int | None = None,
-                                  prf_method: int = 2):
+                                  prf_method: int = 2,
+                                  leaves_minor: bool = False):
     """Fused phase-2: expand every frontier subtree in VMEM and contract.
 
     frontier:   [B, F, 4] u32 — phase-1 output seeds (subtree f of key b).
     cw1, cw2:   [B, 64, 4] u32 — full codeword arrays (wire layout).
     table_perm: [N, E] int32 — bit-reverse-permuted table, N = F * C —
-    or its ``subtree_digits``.
+    or its ``subtree_digits``; with ``leaves_minor``, the digits held
+    [4, E, N] (``table_digits_t`` of the rows in the kernel's order).
     prf_method: 2 = ChaCha20-12, 1 = Salsa20-12 (for AES see
     ``subtree_contract_pallas_aes``).
     Returns [B, E] int32 shares: sum_f leaves(f) . chunk(f).
@@ -466,18 +503,19 @@ def _subtree_contract_pallas_impl(frontier, cw1, cw2, table_perm, *,
            for k in range(levels) for b in (0, 1)]
     return _subtree_contract_run(
         frontier, cw1, cw2, table_perm, idx=idx, sched=(2,) * levels,
-        prf_method=prf_method, interpret=interpret, tb=tb or PALLAS_TB)
+        prf_method=prf_method, interpret=interpret, tb=tb or PALLAS_TB,
+        leaves_minor=leaves_minor)
 
 
 _subtree_contract_pallas_jit = functools.partial(jax.jit, static_argnames=(
-    "depth", "f_levels", "interpret", "tb", "prf_method"))(
+    "depth", "f_levels", "interpret", "tb", "prf_method", "leaves_minor"))(
         _subtree_contract_pallas_impl)
 
 
 def subtree_contract_pallas(frontier, cw1, cw2, table_perm, *,
                             depth: int, f_levels: int,
                             interpret=False, tb: int | None = None,
-                            prf_method: int = 2):
+                            prf_method: int = 2, leaves_minor: bool = False):
     """Jit-wrapped fused subtree kernel; ``interpret=True`` runs EAGERLY
     (see ``chacha_level_step_pallas`` — interpret-under-jit compile
     blows up super-linearly on XLA-CPU)."""
@@ -485,7 +523,7 @@ def subtree_contract_pallas(frontier, cw1, cw2, table_perm, *,
           else _subtree_contract_pallas_jit)
     return fn(frontier, cw1, cw2, table_perm, depth=depth,
               f_levels=f_levels, interpret=interpret, tb=tb,
-              prf_method=prf_method)
+              prf_method=prf_method, leaves_minor=leaves_minor)
 
 
 def _subtree_contract_pallas_mixed_impl(frontier, cw1, cw2, table_perm, *,

@@ -8,9 +8,12 @@ workload onto a ``jax.sharding.Mesh``:
   row-sharded; each chip owns a contiguous range of BFS leaf positions,
   i.e. a set of whole GGM frontier subtrees.  Every chip replicates the
   cheap phase-1 expansion (root -> frontier, O(B*F)), expands only its own
-  subtrees, contracts against its local table rows, and the partial int32
-  outputs are summed with ``psum`` over ICI.  Valid because additive secret
-  shares commute with partial dot products.
+  subtrees, contracts against its local table rows
+  (``core.expand.eval_leaf_range``: the Pallas subtree kernel or the xla
+  scan, by the one-chip kernel rule), and the partial int32 outputs are
+  summed with ``psum`` over ICI.  Valid because additive secret shares
+  commute with partial dot products.  ``place_table`` lays each chip's
+  rows out from the host table directly, in the layout its kernel reads.
 * **"batch" axis (the DP analogue)** — independent DPF keys are embarrassingly
   parallel; the key batch is sharded and outputs concatenated.
 
@@ -32,23 +35,21 @@ the ICI-adjacent dimension so psum rides ICI, not DCN.
 
 from __future__ import annotations
 
+import collections
 import functools
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from ..core import expand, u128
-
-
-def _pvary(x, axes):
-    """Type a shard_map scan carry as varying over the mesh axes.  Empty
-    ``axes`` (a caller outside any shard_map, e.g. the cluster tier's
-    host-local leaf-range eval) is identity: a cast over axis names
-    that don't exist would raise."""
-    return jax.lax.pcast(x, tuple(axes), to="varying") if axes else x
+from ..core.expand import _pvary, _scan_psum_groups, _valid_psum_group
+from ..obs.tracer import annotate, span
+from ..ops.pallas_level import table_digits, table_digits_t
 
 
 def make_mesh(n_table: int | None = None, n_batch: int = 1,
@@ -63,91 +64,167 @@ def make_mesh(n_table: int | None = None, n_batch: int = 1,
     return Mesh(devices.reshape(n_batch, n_table), ("batch", "table"))
 
 
-def shard_table(table_i32: np.ndarray, mesh: Mesh):
-    """Permute (bit-reversal) and row-shard a table over the "table" axis."""
-    perm = expand.permute_table(np.asarray(table_i32, dtype=np.int32))
-    sharding = NamedSharding(mesh, P("table", None))
-    # from host memory, so each device receives its own shard only (a jnp
-    # array here would first stage the whole table on device 0)
-    return jax.device_put(perm, sharding)
+#: int32 bytes of table rows gathered on the host and sent to the chips
+#: at a time by ``place_table``, by how many threads, and how many such
+#: blocks may be gathered ahead of the chips
+PLACE_BLOCK_BYTES = 256 << 20
+PLACE_THREADS = 8
+PLACE_AHEAD = 8
 
 
-def _valid_psum_group(psum_group, n_chunks: int) -> int:
-    """The effective chunk-group size for grouped psums: 0 (one terminal
-    psum) unless ``psum_group`` divides the chunk count with at least
-    two groups — a tuned value from another shape degrades to the
-    terminal psum rather than failing the program."""
-    g = int(psum_group or 0)
-    return g if 0 < g < n_chunks and n_chunks % g == 0 else 0
+@functools.partial(jax.jit, static_argnames=("digits",),
+                   donate_argnums=(0,))
+def _write_rows(buf, rows, row0, digits: bool):
+    """``buf`` with ``rows`` written from row ``row0`` on, in place
+    (``buf`` is donated): [rows, E] int32 into an [N, E] buffer, or with
+    ``digits`` their [4, E, rows] int8 planes into a [4, E, N] one."""
+    if digits:
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, table_digits_t(rows), row0, axis=2)
+    return jax.lax.dynamic_update_slice_in_dim(buf, rows, row0, axis=0)
 
 
-def _scan_psum_groups(body, zeros, xs, axis_name: str,
-                      outer_axes=("batch",)):
-    """Grouped-psum driver shared by the three sharded constructions.
+def _zeros_on(shape, dtype, device):
+    """A zero buffer made on ``device`` itself: ``jnp.zeros(device=d)``
+    fills it on the default device and copies it over, which on a
+    four-chip host put three table-sized buffers on chip 0 at once."""
+    return jax.jit(functools.partial(jnp.zeros, shape, dtype),
+                   out_shardings=SingleDeviceSharding(device))()
 
-    Scans ``xs`` (every leaf already reshaped to ``[n_groups, g, ...]``)
-    one chunk-group at a time: each group accumulates locally through
-    ``body`` (a standard per-chunk scan body), the group partial is
-    psummed over ``axis_name``, and the psum result adds onto the outer
-    carry — int32 wrap keeps any grouping exact, and the collective has
-    no data dependency on the NEXT group's PRF expansion, so an async
-    backend overlaps ICI latency with compute.
 
-    Carry typing: the INNER partial is varying over ``outer_axes`` plus
-    ``axis_name`` (its body adds shard-local dot products), but the
-    OUTER carry holds only psum outputs — invariant along ``axis_name``
-    — so it is typed varying over ``outer_axes`` alone.  Typing it over
-    the reduced axis too would trip shard_map's out_specs invariance
-    check.  The 2D row x
-    entry-byte path passes ``outer_axes=("batch", "byte")``: its psum
-    runs over "table" only, so the carry still varies over the byte
-    axis (each byte shard holds a different entry block)."""
-    def gbody(acc, xs_g):
-        part0 = _pvary(zeros, tuple(outer_axes) + (axis_name,))
-        part, _ = jax.lax.scan(body, part0, xs_g)
-        return acc + jax.lax.psum(part, axis_name), None
+def place_table(table, mesh: Mesh, chunk: int | None = None):
+    """Lay a host table out over the mesh one chip's block at a time,
+    with no permuted copy of the whole table on the host.
 
-    acc, _ = jax.lax.scan(gbody, _pvary(zeros, tuple(outer_axes)), xs)
-    return acc
+    ``chunk=None``: the xla scan's table, [N, E] int32 rows in BFS
+    (bit-reversed) order.  ``chunk=C``: the subtree kernel's, [4, E, N]
+    int8 digit planes (``table_digits_t``) with each subtree of C leaves
+    in the kernel's leaf order: ``ops.pallas_level.subtree_digits`` of
+    the permuted table, its last two axes swapped.
+    Rows shard over "table", entry columns over "byte" where the mesh
+    has that axis, and every "batch" replica gets its own copy.
+
+    With F = N / C subtrees, BFS position g*C + r holds original row
+    bitrev(r)*F + bitrev(g), and the kernel's leaf q of subtree g row
+    q*F + bitrev(g): a chip's block is a strided gather of the original
+    rows.  It is gathered in blocks of about ``PLACE_BLOCK_BYTES`` by a
+    few threads, at most ``PLACE_AHEAD`` blocks ahead, each written into
+    a buffer allocated on its chips once: the host holds the table and a
+    few blocks, a chip its block and one int32 block in flight, never an
+    int32 copy next to the digits."""
+    tbl = np.asarray(table, dtype=np.int32)
+    n, e = tbl.shape
+    if e % dict(mesh.shape).get("byte", 1):
+        raise ValueError("entry columns (%d) must divide over %d byte "
+                         "shards" % (e, mesh.shape["byte"]))
+    digits = chunk is not None
+    # any power of two within a shard gives the same BFS order
+    c = chunk if digits else min(n // mesh.shape["table"], 1 << 12)
+    f = n // c
+    rev_f = u128.bit_reverse_indices(f)
+    leaf = np.arange(c) if digits else u128.bit_reverse_indices(c)
+    cols = "byte" if "byte" in mesh.axis_names else None
+    shape, spec = ((4, e, n), P(None, cols, "table")) if digits else \
+        ((n, e), P("table", cols))
+    sharding = NamedSharding(mesh, spec)
+    blocks = {}   # (row range, column range) -> the chips that hold it
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        r, k = (idx[2], idx[1]) if digits else (idx[0], idx[1])
+        r, k = r.indices(n), k.indices(e)
+        blocks.setdefault((r[:2], k[:2]), []).append(dev)
+    per_block = max(1, PLACE_BLOCK_BYTES // (4 * e * c))  # subtrees
+    bufs, tasks = {}, []
+    for ((r0, r1), (c0, c1)), devs in blocks.items():
+        for d in devs:
+            bufs[d] = _zeros_on(
+                (4, c1 - c0, r1 - r0) if digits else (r1 - r0, c1 - c0),
+                jnp.int8 if digits else jnp.int32, d)
+        tasks += [(devs, r0, c0, c1, g0, min(g0 + per_block, r1 // c))
+                  for g0 in range(r0 // c, r1 // c, per_block)]
+
+    def gather(task):
+        _, _, c0, c1, g0, g1 = task
+        idx = (leaf[None, :] * f + rev_f[g0:g1, None]).reshape(-1)
+        rows = np.take(tbl, idx, axis=0)   # releases the GIL, unlike [idx]
+        return rows if (c0, c1) == (0, e) else rows[:, c0:c1]
+
+    with ThreadPoolExecutor(PLACE_THREADS) as ex:
+        ahead = collections.deque(
+            (t, ex.submit(gather, t)) for t in tasks[:PLACE_AHEAD])
+        later = iter(tasks[PLACE_AHEAD:])
+        while ahead:
+            (devs, r0, _, _, g0, _), rows = ahead.popleft()
+            rows = rows.result()
+            nxt = next(later, None)
+            if nxt is not None:
+                ahead.append((nxt, ex.submit(gather, nxt)))
+            for d in devs:
+                bufs[d] = _write_rows(bufs[d], jax.device_put(rows, d),
+                                      g0 * c - r0, digits=digits)
+            # the block's host rows are free once its chips hold them
+            jax.block_until_ready([bufs[d] for d in devs])
+    return jax.make_array_from_single_device_arrays(
+        shape, sharding, [bufs[d] for d in bufs])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("depth", "prf_method", "chunk_leaves",
-                                    "mesh", "aes_impl", "psum_group"))
+                                    "mesh", "aes_impl", "psum_group",
+                                    "kernel_impl"))
 def eval_sharded(cw1, cw2, last, table_perm, *, depth: int, prf_method: int,
                  chunk_leaves: int, mesh: Mesh, aes_impl: str | None = None,
-                 psum_group: int = 0):
+                 psum_group: int = 0, kernel_impl: str = "xla"):
     """Mesh-parallel fused DPF evaluation.
 
-    Inputs as in ``expand.expand_and_contract``; ``table_perm`` must be
-    row-sharded with ``shard_table``.  ``psum_group`` > 0 accumulates
-    the share psum per group of that many frontier-subtree chunks
-    instead of once at the end — each group's collective has no data
-    dependency on the next group's PRF expansion, so an async backend
-    overlaps ICI latency with compute (int32 adds wrap: grouping cannot
-    change the result).  Returns [B, E] int32 shares, replicated over
-    the "table" axis and sharded over "batch".
+    Inputs as in ``expand.expand_and_contract``; ``table_perm`` is laid
+    out by ``place_table``: int32 BFS rows for ``kernel_impl="xla"``,
+    the subtree kernel's digit planes (``chunk=chunk_leaves``) for
+    ``"pallas"``.  Each chip runs ``expand.eval_leaf_range`` over its
+    own subtrees, and the partials meet in one ``psum`` (``dpf.psum``).
+    ``psum_group`` > 0 (xla scan only) psums per group of that many
+    subtree chunks instead, so an async backend overlaps ICI latency
+    with the next group's PRF expansion (int32 adds wrap: grouping
+    cannot change the result).  Returns [B, E] int32 shares, replicated
+    over the "table" axis and sharded over "batch".
     """
+    return _mesh_eval(cw1, cw2, last, table_perm, depth=depth,
+                      prf_method=prf_method, chunk_leaves=chunk_leaves,
+                      mesh=mesh, aes_impl=aes_impl, psum_group=psum_group,
+                      kernel_impl=kernel_impl, cols=None)
+
+
+def _mesh_eval(cw1, cw2, last, table_perm, *, depth, prf_method,
+               chunk_leaves, mesh, aes_impl, psum_group, kernel_impl, cols):
+    """The binary mesh program: rows over "table", entry columns over
+    ``cols`` (None, or "byte" for the 2D layout)."""
     n_shards = mesh.shape["table"]
-    n = table_perm.shape[0]
+    n = table_perm.shape[-1 if kernel_impl == "pallas" else 0]
     shard_rows = n // n_shards
     assert shard_rows * n_shards == n
 
     def per_shard(cw1, cw2, last, tbl_shard):
-        # tbl_shard: [n/shards, E] — this chip's BFS leaf range
+        # this chip's BFS leaf range: [rows, E] int32 or [4, E, rows] digits
         shard_ix = jax.lax.axis_index("table")
-        out, psummed = _eval_leaf_range(
+        out, psummed = expand.eval_leaf_range(
             cw1, cw2, last, tbl_shard, shard_ix * shard_rows,
             depth=depth, prf_method=prf_method,
-            chunk_leaves=min(chunk_leaves, shard_rows),
-            n_total=n, aes_impl=aes_impl, psum_group=psum_group,
-            axis_name="table")
-        return out if psummed else jax.lax.psum(out, "table")
+            chunk_leaves=min(chunk_leaves, shard_rows), n_total=n,
+            kernel_impl=kernel_impl, aes_impl=aes_impl,
+            psum_group=psum_group, axis_name="table",
+            carry_axes=("batch", "table") + ((cols,) if cols else ()))
+        if psummed:
+            return out
+        with jax.named_scope("dpf.psum"):
+            return jax.lax.psum(out, "table")
 
     fn = jax.shard_map(
         per_shard, mesh=mesh,
-        in_specs=(P("batch"), P("batch"), P("batch"), P("table", None)),
-        out_specs=P("batch", None))
+        in_specs=(P("batch"), P("batch"), P("batch"),
+                  P(None, cols, "table") if kernel_impl == "pallas"
+                  else P("table", cols)),
+        out_specs=P("batch", cols),
+        # a pallas_call's out_shape carries no mesh-axis typing
+        check_vma=kernel_impl != "pallas")
     return fn(cw1, cw2, last, table_perm)
 
 
@@ -169,36 +246,22 @@ def make_mesh_2d(n_table: int | None = None, n_byte: int = 1,
                 ("batch", "table", "byte"))
 
 
-def shard_table_2d(table_i32: np.ndarray, mesh: Mesh):
-    """Permute (bit-reversal) and block-shard a table over the
-    ("table", "byte") plane: each chip holds one ``[rows/n_table,
-    E/n_byte]`` block — contiguous BFS leaf rows x a contiguous slice
-    of entry columns (int32 words; "byte axis" names the role, the
-    unit is the table's column dtype).  This is what lets a table
-    larger than ONE chip's HBM spread over the whole grid: per-chip
-    bytes shrink by n_table x n_byte."""
-    perm = expand.permute_table(np.asarray(table_i32, dtype=np.int32))
-    if perm.shape[1] % mesh.shape["byte"]:
-        raise ValueError(
-            "entry columns (%d) must divide over %d byte shards"
-            % (perm.shape[1], mesh.shape["byte"]))
-    sharding = NamedSharding(mesh, P("table", "byte"))
-    return jax.device_put(perm, sharding)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("depth", "prf_method", "chunk_leaves",
-                                    "mesh", "aes_impl", "psum_group"))
+                                    "mesh", "aes_impl", "psum_group",
+                                    "kernel_impl"))
 def eval_sharded_2d(cw1, cw2, last, table_perm, *, depth: int,
                     prf_method: int, chunk_leaves: int, mesh: Mesh,
-                    aes_impl: str | None = None, psum_group: int = 0):
+                    aes_impl: str | None = None, psum_group: int = 0,
+                    kernel_impl: str = "xla"):
     """Mesh-parallel fused DPF evaluation over a 2D row x entry-byte
-    table layout (``shard_table_2d``).
+    table layout (``place_table`` on a ``make_mesh_2d`` mesh).
 
     Each chip expands only its row shard's GGM subtrees (the PRF work
     is replicated along the "byte" axis — byte shards of the same row
     range need the same leaf bits) and contracts them against its
-    ``[rows_shard, e_shard]`` block.  Partials combine in a two-phase
+    ``[rows_shard, e_shard]`` block (``expand.eval_leaf_range``, either
+    kernel).  Partials combine in a two-phase
     reduction: (1) psum over "table" — blocks in one byte column cover
     disjoint row ranges of the SAME entry columns, and additive int32
     shares commute with partial dot products, so the sum is exact; with
@@ -212,96 +275,10 @@ def eval_sharded_2d(cw1, cw2, last, table_perm, *, depth: int,
     result is simply sharded over "byte" on the entry axis (and
     replicated over "table"), and a consumer that needs it replicated
     pays the gather on materialization."""
-    n_shards = mesh.shape["table"]
-    n = table_perm.shape[0]
-    shard_rows = n // n_shards
-    assert shard_rows * n_shards == n
-
-    def per_shard(cw1, cw2, last, tbl_block):
-        # tbl_block: [n/n_table, E/n_byte] — this chip's 2D block
-        shard_ix = jax.lax.axis_index("table")
-        out, psummed = _eval_leaf_range(
-            cw1, cw2, last, tbl_block, shard_ix * shard_rows,
-            depth=depth, prf_method=prf_method,
-            chunk_leaves=min(chunk_leaves, shard_rows),
-            n_total=n, aes_impl=aes_impl, psum_group=psum_group,
-            axis_name="table", carry_axes=("batch", "table", "byte"))
-        if not psummed:
-            out = jax.lax.psum(out, "table")
-        return out
-
-    fn = jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P("batch"), P("batch"), P("batch"), P("table", "byte")),
-        out_specs=P("batch", "byte"))
-    return fn(cw1, cw2, last, table_perm)
-
-
-def _eval_leaf_range(cw1, cw2, last, tbl, row0, *, depth: int,
-                     prf_method: int, chunk_leaves: int, n_total: int,
-                     aes_impl: str | None = None, psum_group: int = 0,
-                     axis_name: str | None = None,
-                     carry_axes=("batch", "table")):
-    """Expand only BFS leaves [row0, row0 + tbl.rows) and contract locally.
-
-    Phase 1 walks root -> this shard's frontier; because the shard is a
-    contiguous BFS range, its frontier nodes are a contiguous range at the
-    frontier level, reachable by expanding all of phase 1 (cheap: width F)
-    and slicing the local window with a dynamic slice on the node axis.
-
-    Returns ``(out, psummed)``: with a valid ``psum_group`` (and an
-    ``axis_name`` to reduce over) the scan psums every chunk group and
-    ``out`` is already the mesh-wide sum (``psummed=True``); otherwise
-    ``out`` is this shard's local partial and the caller applies the
-    terminal psum.
-
-    ``carry_axes`` types the scan carry for shard_map callers; pass
-    ``()`` when calling OUTSIDE a mesh program (the multi-host cluster
-    tier evaluates granules host-locally through exactly this path).
-    """
-    rows = tbl.shape[0]
-    e = tbl.shape[1]
-    bsz = last.shape[0]
-    c = chunk_leaves
-    f_local = rows // c                      # frontier nodes owned locally
-    f_total = n_total // c                   # global frontier width
-    f_levels = int(np.log2(f_total))
-
-    seeds = expand.expand_levels(last[:, None, :], cw1, cw2, depth - 1,
-                                 f_levels, prf_method, aes_impl)
-    # take the local frontier window [row0/c, row0/c + f_local)
-    node0 = row0 // c
-    seeds = jax.lax.dynamic_slice_in_dim(seeds, node0, f_local, axis=1)
-
-    def expand_subtree(node_seeds):
-        s = expand.expand_levels(node_seeds[:, None, :], cw1, cw2,
-                                 depth - 1 - f_levels, depth - f_levels,
-                                 prf_method, aes_impl)
-        return s[..., 0].astype(jnp.int32)
-
-    tbl_chunks = tbl.reshape(f_local, c, e)
-    if f_local == 1:
-        return (expand._dot_i32(expand_subtree(seeds[:, 0, :]),
-                                tbl_chunks[0]), False)
-
-    frontier = jnp.moveaxis(seeds, 1, 0)  # [f_local, B, 4]
-
-    def body(acc, xs):
-        node_seeds, chunk = xs
-        return acc + expand._dot_i32(expand_subtree(node_seeds), chunk), None
-
-    zeros = jnp.zeros((bsz, e), dtype=jnp.int32)
-    g = _valid_psum_group(psum_group, f_local) if axis_name else 0
-    if not g:
-        # inside shard_map the scan carry must be typed as varying over
-        # the mesh axes (the body's output is), or the carry mismatches
-        acc, _ = jax.lax.scan(body, _pvary(zeros, carry_axes),
-                              (frontier, tbl_chunks))
-        return acc, False
-    return _scan_psum_groups(body, zeros, (
-        frontier.reshape(f_local // g, g, bsz, 4),
-        tbl_chunks.reshape(f_local // g, g, c, e)), axis_name,
-        outer_axes=tuple(a for a in carry_axes if a != axis_name)), True
+    return _mesh_eval(cw1, cw2, last, table_perm, depth=depth,
+                      prf_method=prf_method, chunk_leaves=chunk_leaves,
+                      mesh=mesh, aes_impl=aes_impl, psum_group=psum_group,
+                      kernel_impl=kernel_impl, cols="byte")
 
 
 @functools.partial(jax.jit,
@@ -311,7 +288,7 @@ def eval_leaf_range_local(cw1, cw2, last, tbl, row0, *, depth: int,
                           prf_method: int, chunk_leaves: int, n_total: int,
                           aes_impl: str | None = None):
     """Host-local partial evaluation of one contiguous BFS leaf range —
-    the single-device (no-mesh) entry to ``_eval_leaf_range``.
+    the single-device (no-mesh) entry to ``expand.eval_leaf_range``.
 
     This is the multi-host cluster tier's per-host primitive
     (``parallel/cluster.py``): a host owning table rows
@@ -326,11 +303,10 @@ def eval_leaf_range_local(cw1, cw2, last, tbl, row0, *, depth: int,
     program per (rows, batch) shape serves ANY granule — a re-shard
     after a host drop moves granules between hosts without recompiling.
     """
-    out, _ = _eval_leaf_range(
+    out, _ = expand.eval_leaf_range(
         cw1, cw2, last, tbl, jnp.asarray(row0, dtype=jnp.int32),
         depth=depth, prf_method=prf_method, chunk_leaves=chunk_leaves,
-        n_total=n_total, aes_impl=aes_impl, psum_group=0, axis_name=None,
-        carry_axes=())
+        n_total=n_total, aes_impl=aes_impl, carry_axes=())
     return out
 
 
@@ -444,7 +420,15 @@ class ShardedDPFServer:
     --multichip``), then the single-device tuned entry, then the static
     per-shard heuristic (chunk choices clamp against the SHARD row
     count, not the full table — a tuned single-device chunk must not
-    exceed a shard's leaf range).
+    exceed a shard's leaf range).  The GGM kernel follows the one-chip
+    rule (``ops.pallas_level.heuristic_kernel``): on a TPU, binary
+    Salsa20/ChaCha20 runs the subtree kernel on every shard.
+
+    Binary GGM places at construction the table the constructor's
+    ``batch_size`` resolves (``place_table``): on the subtree kernel's
+    path only its int8 digit planes, never an int32 copy.  The host
+    table (the caller's array) stays referenced, for a dispatch that
+    resolves the other kernel's layout.
     """
 
     def __init__(self, table, mesh: Mesh | None = None, prf_method: int = 3,
@@ -487,23 +471,43 @@ class ShardedDPFServer:
                 "byte-axis (2D) sharding serves the binary GGM "
                 "construction only (scheme=%r radix=%d)"
                 % (self.scheme, self.radix))
-        if self.scheme == "sqrtn":
-            self.table_sharded = shard_table_sqrt(tbl, self.mesh)
-        elif self.radix == 4:
-            self.table_sharded = shard_table_mixed(tbl, self.mesh)
-        elif self.n_byte > 1:
-            self.table_sharded = shard_table_2d(tbl, self.mesh)
-        else:
-            self.table_sharded = shard_table(tbl, self.mesh)
         # the explicit knob layer: ctor args (None = auto); assigning
         # these attributes afterwards pins the knob the same way
         self.chunk = chunk_leaves
         self.row_chunk = row_chunk
         self.psum_group = psum_group
         self.dot_impl = dot_impl
-        self.kernel_impl = kernel_impl  # sqrtn: "xla" | "pallas" | None
+        self.kernel_impl = kernel_impl  # "xla" | "pallas" | None
         self._tuned_memo = {}  # batch -> (mesh-tuned, single-tuned) dicts
         self._digits = None    # sqrt-N grid kernel's table, built once
+        self._layouts = {}     # binary GGM: subtree chunk or None -> table
+        if self.scheme == "sqrtn":
+            self.table_sharded = shard_table_sqrt(tbl, self.mesh)
+        elif self.radix == 4:
+            self.table_sharded = shard_table_mixed(tbl, self.mesh)
+        else:
+            # binary GGM: the layout the constructor's batch resolves is
+            # placed now; the host rows (the caller's array, not a copy)
+            # stay referenced for a dispatch that resolves the other
+            # kernel's layout
+            self._host_table = tbl
+            self.table_sharded = self._layout(
+                self.resolved_eval_knobs(self._mesh_batch(batch_size)))
+
+    def _mesh_batch(self, batch: int) -> int:
+        """A dispatch's batch padded to the mesh "batch" axis."""
+        return batch + (-batch) % max(self.mesh.shape["batch"], 1)
+
+    def _layout(self, kn: dict):
+        """The binary-GGM table as the resolved kernel reads it
+        (``place_table``): int32 BFS rows for the xla scan, the subtree
+        kernel's digit planes for its chunk.  Placed once per layout;
+        on the subtree kernel's path no int32 table is placed at all."""
+        key = kn["chunk_leaves"] if kn["kernel_impl"] == "pallas" else None
+        if key not in self._layouts:
+            self._layouts[key] = place_table(self._host_table, self.mesh,
+                                             chunk=key)
+        return self._layouts[key]
 
     def _resolve_auto_scheme(self, batch_size: int, prf_method: int):
         """scheme="auto" -> the concrete construction, the DPF way:
@@ -564,7 +568,8 @@ class ShardedDPFServer:
                     "kernel_impl": self.kernel_impl}
         fields = (("row_chunk", "psum_group", "dot_impl", "kernel_impl")
                   if self.scheme == "sqrtn"
-                  else ("chunk_leaves", "psum_group", "dot_impl"))
+                  else ("chunk_leaves", "psum_group", "dot_impl",
+                        "kernel_impl"))
         if all(explicit[f] is not None for f in fields):
             # fully pinned (the mesh tuner measuring a candidate): no
             # cache reads — a stale entry must not leak into the knobs
@@ -601,25 +606,11 @@ class ShardedDPFServer:
             # kernel_impl with provenance, the DPF rule: explicit >
             # tuned > "xla"; a resolved "pallas" without Pallas/TPU
             # here degrades to the xla scan instead of raising
-            if explicit["kernel_impl"] is not None:
-                kernel, kernel_from = explicit["kernel_impl"], "config"
-            elif tuned.get("kernel_impl",
-                           single.get("kernel_impl")) is not None:
-                kernel = tuned.get("kernel_impl",
-                                   single.get("kernel_impl"))
-                kernel_from = "tuned"
-            else:
-                kernel, kernel_from = "xla", "heuristic"
+            kernel, kernel_from = self._pick_kernel(explicit, tuned,
+                                                    single, "xla")
             if kernel == "pallas":
-                from ..utils.compat import has_pallas_sqrt_kernel
-                if not has_pallas_sqrt_kernel():
-                    from ..utils.profiling import note_swallowed
-                    note_swallowed(
-                        "sharded.sqrt_kernel_unavailable",
-                        RuntimeError(
-                            "kernel_impl='pallas' (from %s) but Pallas/"
-                            "TPU is unavailable here" % kernel_from))
-                    kernel, kernel_from = "xla", "degraded"
+                kernel, kernel_from = self._degrade(kernel, kernel_from,
+                                                    "sqrt_kernel")
             if (out["row_chunk"] is not None
                     and explicit["row_chunk"] is None
                     and (tuned.get("kernel_impl",
@@ -630,17 +621,64 @@ class ShardedDPFServer:
             out["kernel_impl"] = kernel
             out["kernel_resolved_from"] = kernel_from
             return out
+        # GGM: the kernel by the single-chip rule (explicit > mesh-tuned
+        # > tuned > ``heuristic_kernel``); a "pallas" from a cache
+        # written where the subtree kernel compiles degrades to the scan
+        # here, an explicit one passes through
+        from ..ops.pallas_level import heuristic_kernel, pallas_chunk_leaves
+        kernel, kernel_from = self._pick_kernel(
+            explicit, tuned, single,
+            heuristic_kernel(self.prf_method, self.radix))
+        if kernel == "pallas" and kernel_from == "tuned":
+            kernel, kernel_from = self._degrade(kernel, kernel_from,
+                                                "ggm_kernel")
+        out["kernel_impl"] = kernel
+        out["kernel_resolved_from"] = kernel_from
+        tuned_chunk = tuned.get("chunk_leaves", single.get("chunk_leaves"))
+        # a tuned chunk rides only with the kernel it was timed on (an
+        # entry naming none was timed on the xla scan)
+        if (tuned.get("kernel_impl", single.get("kernel_impl"))
+                or "xla") != kernel:
+            tuned_chunk = None
         if explicit["chunk_leaves"] is not None:
             out["chunk_leaves"] = min(int(explicit["chunk_leaves"]),
                                       self.shard_rows)
+        elif kernel == "pallas":
+            # bounded by the kernel's per-tile VMEM state, within a shard
+            out["chunk_leaves"] = min(
+                int(tuned_chunk or pallas_chunk_leaves(self.shard_rows)),
+                self.shard_rows)
         else:
             # clamp against the shard's own leaf range: tuned entries
             # (mesh or single-device) key on the table shape, and a
             # single-device chunk can exceed what one shard holds
             out["chunk_leaves"] = expand.clamp_chunk(
-                tuned.get("chunk_leaves", single.get("chunk_leaves")),
-                self.shard_rows, batch)
+                tuned_chunk, self.shard_rows, batch)
         return out
+
+    @staticmethod
+    def _pick_kernel(explicit, tuned, single, heuristic: str):
+        """(kernel, provenance): explicit > mesh-tuned > single-device
+        tuned > ``heuristic``."""
+        if explicit["kernel_impl"] is not None:
+            return explicit["kernel_impl"], "config"
+        kernel = tuned.get("kernel_impl", single.get("kernel_impl"))
+        if kernel is not None:
+            return kernel, "tuned"
+        return heuristic, "heuristic"
+
+    @staticmethod
+    def _degrade(kernel: str, kernel_from: str, what: str):
+        """A resolved "pallas" where Pallas/TPU is unavailable: the xla
+        scan, with provenance "degraded" (counted by note_swallowed)."""
+        from ..utils.compat import has_pallas_sqrt_kernel
+        if has_pallas_sqrt_kernel():
+            return kernel, kernel_from
+        from ..utils.profiling import note_swallowed
+        note_swallowed("sharded.%s_unavailable" % what, RuntimeError(
+            "kernel_impl='pallas' (from %s) but Pallas/TPU is "
+            "unavailable here" % kernel_from))
+        return "xla", "degraded"
 
     def _dispatch_packed(self, pk):
         """Pad to the mesh "batch" axis and dispatch WITHOUT a host sync
@@ -648,8 +686,7 @@ class ShardedDPFServer:
         returned device array may carry pad rows — callers trim to the
         real batch."""
         from ..core import prf as _prf
-        pk = pk.pad_to(pk.batch
-                       + (-pk.batch) % max(self.mesh.shape["batch"], 1))
+        pk = pk.pad_to(self._mesh_batch(pk.batch))
         kn = self.resolved_eval_knobs(pk.batch)
         if self.scheme == "sqrtn":
             from ..core import sqrtn
@@ -679,10 +716,10 @@ class ShardedDPFServer:
                     note_swallowed("sharded.sqrt_kernel_unsupported",
                                    ValueError(reason))
                     kernel = "xla"
+            annotate(kernel=kernel)
             table = self.table_sharded
             if kernel == "pallas":
                 if self._digits is None:  # [4, N, E] int8, rows sharded
-                    from ..ops.pallas_level import table_digits
                     self._digits = table_digits(table)
                 table = self._digits
             return sqrtn.eval_sharded_sqrt(
@@ -690,30 +727,43 @@ class ShardedDPFServer:
                 prf_method=self.prf_method, mesh=self.mesh,
                 dot_impl=kn["dot_impl"], row_chunk=rc,
                 psum_group=kn["psum_group"], kernel_impl=kernel)
+        kernel = kn["kernel_impl"]
+        annotate(kernel=kernel)
         if self.radix == 4:
+            if kernel != "xla":
+                raise ValueError("the radix-4 mesh path runs the xla scan "
+                                 "only (kernel_impl=%r)" % kernel)
             return eval_sharded_mixed(
                 pk.cw1, pk.cw2, pk.last, self.table_sharded, n=self.n,
                 prf_method=self.prf_method,
                 chunk_leaves=kn["chunk_leaves"], mesh=self.mesh,
                 aes_impl=_prf._aes_pair_impl(),
                 psum_group=kn["psum_group"])
-        if self.n_byte > 1:
-            return eval_sharded_2d(
-                pk.cw1, pk.cw2, pk.last, self.table_sharded,
-                depth=self.depth, prf_method=self.prf_method,
-                chunk_leaves=kn["chunk_leaves"], mesh=self.mesh,
-                aes_impl=_prf._aes_pair_impl(),
-                psum_group=kn["psum_group"])
-        return eval_sharded(pk.cw1, pk.cw2, pk.last, self.table_sharded,
-                            depth=self.depth, prf_method=self.prf_method,
-                            chunk_leaves=kn["chunk_leaves"],
-                            mesh=self.mesh,
-                            aes_impl=_prf._aes_pair_impl(),
-                            psum_group=kn["psum_group"])
+        from ..ops.pallas_level import has_subtree_core
+        if kernel not in ("xla", "pallas") or (
+                kernel == "pallas" and not has_subtree_core(self.prf_method)):
+            raise ValueError(
+                "kernel_impl=%r: the binary mesh path runs the xla scan or "
+                "the subtree kernel (Salsa20/ChaCha20 and their block "
+                "forms), not prf %d" % (kernel, self.prf_method))
+        fn = eval_sharded_2d if self.n_byte > 1 else eval_sharded
+        return fn(pk.cw1, pk.cw2, pk.last, self._layout(kn),
+                  depth=self.depth, prf_method=self.prf_method,
+                  chunk_leaves=kn["chunk_leaves"], mesh=self.mesh,
+                  aes_impl=_prf._aes_pair_impl(),
+                  psum_group=kn["psum_group"], kernel_impl=kernel)
 
     def eval(self, keys) -> np.ndarray:
-        pk = self._decode_batch(keys)
-        return np.asarray(self._dispatch_packed(pk))[:pk.batch]
+        """[len(keys), E] int32 shares of one key batch, in the spans
+        ``mesh_eval`` > ``.decode`` / ``.dispatch`` (with the resolved
+        ``kernel``) / ``.fetch`` (the device's wait and the copy back)."""
+        with span("mesh_eval", batch=len(keys)):
+            with span("mesh_eval.decode", batch=len(keys)):
+                pk = self._decode_batch(keys)
+            with span("mesh_eval.dispatch"):
+                dev = self._dispatch_packed(pk)
+            with span("mesh_eval.fetch"):
+                return np.asarray(dev)[:pk.batch]
 
     def serving_engine(self, **kwargs):
         """Mesh-path ``ServingEngine`` (serve/engine.py) over this server."""

@@ -146,7 +146,9 @@ def tune_mesh_eval(n: int, batch: int, *, mesh, entry_size: int = 16,
                 row_chunk=knobs.get("row_chunk"),
                 psum_group=knobs.get("psum_group", 0),
                 dot_impl=knobs.get("dot_impl",
-                                   matmul128.default_impl()))
+                                   matmul128.default_impl()),
+                # the GGM stages are the xla scan's knobs: time that
+                kernel_impl=None if scheme == "sqrtn" else "xla")
             out = srv.eval(keys)  # compile + warm
             if out.shape != oracle.shape or not np.array_equal(out,
                                                                oracle):

@@ -44,7 +44,7 @@ def main():
 
     n, method = 256, 2  # ChaCha
     table = np.arange(n * 2, dtype=np.int32).reshape(n, 2)
-    tdev = sharded.shard_table(table, mesh)
+    tdev = sharded.place_table(table, mesh)
     k0, k1 = keygen.generate_keys(42, n, b"multihost", method)
     cw1, cw2, last = expand.pack_keys([k0, k1])
     out = sharded.eval_sharded(cw1, cw2, last, tdev, depth=8,

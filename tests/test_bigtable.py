@@ -304,7 +304,7 @@ def test_2d_rejects_indivisible_entries_and_wrong_scheme(eight_devices):
     from dpf_tpu.parallel import sharded
     mesh = sharded.make_mesh_2d(n_table=4, n_byte=2)
     with pytest.raises(ValueError):
-        sharded.shard_table_2d(_table(256, 7), mesh)   # 7 % 2 != 0
+        sharded.place_table(_table(256, 7), mesh)   # 7 % 2 != 0
     with pytest.raises(ValueError):
         sharded.ShardedDPFServer(_table(256, 8), mesh,
                                  prf_method=DPF.PRF_DUMMY,

@@ -238,6 +238,35 @@ def test_dispatch_spans_carry_the_resolved_kernel(construction, kernel):
         assert spans[0]["attrs"]["kernel"] == kernel, name
 
 
+def test_mesh_eval_spans_decode_dispatch_fetch():
+    """``ShardedDPFServer.eval`` records ``mesh_eval`` >
+    ``mesh_eval.decode`` / ``.dispatch`` (naming the resolved kernel) /
+    ``.fetch``, as ``DPF.eval_tpu`` does."""
+    import jax
+    from dpf_tpu import DPF
+    from dpf_tpu.parallel import sharded
+    n = 1024
+    dpf = DPF(prf=DPF.PRF_DUMMY)
+    keys = [dpf.gen((i * 31) % n, n)[0] for i in range(3)]
+    srv = sharded.ShardedDPFServer(
+        np.arange(n * 3, dtype=np.int32).reshape(n, 3),
+        sharded.make_mesh(n_table=4, devices=jax.devices()[:4]),
+        prf_method=DPF.PRF_DUMMY, batch_size=3)
+    ref = srv.eval(keys)                  # compile outside the record
+    t = obs_tracer.enable()
+    t.clear()
+    assert np.array_equal(srv.eval(keys), ref)
+    evs = t.events()
+    top = [e for e in evs if e["name"] == "mesh_eval"]
+    assert len(top) == 1 and top[0]["attrs"] == {"batch": 3}
+    for part in ("decode", "dispatch", "fetch"):
+        kids = [e for e in evs if e["name"] == "mesh_eval." + part]
+        assert len(kids) == 1, part
+        assert kids[0]["parent_id"] == top[0]["span_id"]
+    dispatch = [e for e in evs if e["name"] == "mesh_eval.dispatch"][0]
+    assert dispatch["attrs"]["kernel"] == "xla"
+
+
 def test_backpressure_span_only_when_the_window_is_full():
     dpf, keys = _small_dpf()
     engine = dpf.serving_engine(buckets=(4,), max_in_flight=1)
@@ -300,6 +329,27 @@ def test_fused_program_carries_dpf_scopes():
                              S((n, 16), jnp.int32)).compile().as_text()
     assert {"dpf.frontier", "dpf.subtree", "dpf.contract", "dpf.prf",
             "dpf.cw_add"} <= _scopes(text)
+
+
+def test_mesh_program_carries_frontier_subtree_psum_scopes():
+    """The mesh program names its phases on the device trace: the
+    frontier, each shard's subtrees and the cross-chip psum."""
+    import jax
+    import jax.numpy as jnp
+
+    from dpf_tpu.parallel import sharded
+    n, u32 = 1 << 10, jnp.uint32
+    mesh = sharded.make_mesh(n_table=4, devices=jax.devices()[:4])
+
+    def fn(cw1, cw2, last, t):
+        return sharded.eval_sharded(cw1, cw2, last, t, depth=10,
+                                    prf_method=2, chunk_leaves=64,
+                                    mesh=mesh)
+    S = jax.ShapeDtypeStruct
+    text = jax.jit(fn).lower(S((4, 64, 4), u32), S((4, 64, 4), u32),
+                             S((4, 4), u32),
+                             S((n, 16), jnp.int32)).compile().as_text()
+    assert {"dpf.frontier", "dpf.subtree", "dpf.psum"} <= _scopes(text)
 
 
 def test_dispatch_path_level_programs_carry_their_phase():

@@ -355,3 +355,112 @@ def test_sharded_radix4_matches_single_chip(eight_devices, mesh_shape):
     dpf.eval_init(table)
     single = np.asarray(dpf.eval_tpu([k[0] for k in keys]))
     assert (a == single).all()
+
+
+# ------------------------------------- the subtree kernel under the mesh
+
+def _four_chip_mesh():
+    import jax
+    return sharded.make_mesh(n_table=4, devices=jax.devices()[:4])
+
+
+@pytest.mark.parametrize("chunk", [None, 64, 256])
+def test_each_chip_holds_its_slice_of_the_whole_layout(eight_devices, chunk):
+    """``place_table`` builds every chip's block from the host rows: the
+    int32 BFS rows of ``permute_table``, or the subtree kernel's digit
+    planes, each chip's the matching slice of ``subtree_digits`` of the
+    whole permuted table (its last two axes swapped)."""
+    from dpf_tpu.core import expand
+    from dpf_tpu.ops.pallas_level import subtree_digits
+    n, e = 1 << 12, 16
+    table = np.random.default_rng(5).integers(
+        -2 ** 31, 2 ** 31, (n, e), dtype=np.int64).astype(np.int32)
+    perm = expand.permute_table(table)
+    want = perm if chunk is None else np.swapaxes(np.asarray(subtree_digits(
+        perm, n // chunk, (2,) * (chunk.bit_length() - 1))), 1, 2)
+    placed = sharded.place_table(table, _four_chip_mesh(), chunk=chunk)
+    assert placed.shape == want.shape
+    for sh in placed.addressable_shards:
+        assert (np.asarray(sh.data) == want[sh.index]).all()
+
+
+def test_placement_and_eval_copy_nothing_between_chips(eight_devices,
+                                                     monkeypatch):
+    """Each chip's block goes from the host to that chip alone (no
+    table-sized buffer staged on chip 0 for the others), and a call
+    moves nothing from chip to chip but its psum."""
+    import jax
+    from dpf_tpu.utils import compat
+    n = 1 << 12
+    table = np.random.default_rng(6).integers(
+        -2 ** 31, 2 ** 31, (n, 16), dtype=np.int64).astype(np.int32)
+    dpf = DPF(prf=DPF.PRF_DUMMY)
+    keys = [dpf.gen(i, n)[0] for i in (3, 1000, 4000)]
+    dpf.eval_init(table)
+    with jax.transfer_guard_device_to_device("disallow"):
+        srv = sharded.ShardedDPFServer(table, _four_chip_mesh(),
+                                       prf_method=DPF.PRF_DUMMY,
+                                       batch_size=3)
+        assert (srv.eval(keys) == np.asarray(dpf.eval_tpu(keys))).all()
+        monkeypatch.setattr(compat, "has_pallas_sqrt_kernel",
+                            lambda backend=None: True)
+        srv = sharded.ShardedDPFServer(table, _four_chip_mesh(),
+                                       prf_method=DPF.PRF_CHACHA20,
+                                       batch_size=8)
+        assert srv.table_sharded.dtype == np.int8
+
+
+# (case id) -> (server kwargs, probe reports a TPU, planted mesh-tuned
+# entry, kernel, provenance): test_pallas_level's rule, on the mesh
+_MESH_RULE_CASES = {
+    "chacha.tpu": (dict(prf_method=2), True, None, "pallas", "heuristic"),
+    "salsa.tpu": (dict(prf_method=1), True, None, "pallas", "heuristic"),
+    "salsa_blk.tpu": (dict(prf_method=4), True, None, "pallas",
+                      "heuristic"),
+    "chacha_blk.tpu": (dict(prf_method=5), True, None, "pallas",
+                       "heuristic"),
+    "aes.tpu": (dict(prf_method=3), True, None, "xla", "heuristic"),
+    "dummy.tpu": (dict(prf_method=0), True, None, "xla", "heuristic"),
+    "radix4.tpu": (dict(prf_method=2, radix=4), True, None, "xla",
+                   "heuristic"),
+    "config_xla.tpu": (dict(prf_method=2, kernel_impl="xla"), True, None,
+                       "xla", "config"),
+    "tuned_xla.tpu": (dict(prf_method=2), True,
+                      {"kernel_impl": "xla", "chunk_leaves": 256},
+                      "xla", "tuned"),
+    # a tuned chunk naming no kernel was timed on the scan: it never
+    # rides the subtree kernel
+    "tuned_chunk_only.tpu": (dict(prf_method=2), True,
+                             {"chunk_leaves": 256}, "pallas", "heuristic"),
+    "tuned_pallas.cpu": (dict(prf_method=2), False,
+                         {"kernel_impl": "pallas"}, "xla", "degraded"),
+    "chacha.cpu": (dict(prf_method=2), False, None, "xla", "heuristic"),
+    "salsa.cpu": (dict(prf_method=1), False, None, "xla", "heuristic"),
+    "aes.cpu": (dict(prf_method=3), False, None, "xla", "heuristic"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MESH_RULE_CASES))
+def test_mesh_kernel_rule(eight_devices, monkeypatch, case):
+    """``ShardedDPFServer`` resolves the kernel by the one-chip rule
+    (explicit > mesh-tuned > tuned > ``heuristic_kernel``): binary
+    Salsa/ChaCha GGM on the subtree kernel where it compiles, with its
+    chunk clamped to the shard; everything else on the xla scan."""
+    from dpf_tpu.ops.pallas_level import pallas_chunk_leaves
+    from dpf_tpu.utils import compat
+    kwargs, on_tpu, tuned, kernel, prov = _MESH_RULE_CASES[case]
+    if on_tpu:
+        monkeypatch.setattr(compat, "has_pallas_sqrt_kernel",
+                            lambda backend=None: True)
+    n, batch = 1 << 12, 8
+    srv = sharded.ShardedDPFServer(np.zeros((n, 16), np.int32),
+                                   _four_chip_mesh(), batch_size=batch,
+                                   **kwargs)
+    if tuned is not None:
+        srv._tuned_memo[batch] = (dict(tuned), {})
+    kn = srv.resolved_eval_knobs(batch)
+    assert (kn["kernel_impl"], kn["kernel_resolved_from"]) == (kernel, prov)
+    if kernel == "pallas":
+        assert kn["chunk_leaves"] == pallas_chunk_leaves(srv.shard_rows)
+    elif tuned is not None and prov == "tuned":
+        assert kn["chunk_leaves"] == tuned["chunk_leaves"]

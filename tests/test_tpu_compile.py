@@ -251,3 +251,62 @@ def test_resolved_binary_chacha_program_compiles_for_v5e(compile_tpu,
         fn, S((batch, 64, 4), U32), S((batch, 64, 4), U32),
         S((batch, 4), U32), S((4, n, E), jnp.int8)))
     assert len(calls) == 1 and "dpf_subtree_contract" in calls[0]
+
+
+def test_four_chip_subtree_cell_compiles_and_fits(compile_tpu, topo,
+                                                  monkeypatch):
+    """The benchmark's four-chip cell as ``ShardedDPFServer`` resolves it
+    on a TPU: 2^28 x 16 rows over the described v5e:2x2, 64 keys, each
+    chip running the subtree kernel over its 2^26 rows' digit planes,
+    then one all-reduce.  Per chip, the program's arguments, output and
+    temporaries fit 9e9 bytes, and so does one block write of the
+    placement."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dpf_tpu.parallel import sharded
+    from dpf_tpu.utils import compat
+    monkeypatch.setattr(compat, "has_pallas_sqrt_kernel",
+                        lambda backend=None: True)
+    n, b = 1 << 28, 64
+    mesh = sharded.make_mesh(devices=np.asarray(topo.devices))
+    srv = sharded.ShardedDPFServer.__new__(sharded.ShardedDPFServer)
+    srv.mesh, srv.n, srv.entry_size = mesh, n, E
+    srv.scheme, srv.radix, srv.prf_method = "logn", 2, 2
+    srv.chunk = srv.row_chunk = srv.psum_group = srv.dot_impl = None
+    srv.kernel_impl, srv._tuned_memo = None, {}
+    kn = srv.resolved_eval_knobs(b)
+    assert (kn["kernel_impl"], kn["chunk_leaves"]) == ("pallas", 4096)
+
+    def on(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def fn(cw1, cw2, last, t):
+        return sharded.eval_sharded(
+            cw1, cw2, last, t, depth=28, prf_method=2,
+            chunk_leaves=kn["chunk_leaves"], mesh=mesh,
+            psum_group=kn["psum_group"], kernel_impl="pallas")
+    args = (on((b, 64, 4), U32, P("batch")), on((b, 64, 4), U32, P("batch")),
+            on((b, 4), U32, P("batch")),
+            on((4, E, n), jnp.int8, P(None, None, "table")))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and "dpf_subtree_contract" in calls[0]
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert 4 * (n // 4) * E <= per_chip <= 9e9, per_chip
+
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topo.devices[0])
+    rows = sharded.PLACE_BLOCK_BYTES // (4 * E)
+    placed = sharded._write_rows.lower(
+        jax.ShapeDtypeStruct((4, E, n // 4), jnp.int8, sharding=one),
+        jax.ShapeDtypeStruct((rows, E), I32, sharding=one),
+        jax.ShapeDtypeStruct((), I32, sharding=one), digits=True).compile()
+    m = placed.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes) <= 9e9
