@@ -907,11 +907,13 @@ class DPF(object):
                 deadline=self.dispatch_deadline)
         table = self.table_device
         if k["kernel_impl"] == "pallas" and self.prf_method != PRF_AES128:
-            from .ops.pallas_level import subtree_digits
+            from .ops.pallas_level import subtree_digits, tiled_levels
             fl = k.get("f_levels") or depth - chunk.bit_length() + 1
+            sched = (2,) * (depth - fl)
+            annotate(tiled_levels=tiled_levels(sched))
             table = self._kernel_table(
                 ("subtree", fl),
-                lambda t: subtree_digits(t, 1 << fl, (2,) * (depth - fl)))
+                lambda t: subtree_digits(t, 1 << fl, sched))
         return expand.expand_and_contract(
             cw1, cw2, last, table, depth=depth,
             prf_method=self.prf_method, chunk_leaves=chunk,

@@ -322,8 +322,37 @@ def chacha_level_step_pallas(seeds, cw1_lvl, cw2_lvl, interpret=False,
 # Fused subtree expand + contract (the production kernel)
 # ---------------------------------------------------------------------------
 
+# default tile knobs: the levels' limbs live in two [4, TB, C] u32
+# scratch buffers (``_make_subtree_kernel``).
+# On a v5e at N = 2^16, B = 64 and 512 (PERF.md): the binary kernel
+# (ChaCha20) ran 11-29 % faster per key at TB = 8 than at 16, 32 or 64,
+# and slower at C = 2048; the radix-4 kernel (ChaCha20-BLK) ran 3 %
+# faster at TB = 32 than at 8.
+PALLAS_TB = 8         # binary key tile (one sublane group)
+PALLAS_TB_MIXED = 32  # radix-4 key tile (a smaller batch: its size, >= 8)
+PALLAS_MAX_C = 4096   # leaves per subtree -> 1 MiB of scratch at TB = 8
+
+# One register tile of the cipher state: the 16 words of [8, 256] u32
+# planes are 32 of the core's 64 vregs, so a tile's double rounds run
+# in registers.  A whole wide level's [TB, w] planes did not fit: at
+# w >= 1024 the state spilled to VMEM and the stores bound the loop
+# (PERF.md section 5).
+TILE_ROWS, TILE_LANES = 8, 256
+
+
+def tiled_levels(sched: tuple) -> int:
+    """How many of the subtree kernel's levels run tile by tile: those
+    whose parent planes are at least one register tile wide (4 of 12
+    at 4,096 leaves a subtree, binary)."""
+    w, n = 1, 0
+    for a in sched:
+        n += w >= TILE_LANES
+        w *= a
+    return n
+
+
 def _make_subtree_kernel(sched: tuple, prf_method: int = 2,
-                         leaves_minor: bool = False):
+                         leaves_minor: bool = False, tb: int = PALLAS_TB):
     """Kernel over a per-level arity schedule.  ``sched[k]`` is the
     fan-out of kernel level k; the sliced codeword arrays hold the levels'
     slots back to back in the same order (see the wrapper's ``idx``).
@@ -334,36 +363,78 @@ def _make_subtree_kernel(sched: tuple, prf_method: int = 2,
     interleaved: Mosaic refuses the lane-splitting reshape an
     interleave needs.  The leaves thus come out digit-reversed against
     the XLA path's order, and the launcher hands the kernel its table in
-    that order (``_kernel_leaf_order``)."""
+    that order (``_kernel_leaf_order``).
+
+    Levels narrower than a register tile run on the whole [TB, w]
+    planes as values.  The wider ones (``tiled_levels``) loop over
+    tiles of (8 rows, 256 lanes), reading their parents from one of two
+    [4, TB, C] VMEM scratch buffers and writing the children to the
+    other at lane offset ``b*w + j``; the leaf level writes limb 0
+    alone, the one the contraction reads."""
     from jax.experimental import pallas as pl
 
     blk = _BLK_CORES.get(prf_method)
     core = None if blk is not None else _CORES[prf_method]
+    n_tiled = tiled_levels(sched)
+    n_narrow = len(sched) - n_tiled
+    tr = TILE_ROWS if tb % TILE_ROWS == 0 else tb
+    n_row_tiles = tb // tr
 
-    def kernel(seeds_ref, cw1_ref, cw2_ref, table_ref, out_ref):
+    def level(planes, cw1_ref, cw2_ref, rows, off, a):
+        """Yield each child ``b`` of the parents ``planes`` (4 limbs of
+        [R, L]) with its 4 limbs, keys ``rows`` of the codeword refs."""
+        sel = (planes[0] & np.uint32(1)).astype(jnp.bool_)
+        if blk is not None:
+            out16 = blk(planes, np.uint32(0))
+        for b in range(a):
+            if blk is not None:
+                val = [out16[4 * b + 3], out16[4 * b + 2],
+                       out16[4 * b + 1], out16[4 * b]]
+            else:
+                val = core(planes, np.uint32(b))
+            cw = [jnp.where(sel, cw2_ref[i, rows, off + b][:, None],
+                            cw1_ref[i, rows, off + b][:, None])
+                  for i in range(4)]
+            yield b, _add128_planes(val, cw)
+
+    def kernel(seeds_ref, cw1_ref, cw2_ref, table_ref, out_ref, *bufs):
         f = pl.program_id(1)
         x = seeds_ref[...]                            # [TB, 4]
         planes = [x[:, i:i + 1] for i in range(4)]    # [TB, 1]
-        off = 0
-        for a in sched:
-            sel = (planes[0] & np.uint32(1)).astype(jnp.bool_)  # [TB, w]
-            if blk is not None:
-                out16 = blk(planes, np.uint32(0))
-            children = []
-            for b in range(a):
-                if blk is not None:
-                    val = [out16[4 * b + 3], out16[4 * b + 2],
-                           out16[4 * b + 1], out16[4 * b]]
-                else:
-                    val = core(planes, np.uint32(b))
-                cw = [jnp.where(sel, cw2_ref[i, :, off + b][:, None],
-                                cw1_ref[i, :, off + b][:, None])
-                      for i in range(4)]
-                children.append(_add128_planes(val, cw))
-            off += a
-            planes = [jnp.concatenate([children[b][i] for b in range(a)],
-                                      axis=1) for i in range(4)]
-        contrib = _dot_digits(planes[0].astype(jnp.int32), table_ref,
+        off, w = 0, 1
+        for k, a in enumerate(sched[:n_narrow]):
+            kids = level(planes, cw1_ref, cw2_ref, slice(None), off, a)
+            if n_tiled and k == n_narrow - 1:     # into the first buffer
+                for b, res in kids:
+                    for i in range(4):
+                        bufs[0][i, :, b * w:(b + 1) * w] = res[i]
+            else:
+                kids = [res for _, res in kids]
+                planes = [jnp.concatenate([c[i] for c in kids], axis=1)
+                          for i in range(4)]
+            off, w = off + a, w * a
+        for k, a in enumerate(sched[n_narrow:]):
+            src, dst = bufs[k % 2], bufs[1 - k % 2]
+            limbs = 1 if k == n_tiled - 1 else 4
+            n_lane_tiles = w // TILE_LANES        # a power of two
+
+            def tile(t, carry):                   # traced right here
+                rows = slice(None)
+                if n_row_tiles > 1:
+                    r = t >> (n_lane_tiles.bit_length() - 1)
+                    rows = pl.ds(pl.multiple_of(r * tr, tr), tr)
+                    t = t & (n_lane_tiles - 1)
+                j = pl.multiple_of(t * TILE_LANES, TILE_LANES)
+                p = [src[i, rows, pl.ds(j, TILE_LANES)] for i in range(4)]
+                for b, res in level(p, cw1_ref, cw2_ref, rows, off, a):
+                    for i in range(limbs):
+                        dst[i, rows, pl.ds(b * w + j, TILE_LANES)] = res[i]
+                return carry
+
+            lax.fori_loop(0, n_row_tiles * n_lane_tiles, tile, 0)
+            off, w = off + a, w * a
+        leaves = bufs[n_tiled % 2][0] if n_tiled else planes[0]
+        contrib = _dot_digits(leaves.astype(jnp.int32), table_ref,
                               int(leaves_minor))      # [TB, E]
 
         @pl.when(f == 0)
@@ -375,16 +446,6 @@ def _make_subtree_kernel(sched: tuple, prf_method: int = 2,
             out_ref[:] = out_ref[:] + contrib
 
     return kernel
-
-
-# default tile knobs: widest level state = 16 words x [TB, C/2] u32.
-# On a v5e at N = 2^16, B = 64 and 512 (PERF.md): the binary kernel
-# (ChaCha20) ran 11-29 % faster per key at TB = 8 than at 16, 32 or 64,
-# and slower at C = 2048; the radix-4 kernel (ChaCha20-BLK) ran 3 %
-# faster at TB = 32 than at 8.
-PALLAS_TB = 8         # binary key tile (one sublane group)
-PALLAS_TB_MIXED = 32  # radix-4 key tile (a smaller batch: its size, >= 8)
-PALLAS_MAX_C = 4096   # leaves per subtree -> 1 MiB cipher state in VMEM
 
 
 def _kernel_leaf_order(table_perm, f_cnt: int, sched: tuple):
@@ -428,6 +489,7 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
     with ``leaves_minor`` its digits held [4, E, N] (``table_digits_t``
     of the rows in the kernel's leaf order)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     bsz, f_cnt, _ = frontier.shape
     n, e = table_perm.shape[-2:]
@@ -455,7 +517,10 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
               else subtree_digits(table_perm, f_cnt, tuple(sched)))
 
     grid = (bp // tb, f_cnt)
-    kernel = _make_subtree_kernel(tuple(sched), prf_method, leaves_minor)
+    kernel = _make_subtree_kernel(tuple(sched), prf_method, leaves_minor,
+                                  tb)
+    scratch = ([pltpu.VMEM((4, tb, c), jnp.uint32)] * 2
+               if tiled_levels(tuple(sched)) else [])
     table_spec = (pl.BlockSpec((4, e, c), lambda i, f: (0, 0, f))
                   if leaves_minor
                   else pl.BlockSpec((4, c, e), lambda i, f: (0, f, 0)))
@@ -472,6 +537,7 @@ def _subtree_contract_run(frontier, cw1, cw2, table_perm, *, idx, sched,
         ],
         out_specs=pl.BlockSpec((tb, e), lambda i, f: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, e), jnp.int32),
+        scratch_shapes=scratch,
         interpret=interpret,
         # key tiles are independent; the subtree axis accumulates into
         # the same [tb, E] output block (reduction dim -> "arbitrary")

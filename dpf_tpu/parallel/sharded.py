@@ -739,13 +739,16 @@ class ShardedDPFServer:
                 chunk_leaves=kn["chunk_leaves"], mesh=self.mesh,
                 aes_impl=_prf._aes_pair_impl(),
                 psum_group=kn["psum_group"])
-        from ..ops.pallas_level import has_subtree_core
+        from ..ops.pallas_level import has_subtree_core, tiled_levels
         if kernel not in ("xla", "pallas") or (
                 kernel == "pallas" and not has_subtree_core(self.prf_method)):
             raise ValueError(
                 "kernel_impl=%r: the binary mesh path runs the xla scan or "
                 "the subtree kernel (Salsa20/ChaCha20 and their block "
                 "forms), not prf %d" % (kernel, self.prf_method))
+        if kernel == "pallas":
+            levels = kn["chunk_leaves"].bit_length() - 1
+            annotate(tiled_levels=tiled_levels((2,) * levels))
         fn = eval_sharded_2d if self.n_byte > 1 else eval_sharded
         return fn(pk.cw1, pk.cw2, pk.last, self._layout(kn),
                   depth=self.depth, prf_method=self.prf_method,

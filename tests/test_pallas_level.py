@@ -97,6 +97,56 @@ def test_pallas_subtree_contract_multi_tile():
     _subtree_case(256, 10, 64, tb=4)
 
 
+# cases that reach the register-tiled levels (``tiled_levels``): two
+# binary subtrees of 2,048 leaves (parent widths 256, 512 and 1,024 run
+# tiled, the last two over 2 and 4 lane tiles), and the radix-4 block
+# PRG at its 32-key tile, two subtrees of 4,096 leaves (parent widths
+# 256 and 1,024 tiled, over 4 row groups and 4 and 16 tiles)
+_TILED_CASES = {
+    "chacha": 2,
+    "salsa": 1,
+    "chacha_blk.radix4": 5,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TILED_CASES))
+def test_pallas_subtree_tiled_levels_match_portable(case):
+    """The levels that run tile by tile from VMEM scratch give the
+    portable XLA path's shares bit for bit."""
+    from dpf_tpu.ops import pallas_level
+    method = _TILED_CASES[case]
+    if not case.endswith("radix4"):
+        assert pallas_level.tiled_levels((2,) * 11) == 3
+        _subtree_case(4096, 2, 2048, method=method)
+        return
+    from dpf_tpu.core import radix4
+    n, n_keys = 1 << 13, 32
+    assert pallas_level.tiled_levels(radix4.arities(n)[1:]) == 2
+    mk = [radix4.generate_keys_r4((i * 97) % n, n, b"ptl%d" % i, method)[0]
+          for i in range(n_keys)]
+    cw1, cw2, last = radix4.pack_mixed_keys(mk)
+    rng = np.random.default_rng(11)
+    table = rng.integers(-2 ** 31, 2 ** 31, (n, 8), dtype=np.int32)
+    perm = radix4.mixed_reverse_indices(radix4.arities(n))
+    tperm = jnp.asarray(np.ascontiguousarray(table[perm]))
+    want = np.asarray(radix4.expand_and_contract_mixed(
+        cw1, cw2, last, tperm, n=n, prf_method=method, chunk_leaves=None))
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(radix4.expand_and_contract_mixed_pallas(
+            cw1, cw2, last, tperm, n=n, prf_method=method))
+    assert (got == want).all()
+
+
+@pytest.mark.parametrize("sched,tiled", [
+    ((2,) * 8, 0), ((2,) * 9, 1), ((2,) * 12, 4), ((4,) * 4, 0),
+    ((4,) * 6, 2), ((2, 4, 4, 4, 4, 4), 1)])
+def test_tiled_levels_count(sched, tiled):
+    """A level runs tiled once its parents are a register tile (256
+    lanes) wide: 4 of the 12 binary levels at 4,096 leaves."""
+    from dpf_tpu.ops.pallas_level import tiled_levels
+    assert tiled_levels(sched) == tiled
+
+
 def test_pallas_subtree_mixed_radix4():
     """Radix-4 ChaCha through the mixed-arity subtree kernel
     (subtree_contract_pallas_mixed) vs the XLA mixed-radix path."""
@@ -142,6 +192,34 @@ def test_pallas_full_path_via_config(monkeypatch):
     got = np.asarray(d.eval_tpu(keys))
     want = np.asarray(ref.eval_tpu(keys))
     assert (got == want).all()
+
+
+def test_dispatch_span_names_the_tiled_levels(monkeypatch):
+    """``eval_tpu.dispatch`` carries the subtree kernel's tiled level
+    count beside the kernel: at 512 leaves a subtree, 1 of 9 levels."""
+    import dpf_tpu
+    from dpf_tpu.obs import tracer
+    from dpf_tpu.ops import pallas_level
+    from dpf_tpu.utils.config import EvalConfig
+
+    orig = pallas_level.subtree_contract_pallas
+    monkeypatch.setattr(
+        pallas_level, "subtree_contract_pallas",
+        lambda *a, **kw: orig(*a, **{**kw, "interpret": True}))
+    n = 512
+    d = dpf_tpu.DPF(config=EvalConfig(prf_method=dpf_tpu.PRF_CHACHA20,
+                                      kernel_impl="pallas"))
+    d.eval_init(np.arange(n * 4, dtype=np.int32).reshape(n, 4))
+    keys = [d.gen(7, n)[0], d.gen(300, n)[1]]
+    t = tracer.enable()
+    try:
+        t.clear()
+        d.eval_tpu(keys)
+        spans = [e for e in t.events() if e["name"] == "eval_tpu.dispatch"]
+    finally:
+        tracer.disable()
+    assert [s["attrs"] for s in spans] == [{"kernel": "pallas",
+                                            "tiled_levels": 1}]
 
 
 # the heuristic kernel rule: (case id) -> (DPF kwargs, probe patched to

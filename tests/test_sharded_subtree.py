@@ -31,8 +31,11 @@ def test_mesh_subtree_kernel_matches_cpu_and_single_chip(eight_devices, prf,
     """The subtree kernel per shard (Mosaic's interpreter) over four
     devices, 2^12 x 16 rows: two subtrees of nine kernel levels a shard,
     13 keys not a multiple of the key tile.  Server 0's shares equal
-    the host oracle and the one-chip xla path bit for bit."""
+    the host oracle and the one-chip xla path bit for bit, and the
+    dispatch span names the kernel and its one register-tiled level."""
     from jax.experimental.pallas import tpu as pltpu
+
+    from dpf_tpu.obs import tracer
     n = 1 << 12
     rng = np.random.default_rng(prf * 100 + batch)
     table = rng.integers(-2 ** 31, 2 ** 31, (n, 16),
@@ -51,5 +54,13 @@ def test_mesh_subtree_kernel_matches_cpu_and_single_chip(eight_devices, prf,
         assert (kn["kernel_impl"], kn["chunk_leaves"]) == ("pallas", 512)
         assert srv.shard_rows // kn["chunk_leaves"] == 2
         assert srv.table_sharded.dtype == np.int8   # no int32 table placed
-        got = srv.eval(keys)
+        t = tracer.enable()
+        try:
+            got = srv.eval(keys)
+            spans = [e for e in t.events()
+                     if e["name"] == "mesh_eval.dispatch"]
+        finally:
+            tracer.disable()
     assert (got == one_chip).all()
+    assert [s["attrs"] for s in spans] == [{"kernel": "pallas",
+                                            "tiled_levels": 1}]

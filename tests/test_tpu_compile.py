@@ -156,6 +156,54 @@ def test_pallas_kernel_compiles_for_v5e(compile_tpu, case):
     assert all(name in ln for ln in calls), (name, calls[0][:200])
 
 
+V5E_SCOPED_VMEM = 16 << 20   # a v5e kernel's default scoped-VMEM limit
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)
+            if hasattr(sub, "eqns"):
+                yield from _pallas_calls(sub)
+
+
+def _vmem_bytes(fn, *shapes):
+    """(scratch, total) VMEM bytes of the one kernel ``fn`` traces: its
+    scratch operands, and those plus two buffers of every pipelined
+    block, each padded to the chip's (sublane, 128-lane) tiles."""
+    (eqn,) = _pallas_calls(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    gm = eqn.params["grid_mapping"]
+
+    def padded(aval):
+        shape, size = list(aval.shape), aval.dtype.itemsize
+        if len(shape) >= 2:
+            sub = 8 * 4 // size
+            shape[-2] = -(-shape[-2] // sub) * sub
+        shape[-1] = -(-shape[-1] // 128) * 128
+        return int(np.prod(shape)) * size
+
+    scratch = sum(padded(a) for a in gm.scratch_avals)
+    blocks = sum(padded(bm.block_aval) for bm in gm.block_mappings)
+    return scratch, scratch + 2 * blocks
+
+
+@pytest.mark.parametrize("case,tb", [("subtree.chacha", 8),
+                                     ("mixed.chacha", 32),
+                                     ("mixed.chacha_blk", 32)])
+def test_subtree_kernel_scratch_fits_scoped_vmem(compile_tpu, case, tb):
+    """The subtree kernel's two [4, TB, C] u32 scratch buffers (4 MiB at
+    the radix-4 kernel's 32-key tile) and its double-buffered blocks fit
+    a v5e kernel's 16 MiB of scoped VMEM, and Mosaic compiles it so."""
+    fn, *shapes = PALLAS_CASES[case]()
+    scratch, total = _vmem_bytes(fn, *shapes)
+    assert scratch == 2 * 4 * tb * 4096 * 4
+    assert total <= V5E_SCOPED_VMEM, total
+    calls = _custom_calls(compile_tpu(fn, *shapes))
+    assert len(calls) == 1 and "dpf_subtree_contract" in calls[0]
+
+
 def test_subtree_kernel_keeps_its_name_at_a_small_table(compile_tpu):
     """The device trace finds the production kernel by its name,
     whatever the shape: here 2^14 rows and 64 keys."""
